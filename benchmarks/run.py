@@ -56,6 +56,10 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     from benchmarks import overlap_autotune, paper_tables
 
     benches = {

@@ -246,7 +246,10 @@ class SolverConfig:
     ``dtype``      operand precision. ``None`` (default) preserves the input
                    dtype; an explicit float dtype casts every operand on the
                    way in (``np.float64`` is the paper's precision — remember
-                   ``repro.core.tridiag.ensure_x64()``).
+                   ``repro.core.tridiag.ensure_x64()``). On a TPU the Pallas
+                   kernels take fp32 only: fp64 there is refused with a
+                   ``ValueError`` (by :meth:`validate` for ``dtype``, by the
+                   first solve for fp64 operands), never downcast.
     ``backend``    stage implementation: ``"auto"`` (default — Pallas kernels
                    on TPU hosts, reference jnp stages elsewhere),
                    ``"reference"``, ``"pallas"``, or a ``StageBackend``.
@@ -383,7 +386,9 @@ class SolverConfig:
                     f"dtype={self.dtype!r}: the solver runs in floating "
                     f"point; pass np.float64, np.float32, or None"
                 )
-        resolve_backend(self.backend)  # raises naming the known backends
+        backend = resolve_backend(self.backend)  # raises naming the known ones
+        if self.dtype is not None:
+            backend.check_dtype(self.dtype)
         if self.dispatch not in DISPATCH_MODES:
             raise ValueError(
                 f"dispatch={self.dispatch!r}: must be one of "
@@ -611,7 +616,9 @@ class SolveEngine:
     dispatch with the batch composition, chunk count, solve latency and the
     requests' queue wait times; ``rejected``/``timed_out``/``cancelled``/
     ``failed`` count shed and errored requests and ``queue_high_water`` the
-    deepest queue seen. The dict is mutated under ``_stats_lock`` —
+    deepest queue seen; ``layout`` and ``stage2`` count dispatches by the
+    operand layout and Stage-2 implementation that ran
+    (:meth:`record_dispatch`). The dict is mutated under ``_stats_lock`` —
     concurrent readers should take :meth:`stats_snapshot` instead.
     """
 
@@ -704,6 +711,8 @@ class SolveEngine:
             "failed": 0,
             "shed_predicted": 0,
             "queue_high_water": 0,
+            "layout": {},
+            "stage2": {},
         }
 
     # -- predicted-latency admission ------------------------------------------
@@ -1097,7 +1106,8 @@ class SolveEngine:
                 if model is None
                 else model.predict_ms(effective_size(sizes), plan.num_chunks)
             )
-            x, _ = self._executor.execute(plan, dl, d, du, b)
+            x, timing = self._executor.execute(plan, dl, d, du, b)
+            self.record_dispatch(timing)
             # copy: split_ragged returns views, which would otherwise pin the
             # whole fused solution for as long as any one result is retained
             solutions = [
@@ -1179,13 +1189,24 @@ class SolveEngine:
             else:
                 self._results[r.rid] = xi
 
+    def record_dispatch(self, timing: ChunkTiming) -> None:
+        """Count the layout and Stage-2 implementation one dispatch ran."""
+        with self._stats_lock:
+            for key in ("layout", "stage2"):
+                name = getattr(timing, key)
+                self.stats[key][name] = self.stats[key].get(name, 0) + 1
+
     def stats_snapshot(self) -> dict:
         """A consistent copy of :attr:`stats` (``per_batch`` entries
         included) plus the instantaneous ``queue_depth``, safe to read while
         a dispatch records its batch on another thread."""
         with self._stats_lock:
             snap = {
-                k: (v if not isinstance(v, list) else [dict(pb) for pb in v])
+                k: (
+                    [dict(pb) for pb in v]
+                    if isinstance(v, list)
+                    else dict(v) if isinstance(v, dict) else v
+                )
                 for k, v in self.stats.items()
             }
         snap["queue_depth"] = len(self._queue)
@@ -1363,6 +1384,7 @@ class TridiagSession:
         x, timing = self._pick_executor(timed).execute(
             self.plan_for(n), dl, d, du, b
         )
+        self._engine.record_dispatch(timing)
         return self._cast_out(x), timing
 
     def solve_batched(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
@@ -1378,18 +1400,18 @@ class TridiagSession:
         self, dl: Any, d: Any, du: Any, b: Any, *, timed: bool
     ) -> Tuple[np.ndarray, ChunkTiming]:
         dl, d, du, b = self._cast(dl, d, du, b)
-        d_arr = np.asarray(d)
-        if d_arr.ndim != 2:
+        if np.ndim(d) != 2:
             raise ValueError(
                 f"solve_batched takes (batch, n) operands, got shape "
-                f"{d_arr.shape}; use solve() for one system or solve_many() "
+                f"{np.shape(d)}; use solve() for one system or solve_many() "
                 f"for mixed sizes"
             )
-        batch, n = d_arr.shape
-        fused = fuse_systems(dl, d_arr, du, b)
+        batch, n = np.shape(d)
+        fused = fuse_systems(dl, d, du, b)
         x, timing = self._pick_executor(timed).execute(
             self.plan_for((n,) * batch), *fused
         )
+        self._engine.record_dispatch(timing)
         return split_systems(self._cast_out(x), batch), timing
 
     def solve_many(self, systems: Sequence[System]) -> List[np.ndarray]:
@@ -1410,6 +1432,7 @@ class TridiagSession:
         x, timing = self._pick_executor(timed).execute(
             self.plan_for(sizes), dl, d, du, b
         )
+        self._engine.record_dispatch(timing)
         return split_ragged(self._cast_out(x), sizes), timing
 
     # -- asynchronous serving ------------------------------------------------
@@ -1640,13 +1663,20 @@ class TridiagSession:
         recorded/dropped/buffered observation counts. ``mesh`` reports the
         active device mesh (None on the single-device path; otherwise the
         device count, platform and device-id signature sharded executables
-        run under).
+        run under). ``backend`` names the resolved stage backend and whether
+        its kernels run interpreted (None for a backend without kernels);
+        ``layout`` and ``stage2`` count every dispatch — synchronous verbs and
+        served batches — by operand layout and Stage-2 implementation.
         """
         with self._cv:
             snap = self._engine.stats_snapshot()
             snap["unresolved"] = len(self._futures)
         snap["plan_cache"] = plan_cache_stats()
         snap["executable_cache"] = executable_cache_stats()
+        snap["backend"] = {
+            "name": self.backend.name,
+            "interpret": self.backend.interpret_mode(),
+        }
         snap["mesh"] = (
             None
             if self._mesh_devices is None

@@ -91,8 +91,15 @@ def fuse_systems(
 
     Zeroing ``dl[:, 0]`` / ``du[:, n-1]`` is what makes the fused partition
     solve decouple exactly (see module docstring); those entries are ignored
-    by convention in the unfused solve, so this loses nothing.
+    by convention in the unfused solve, so this loses nothing. Device arrays
+    are fused on their devices, keeping their sharding.
     """
+    if isinstance(d, jax.Array):
+        dl = jnp.asarray(dl).at[..., :, 0].set(0.0)
+        du = jnp.asarray(du).at[..., :, -1].set(0.0)
+        return tuple(
+            jnp.asarray(a).reshape(*a.shape[:-2], -1) for a in (dl, d, du, b)
+        )
     dl = np.array(dl, copy=True)
     du = np.array(du, copy=True)
     dl[..., :, 0] = 0.0

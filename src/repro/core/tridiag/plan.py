@@ -112,6 +112,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core.tridiag import layout as layout_mod
@@ -119,7 +120,6 @@ from repro.core.tridiag import partition
 from repro.core.tridiag.layout import resolve_layout
 from repro.core.tridiag.reference import thomas_numpy
 from repro.core.tridiag.thomas import thomas as thomas_scan
-from repro.parallel.compat import shard_map
 from repro.parallel.solver import (
     MESH_AXIS_BATCH,
     MESH_AXIS_CHUNKS,
@@ -134,7 +134,10 @@ Sizes = Union[int, Sequence[int]]
 
 @dataclass
 class ChunkTiming:
-    """Wall-clock phase breakdown of one planned solve (milliseconds)."""
+    """Wall-clock phase breakdown of one planned solve (milliseconds), and
+    what ran: the operand ``layout`` and the ``stage2`` implementation
+    (``"host"`` for the staged path's ``thomas_numpy``, otherwise the name
+    :meth:`StageBackend.reduced_solve_impl` gives)."""
 
     num_chunks: int
     t_stage1_ms: float
@@ -142,6 +145,8 @@ class ChunkTiming:
     t_stage3_ms: float
     t_total_ms: float
     n: int = 0
+    layout: str = "system-major"
+    stage2: str = "host"
 
     @property
     def phases(self) -> Tuple[float, float, float]:
@@ -175,9 +180,15 @@ class StageBackend:
     ``make_reduced_solve()`` returns the *device-side* Stage-2 solver used by
     the fused dispatch path (``(red_dl, red_d, red_du, red_b) -> s``, traced
     into the fused executable). The default is the pure-jnp Thomas scan; the
-    Pallas backend routes 1-D/2-D reduced systems through the
-    ``repro.kernels.thomas`` kernel. The staged path never calls it — its
-    Stage 2 stays on the host (``thomas_numpy``), as in the paper.
+    Pallas backend routes 1-D/2-D reduced systems that fit VMEM through the
+    ``repro.kernels.thomas`` kernel and larger ones through the scan.
+    :meth:`reduced_solve_impl` names the choice for a shape before anything
+    is traced, so executors can report it. The staged path never calls it —
+    its Stage 2 stays on the host (``thomas_numpy``), as in the paper.
+
+    :meth:`check_dtype` refuses operand dtypes the backend cannot run, and
+    :meth:`interpret_mode` says whether its kernels run interpreted (None
+    for a backend without kernels).
 
     Operand *layout* is also a backend concern: the ``make_wide_*`` trio are
     the batch-interleaved (lane-major) counterparts, consuming wide operands
@@ -200,6 +211,21 @@ class StageBackend:
 
     def make_reduced_solve(self) -> Callable:
         return thomas_scan
+
+    def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        """The Stage-2 implementation ``make_reduced_solve`` runs on reduced
+        rows of ``shape`` (``(..., P)``)."""
+        return "thomas_scan"
+
+    def wide_reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        """Same for ``make_wide_reduced_solve`` on ``(P, B)`` rows."""
+        return "thomas_scan_wide"
+
+    def interpret_mode(self) -> Optional[bool]:
+        return None
+
+    def check_dtype(self, dtype: Any) -> None:
+        """Raise ``ValueError`` for operands this backend cannot run."""
 
     def make_wide_stage1(self, m: int) -> Callable:
         return jax.jit(partial(layout_mod.partition_stage1_wide, m=m))
@@ -233,6 +259,12 @@ class PallasBackend(StageBackend):
     the single-system grid. ``interpret=None`` defers to
     ``repro.kernels.common.interpret_default()`` — interpret mode off-TPU, so
     the same backend object serves CPU tests and TPU runs.
+
+    Compiled kernels take fp32 only (Mosaic has no fp64): fp64 operands are
+    refused with a ``ValueError`` rather than downcast. The reduced solve
+    stays on the Thomas kernel while its tiles fit VMEM
+    (``repro.kernels.thomas.ops.thomas_fits_vmem``, decided from the shape)
+    and runs the XLA scan beyond that.
     """
 
     name = "pallas"
@@ -289,14 +321,39 @@ class PallasBackend(StageBackend):
 
         return stage3
 
+    def interpret_mode(self) -> Optional[bool]:
+        from repro.kernels.common import interpret_default
+
+        return interpret_default() if self.interpret is None else self.interpret
+
+    def check_dtype(self, dtype: Any) -> None:
+        if self.interpret_mode():
+            return  # interpreted kernels are plain XLA ops: fp64 runs
+        if np.dtype(jax.dtypes.canonicalize_dtype(dtype)) == np.float64:
+            raise ValueError(
+                "fp64 operands cannot run on the compiled Pallas kernels "
+                "(Mosaic has no fp64); pass SolverConfig(dtype=np.float32) "
+                "or fp32 operands"
+            )
+
+    def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        from repro.kernels.thomas.ops import thomas_fits_vmem
+
+        # The kernel's grid is (batch,)-tiled: 1-D and 2-D reduced systems
+        # route through it while their tiles fit VMEM; exotic extra leading
+        # dims and longer systems take the scan.
+        if len(shape) <= 2:
+            lanes = shape[0] if len(shape) == 2 else 1
+            if thomas_fits_vmem(shape[-1], lanes, np.dtype(dtype).itemsize):
+                return "thomas_pallas"
+        return "thomas_scan"
+
     def make_reduced_solve(self) -> Callable:
         from repro.kernels.thomas.ops import thomas_pallas
 
         def reduced_solve(red_dl: Any, red_d: Any, red_du: Any, red_b: Any) -> Any:
-            # The kernel's grid is (batch,)-tiled: 1-D and 2-D reduced
-            # systems route through it; exotic extra leading dims fall back
-            # to the scan (they only arise on the reference stages anyway).
-            if jnp.asarray(red_d).ndim <= 2:
+            red_d = jnp.asarray(red_d)
+            if self.reduced_solve_impl(red_d.shape, red_d.dtype) == "thomas_pallas":
                 return thomas_pallas(
                     red_dl, red_d, red_du, red_b, interpret=self.interpret
                 )
@@ -332,12 +389,30 @@ class PallasBackend(StageBackend):
 
         return wide_stage3
 
+    def wide_reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        from repro.kernels.thomas.ops import thomas_fits_vmem
+
+        p, lanes = shape
+        if thomas_fits_vmem(p, lanes, np.dtype(dtype).itemsize, self.block_b):
+            return "thomas_pallas_wide"
+        return "thomas_scan_wide"
+
     def make_wide_reduced_solve(self) -> Callable:
         from repro.kernels.thomas.ops import thomas_pallas_wide
 
-        return partial(
-            thomas_pallas_wide, block_b=self.block_b, interpret=self.interpret
-        )
+        def wide_reduced_solve(
+            red_dl: Any, red_d: Any, red_du: Any, red_b: Any
+        ) -> Any:
+            red_d = jnp.asarray(red_d)
+            impl = self.wide_reduced_solve_impl(red_d.shape, red_d.dtype)
+            if impl == "thomas_pallas_wide":
+                return thomas_pallas_wide(
+                    red_dl, red_d, red_du, red_b,
+                    block_b=self.block_b, interpret=self.interpret,
+                )
+            return layout_mod.thomas_wide(red_dl, red_d, red_du, red_b)
+
+        return wide_reduced_solve
 
 
 @dataclass(frozen=True)
@@ -363,6 +438,18 @@ class AutoBackend(StageBackend):
 
     def make_reduced_solve(self) -> Callable:
         return self.resolve().make_reduced_solve()
+
+    def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        return self.resolve().reduced_solve_impl(shape, dtype)
+
+    def wide_reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        return self.resolve().wide_reduced_solve_impl(shape, dtype)
+
+    def interpret_mode(self) -> Optional[bool]:
+        return self.resolve().interpret_mode()
+
+    def check_dtype(self, dtype: Any) -> None:
+        self.resolve().check_dtype(dtype)
 
     def make_wide_stage1(self, m: int) -> Callable:
         return self.resolve().make_wide_stage1(m)
@@ -869,6 +956,7 @@ class PlanExecutor:
             raise ValueError(
                 f"operands have {n} rows but the plan lays out {plan.total_size}"
             )
+        self.backend.check_dtype(d.dtype if hasattr(d, "dtype") else np.result_type(d))
         layout = resolve_layout(
             self.layout, plan.sizes, m, fused=False, lead_ndim=np.ndim(d) - 1
         )
@@ -982,6 +1070,7 @@ class PlanExecutor:
             t_stage3_ms=(t3 - t2) * 1e3,
             t_total_ms=(t3 - t0) * 1e3,
             n=plan.total_size,
+            layout="interleaved",
         )
         return x, timing
 
@@ -1155,8 +1244,9 @@ def _fused_callable(
     avals: Sequence[jax.ShapeDtypeStruct],
     layout: str = "system-major",
     mesh_devices: Optional[Sequence[Any]] = None,
-) -> Callable:
-    """Trace + AOT-compile the whole three-stage solve for ``plan``.
+) -> Tuple[Callable, str]:
+    """Trace + AOT-compile the whole three-stage solve for ``plan``; return
+    the executable and the name of the Stage-2 implementation traced in.
 
     The chunk structure is baked in from the (static) plan: stage 1 slices
     every chunk + halo out of the fused operands via ``lax.slice`` inside the
@@ -1192,9 +1282,12 @@ def _fused_callable(
     sees its own diagnostics).
     """
     m = plan.m
+    dtype = avals[1].dtype
 
     if layout == "interleaved":
         sizes = plan.sizes
+        lanes = len(sizes) // (len(mesh_devices) if mesh_devices else 1)
+        stage2 = backend.wide_reduced_solve_impl((max(sizes) // m, lanes), dtype)
         wide_stage1, wide_stage3 = jitted_wide_stages(m, backend)
         wide_reduced = backend.make_wide_reduced_solve()
 
@@ -1219,9 +1312,13 @@ def _fused_callable(
             return layout_mod.deinterleave(xw, sizes, m)
 
     elif mesh_devices is not None:
+        stage2 = backend.reduced_solve_impl((plan.num_blocks,), dtype)
         fused = _sharded_fused_callable(plan, backend, mesh_devices)
 
     else:
+        stage2 = backend.reduced_solve_impl(
+            tuple(avals[1].shape[:-1]) + (plan.num_blocks,), dtype
+        )
         stage1, _ = jitted_stages(m, backend)
         stage3_ghost = jitted_stage3_ghost(backend)
         reduced_solve = backend.make_reduced_solve()
@@ -1254,7 +1351,7 @@ def _fused_callable(
             return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
 
     if not donate:
-        return jax.jit(fused)
+        return jax.jit(fused), stage2
     jitted = jax.jit(fused, donate_argnums=(0, 1, 2, 3))
     # catch_warnings mutates the process-global filter list, so concurrent
     # compiles must not interleave with it (a racing restore would leak the
@@ -1264,7 +1361,7 @@ def _fused_callable(
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable"
         )
-        return jitted.lower(*avals).compile()
+        return jitted.lower(*avals).compile(), stage2
 
 
 class FusedExecutor:
@@ -1338,7 +1435,10 @@ class FusedExecutor:
             return self.mesh_devices[: plan.shards]
         return None
 
-    def _executable(self, plan: SolvePlan, ops: Sequence) -> Callable:
+    def _executable(
+        self, plan: SolvePlan, ops: Sequence
+    ) -> Tuple[Callable, str, str]:
+        """The cached ``(executable, layout, stage2)`` for these operands."""
         lead_ndim = ops[1].ndim - 1
         batch_shards = (
             shard_count(len(plan.sizes), len(self.mesh_devices))
@@ -1364,11 +1464,11 @@ class FusedExecutor:
             tuple(a.shape[:-1] for a in ops),
         )
         with _CACHE_LOCK:
-            fn = _EXEC_CACHE.get(key)
-            if fn is not None:
+            entry = _EXEC_CACHE.get(key)
+            if entry is not None:
                 _EXEC_CACHE.move_to_end(key)
                 _EXEC_STATS["hits"] += 1
-                return fn
+                return entry
             _EXEC_STATS["misses"] += 1
         # Build (trace + compile) outside the lock: compilation is the
         # expensive part, and a racing builder is harmless (first one in
@@ -1377,19 +1477,20 @@ class FusedExecutor:
             jax.ShapeDtypeStruct(a.shape, jax.dtypes.canonicalize_dtype(a.dtype))
             for a in ops
         ]
-        fn = _fused_callable(
+        fn, stage2 = _fused_callable(
             plan, self.backend, self.donate, avals, layout, shard_devices
         )
+        entry = (fn, layout, stage2)
         with _CACHE_LOCK:
             existing = _EXEC_CACHE.get(key)
             if existing is not None:
                 return existing
             if _EXEC_CACHE_CAPACITY > 0:
-                _EXEC_CACHE[key] = fn
+                _EXEC_CACHE[key] = entry
                 while len(_EXEC_CACHE) > _EXEC_CACHE_CAPACITY:
                     _EXEC_CACHE.popitem(last=False)
                     _EXEC_STATS["evictions"] += 1
-        return fn
+        return entry
 
     def execute(
         self,
@@ -1412,7 +1513,8 @@ class FusedExecutor:
             raise ValueError(
                 f"operands have {n} rows but the plan lays out {plan.total_size}"
             )
-        fn = self._executable(plan, ops)
+        self.backend.check_dtype(ops[1].dtype)
+        fn, layout, stage2 = self._executable(plan, ops)
         t0 = time.perf_counter()
         x = np.asarray(fn(*ops))  # blocks until the solution is on the host
         t1 = time.perf_counter()
@@ -1423,4 +1525,6 @@ class FusedExecutor:
             t_stage3_ms=0.0,
             t_total_ms=(t1 - t0) * 1e3,
             n=int(n),
+            layout=layout,
+            stage2=stage2,
         )

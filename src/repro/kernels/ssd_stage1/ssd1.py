@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import common
+
 
 def _ssd1_kernel(u_ref, dac_ref, b_ref, c_ref, y_ref, s_ref, *, q: int, nh: int):
     u = u_ref[0].astype(jnp.float32)          # [Q, H, P]
@@ -55,14 +57,14 @@ def ssd1_tiled(u, dac, b, c, *, interpret: bool):
         functools.partial(_ssd1_kernel, q=q, nh=nh),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q, nh, p), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, q, nh), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, q, n), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, q, n), lambda i: (i, 0, 0)),
+            common.block_spec((1, q, nh, p), lambda i: (i, 0, 0, 0)),
+            common.block_spec((1, q, nh), lambda i: (i, 0, 0)),
+            common.block_spec((1, q, n), lambda i: (i, 0, 0)),
+            common.block_spec((1, q, n), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, q, nh, p), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, nh, p, n), lambda i: (i, 0, 0, 0)),
+            common.block_spec((1, q, nh, p), lambda i: (i, 0, 0, 0)),
+            common.block_spec((1, nh, p, n), lambda i: (i, 0, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, q, nh, p), jnp.float32),

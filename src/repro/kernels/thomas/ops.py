@@ -4,8 +4,9 @@ Besides its original role (B independent solves), this kernel is the
 device-side Stage-2 reduced solver of the fused dispatch path:
 ``repro.core.tridiag.plan.PallasBackend.make_reduced_solve`` traces
 :func:`thomas_pallas` into the single-dispatch fused executable (1-D reduced
-systems ride the batch-1 path below), so a fused Pallas solve keeps all
-three partition stages on device.
+systems ride the batch-1 path below) while :func:`thomas_fits_vmem` holds,
+and the XLA scan beyond it, so a fused Pallas solve keeps all three
+partition stages on device at every size.
 """
 
 from __future__ import annotations
@@ -17,6 +18,27 @@ import jax.numpy as jnp
 
 from repro.kernels import common
 from repro.kernels.thomas.thomas import thomas_tiled
+
+#: Scoped VMEM one Mosaic kernel may use by default on TPU v5e.
+VMEM_BUDGET_BYTES = 16 * 2**20
+#: (n, block_b) tiles the kernel keeps resident: 4 in, 1 out, 2 scratch.
+RESIDENT_TILES = 7
+
+
+def thomas_vmem_bytes(n: int, lanes: int, itemsize: int, block_b: int = 256) -> int:
+    """VMEM one grid step of :func:`thomas_tiled` takes for ``lanes``
+    systems of ``n`` rows, with the lane block the wrappers below pick."""
+    block_b = min(block_b, common.round_up(lanes, common.LANES))
+    return RESIDENT_TILES * common.round_up(n, common.SUBLANES) * block_b * itemsize
+
+
+def thomas_fits_vmem(n: int, lanes: int, itemsize: int, block_b: int = 256) -> bool:
+    """Whether the kernel compiles for these shapes, decided before tracing.
+
+    The 1-D fused reduced system rides one 128-lane tile, so on fp32 it
+    fits up to n = 4,680 rows (a v5e compile at 4,681 runs out of VMEM).
+    """
+    return thomas_vmem_bytes(n, lanes, itemsize, block_b) <= VMEM_BUDGET_BYTES
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))

@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 
+from repro.kernels import common
+
 
 def _matvec_kernel(dl_ref, d_ref, du_ref, xl_ref, x_ref, xr_ref, r_ref):
     r_ref[...] = (
@@ -24,7 +26,7 @@ def matvec_tiled(
     """All operands pre-reshaped to (R, 128); tiles of (block_r, 128)."""
     r, lanes = d2.shape
     grid = (r // block_r,)
-    spec = pl.BlockSpec((block_r, lanes), lambda i: (i, 0))
+    spec = common.block_spec((block_r, lanes), lambda i: (i, 0))
     return pl.pallas_call(
         _matvec_kernel,
         grid=grid,

@@ -7,7 +7,9 @@ inter-pod links; see repro.parallel.collectives for the bucketed overlap.
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import; smoke tests see
-one CPU device).
+one CPU device). Every model-substrate mesh is built here, with Auto axis
+types: ``jax.make_mesh`` defaults to Explicit axes, which
+``with_sharding_constraint`` (``ParallelCtx.shard``) refuses.
 """
 
 from __future__ import annotations
@@ -19,10 +21,16 @@ import jax
 from repro.parallel.ctx import ParallelCtx
 
 
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_ctx(mesh, *, seq_shard: bool = False, remat: str = "full",
@@ -69,4 +77,4 @@ def make_ctx(mesh, *, seq_shard: bool = False, remat: str = "full",
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for tests run under --xla_force_host_platform_device_count."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

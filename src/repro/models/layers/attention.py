@@ -19,8 +19,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.parallel.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models.layers.norms import init_rmsnorm, rms_norm

@@ -27,9 +27,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.compat import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models.layers.mlp import init_mlp, mlp_apply

@@ -20,7 +20,7 @@ SCRIPT = textwrap.dedent(
 
     from repro.configs.base import get_config
     from repro.configs.shapes import ShapeSpec, input_specs, synthesize_batch
-    from repro.launch.mesh import make_ctx
+    from repro.launch.mesh import make_ctx, make_debug_mesh
     from repro.models.registry import build_model
     from repro.optim import adamw
     from repro.parallel.sharding import batch_spec, param_specs
@@ -29,7 +29,7 @@ SCRIPT = textwrap.dedent(
 
     arch = sys.argv[1]
     mode = sys.argv[2]
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_debug_mesh(4, 2)
     pctx = make_ctx(mesh, remat="full")
     cfg = get_config(arch).smoke()
     model = build_model(cfg)

@@ -22,13 +22,13 @@ SCRIPT = textwrap.dedent(
 
     from repro.configs.base import get_config
     from repro.configs.shapes import ShapeSpec, synthesize_batch
-    from repro.launch.mesh import make_ctx
+    from repro.launch.mesh import make_ctx, make_debug_mesh
     from repro.models.registry import build_model
     from repro.parallel.ctx import ParallelCtx
     from repro.train.step import make_loss_fn
 
     mode = sys.argv[1]
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_debug_mesh(4, 2)
     arch = "moonshot-v1-16b-a3b" if mode == "int8moe" else "qwen3-4b"
     cfg = get_config(arch).smoke()
     model = build_model(cfg)

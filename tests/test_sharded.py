@@ -208,6 +208,25 @@ class TestShardedParityWide:
         err = max(rel_err(x[i], ref[i]) for i in range(B))
         assert err < 1e-12
 
+    @pytest.mark.parametrize("layout", ["system-major", "interleaved"])
+    def test_placed_device_operands(self, multi_device_count, layout):
+        # Operands already placed a quarter per device are fused where they
+        # live and solve like the same operands passed from the host.
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        B, n = 64, 320
+        ops = make_diag_dominant_system(n, seed=5, batch=(B,))[:4]
+        devices = jax.devices()[:4]
+        sharding = NamedSharding(Mesh(np.array(devices), ("x",)), PartitionSpec("x"))
+        cfg = SolverConfig(mesh=4, layout=layout)
+        with TridiagSession(cfg) as s:
+            x_host = s.solve_batched(*ops)
+            placed = [jax.device_put(a, sharding) for a in ops]
+            x_placed = s.solve_batched(*placed)
+            assert s.stats["layout"] == {layout: 2}
+        np.testing.assert_array_equal(x_placed, x_host)
+        assert rel_err(x_placed, thomas_numpy(*ops)) < 1e-12
+
     def test_per_shard_auto_threshold(self, multi_device_count):
         # 64 lanes / 8 devices = 8 per shard < 32: "auto" must NOT interleave
         # under a mesh (per-shard lanes too narrow), though it would at B=64
@@ -329,14 +348,14 @@ class TestShardMapProof:
         backend = resolve_backend("reference")
         devices = resolve_mesh_devices("auto")
 
-        sharded = _fused_callable(
+        sharded, _ = _fused_callable(
             plan, backend, False, avals, "system-major", devices
         )
         hlo = jax.jit(sharded).lower(*avals).compile().as_text()
         assert "all-gather" in hlo
         assert "collective-permute" in hlo
 
-        unsharded = _fused_callable(plan, backend, False, avals, "system-major")
+        unsharded, _ = _fused_callable(plan, backend, False, avals, "system-major")
         hlo_u = jax.jit(unsharded).lower(*avals).compile().as_text()
         assert "all-gather" not in hlo_u
         assert "collective-permute" not in hlo_u
@@ -353,7 +372,7 @@ class TestShardMapProof:
         plan = build_plan(sizes, M, num_chunks=1)
         avals = [jax.ShapeDtypeStruct((n * B,), jnp.float64)] * 4
         devices = resolve_mesh_devices("auto")
-        wide = _fused_callable(
+        wide, _ = _fused_callable(
             plan, resolve_backend("reference"), False, avals, "interleaved", devices
         )
         compiled = jax.jit(wide).lower(*avals).compile()

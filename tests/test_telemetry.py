@@ -366,8 +366,11 @@ def test_session_records_observations_while_serving():
 
 def _seeded_session(mode, clock):
     cfg = SolverConfig(m=10, max_wait_ms=1.0, autotune=mode)
+    # The fake clock never advances, so with a non-zero interval exactly one
+    # refit fires (the test's own); the worker cannot refit again on the
+    # served batches' observations and reprice them mid-test.
     refitter = OnlineRefitter(
-        mode, min_samples=1, interval_s=0.0, clock=clock
+        mode, min_samples=1, interval_s=1.0, clock=clock
     )
     session = TridiagSession(cfg, refitter=refitter)
     for o in streams_help_observations():

@@ -35,6 +35,17 @@ def test_thomas_matches_numpy(n):
     assert _rel_err(x, x_true) < 1e-9
 
 
+@pytest.mark.parametrize("n", [1, 2, 97, 1000])
+def test_thomas_numpy_1d_matches_array_loop(n):
+    # One system takes the Python-float recurrence; a batch of one takes the
+    # array loop. Both are IEEE-double, so they must agree bit for bit.
+    dl, d, du, b, _ = make_diag_dominant_system(n, seed=n + 1)
+    x1 = thomas_numpy(dl, d, du, b)
+    xb = thomas_numpy(dl[None], d[None], du[None], b[None])[0]
+    assert x1.dtype == np.float64
+    np.testing.assert_array_equal(x1, xb)
+
+
 def test_thomas_vs_dense_solve():
     dl, d, du, b, _ = make_diag_dominant_system(64, seed=7)
     x_dense = np.linalg.solve(tridiag_to_dense(dl, d, du), b)
