@@ -86,9 +86,11 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.streams.timemodel import LatencyModel
+from repro.core.tridiag import spans
 from repro.core.tridiag.batched import fuse_systems, split_systems
 from repro.core.tridiag.layout import LAYOUTS, resolve_layout
 from repro.core.tridiag.plan import (
@@ -1081,113 +1083,116 @@ class SolveEngine:
         silently killed the daemon thread).
         """
         reqs = [p.req for p in group]
-        t0 = time.perf_counter()
-        try:
-            sizes = tuple(r.size for r in reqs)
-            same_size = len(set(sizes)) == 1
-            dl, d, du, b, sizes = fuse_ragged([(r.dl, r.d, r.du, r.b) for r in reqs])
-            # One read of the policy: a live-mode refit swaps it between
-            # dispatches, and this batch must be priced (and recorded) by
-            # exactly one of the two.
-            policy = self.policy
-            shards = self.plan_shards(sizes)
-            if policy is not None:
-                plan = build_plan(sizes, self.m, policy=policy, shards=shards)
-            else:
-                plan = build_plan(
-                    sizes,
-                    self.m,
-                    num_chunks=self.pick_chunks_ragged(sizes),
-                    shards=shards,
-                )
-            model = self.latency_model()
-            predicted_ms = (
-                None
-                if model is None
-                else model.predict_ms(effective_size(sizes), plan.num_chunks)
-            )
-            x, timing = self._executor.execute(plan, dl, d, du, b)
-            self.record_dispatch(timing)
-            # copy: split_ragged returns views, which would otherwise pin the
-            # whole fused solution for as long as any one result is retained
-            solutions = [
-                np.array(xi, dtype=self.dtype, copy=True)
-                for xi in split_ragged(x, sizes)
-            ]
-            dt = time.perf_counter() - t0
-            waits_ms = [(now - p.t_submit) * 1e3 for p in group]
-            # Stats are recorded BEFORE futures resolve: a caller unblocked
-            # by fut.result() may immediately read session.stats and must see
-            # this batch's entry (the worker races it otherwise).
-            with self._stats_lock:
-                self.stats["batches"] += 1
-                self.stats["systems"] += len(reqs)
-                self.stats["wall_s"] += dt
-                self.stats["per_batch"].append(
-                    {
-                        "systems": len(reqs),
-                        "sizes": sizes,
-                        "effective_size": effective_size(sizes),
-                        "ragged": not same_size,
-                        "num_chunks": plan.num_chunks,
-                        "latency_ms": dt * 1e3,
-                        "mean_wait_ms": float(np.mean(waits_ms)),
-                        "max_wait_ms": float(np.max(waits_ms)),
-                    }
-                )
-            if self.telemetry is not None and self.telemetry.enabled:
-                # Guarded separately: telemetry is observability, and a
-                # recording failure must not fail a *solved* batch.
-                try:
-                    self.telemetry.record(
-                        BatchObservation(
-                            t=now,
-                            sizes=sizes,
-                            num_chunks=plan.num_chunks,
-                            backend=str(
-                                getattr(
-                                    getattr(self._executor, "backend", None),
-                                    "name",
-                                    "?",
-                                )
-                            ),
-                            layout=resolve_layout(
-                                self.layout,
-                                sizes,
-                                self.m,
-                                fused=self.dispatch != "staged",
-                                batch_shards=(
-                                    shard_count(len(sizes), len(self.mesh_devices))
-                                    if self.mesh_devices is not None
-                                    else 1
-                                ),
-                            ),
-                            dispatch=(
-                                "staged" if self.dispatch == "staged" else "fused"
-                            ),
-                            latency_ms=dt * 1e3,
-                            mean_wait_ms=float(np.mean(waits_ms)),
-                            max_wait_ms=float(np.max(waits_ms)),
-                            predicted_ms=predicted_ms,
-                        )
+        with jax.profiler.TraceAnnotation(
+            spans.BATCH, systems=len(reqs), first_rid=reqs[0].rid if reqs else -1
+        ):
+            t0 = time.perf_counter()
+            try:
+                sizes = tuple(r.size for r in reqs)
+                same_size = len(set(sizes)) == 1
+                dl, d, du, b, sizes = fuse_ragged([(r.dl, r.d, r.du, r.b) for r in reqs])
+                # One read of the policy: a live-mode refit swaps it between
+                # dispatches, and this batch must be priced (and recorded) by
+                # exactly one of the two.
+                policy = self.policy
+                shards = self.plan_shards(sizes)
+                if policy is not None:
+                    plan = build_plan(sizes, self.m, policy=policy, shards=shards)
+                else:
+                    plan = build_plan(
+                        sizes,
+                        self.m,
+                        num_chunks=self.pick_chunks_ragged(sizes),
+                        shards=shards,
                     )
-                except Exception:
-                    pass
-        except Exception as e:
-            # A bad dispatch fails *these* requests and leaves the engine
-            # serving; the legacy shim (no on_error) keeps the raise.
-            self._fail_group(reqs, e)
-            return
-        for r, xi in zip(reqs, solutions):
-            if self._on_result is not None:
-                try:
-                    self._on_result(r.rid, xi)
-                except Exception as e:
-                    # A result channel that raises fails only ITS request;
-                    # the rest of the batch still delivers.
-                    self._fail_group([r], e)
-            else:
-                self._results[r.rid] = xi
+                model = self.latency_model()
+                predicted_ms = (
+                    None
+                    if model is None
+                    else model.predict_ms(effective_size(sizes), plan.num_chunks)
+                )
+                x, timing = self._executor.execute(plan, dl, d, du, b)
+                self.record_dispatch(timing)
+                # copy: split_ragged returns views, which would otherwise pin the
+                # whole fused solution for as long as any one result is retained
+                solutions = [
+                    np.array(xi, dtype=self.dtype, copy=True)
+                    for xi in split_ragged(x, sizes)
+                ]
+                dt = time.perf_counter() - t0
+                waits_ms = [(now - p.t_submit) * 1e3 for p in group]
+                # Stats are recorded BEFORE futures resolve: a caller unblocked
+                # by fut.result() may immediately read session.stats and must see
+                # this batch's entry (the worker races it otherwise).
+                with self._stats_lock:
+                    self.stats["batches"] += 1
+                    self.stats["systems"] += len(reqs)
+                    self.stats["wall_s"] += dt
+                    self.stats["per_batch"].append(
+                        {
+                            "systems": len(reqs),
+                            "sizes": sizes,
+                            "effective_size": effective_size(sizes),
+                            "ragged": not same_size,
+                            "num_chunks": plan.num_chunks,
+                            "latency_ms": dt * 1e3,
+                            "mean_wait_ms": float(np.mean(waits_ms)),
+                            "max_wait_ms": float(np.max(waits_ms)),
+                        }
+                    )
+                if self.telemetry is not None and self.telemetry.enabled:
+                    # Guarded separately: telemetry is observability, and a
+                    # recording failure must not fail a *solved* batch.
+                    try:
+                        self.telemetry.record(
+                            BatchObservation(
+                                t=now,
+                                sizes=sizes,
+                                num_chunks=plan.num_chunks,
+                                backend=str(
+                                    getattr(
+                                        getattr(self._executor, "backend", None),
+                                        "name",
+                                        "?",
+                                    )
+                                ),
+                                layout=resolve_layout(
+                                    self.layout,
+                                    sizes,
+                                    self.m,
+                                    fused=self.dispatch != "staged",
+                                    batch_shards=(
+                                        shard_count(len(sizes), len(self.mesh_devices))
+                                        if self.mesh_devices is not None
+                                        else 1
+                                    ),
+                                ),
+                                dispatch=(
+                                    "staged" if self.dispatch == "staged" else "fused"
+                                ),
+                                latency_ms=dt * 1e3,
+                                mean_wait_ms=float(np.mean(waits_ms)),
+                                max_wait_ms=float(np.max(waits_ms)),
+                                predicted_ms=predicted_ms,
+                            )
+                        )
+                    except Exception:
+                        pass
+            except Exception as e:
+                # A bad dispatch fails *these* requests and leaves the engine
+                # serving; the legacy shim (no on_error) keeps the raise.
+                self._fail_group(reqs, e)
+                return
+            for r, xi in zip(reqs, solutions):
+                if self._on_result is not None:
+                    try:
+                        self._on_result(r.rid, xi)
+                    except Exception as e:
+                        # A result channel that raises fails only ITS request;
+                        # the rest of the batch still delivers.
+                        self._fail_group([r], e)
+                else:
+                    self._results[r.rid] = xi
 
     def record_dispatch(self, timing: ChunkTiming) -> None:
         """Count the layout and Stage-2 implementation one dispatch ran."""
@@ -1376,16 +1381,29 @@ class TridiagSession:
     ) -> Tuple[np.ndarray, ChunkTiming]:
         return self._solve(dl, d, du, b, timed=True)
 
+    def _execute(
+        self, sizes: Sizes, timed: bool, dl: Any, d: Any, du: Any, b: Any
+    ) -> Tuple[Any, ChunkTiming]:
+        """Plan the fused operands of one verb call and run them."""
+        with jax.profiler.TraceAnnotation(spans.LOOKUP):
+            plan = self.plan_for(sizes)
+        x, timing = self._pick_executor(timed).execute(plan, dl, d, du, b)
+        self._engine.record_dispatch(timing)
+        return x, timing
+
     def _solve(
         self, dl: Any, d: Any, du: Any, b: Any, *, timed: bool
     ) -> Tuple[np.ndarray, ChunkTiming]:
-        dl, d, du, b = self._cast(dl, d, du, b)
-        n = int(np.shape(d)[-1])
-        x, timing = self._pick_executor(timed).execute(
-            self.plan_for(n), dl, d, du, b
-        )
-        self._engine.record_dispatch(timing)
-        return self._cast_out(x), timing
+        shape = np.shape(d)
+        systems = math.prod(shape[:-1])
+        with jax.profiler.TraceAnnotation(
+            spans.SOLVE, rows=systems * shape[-1], systems=systems
+        ):
+            with jax.profiler.TraceAnnotation(spans.FUSE):
+                dl, d, du, b = self._cast(dl, d, du, b)
+            x, timing = self._execute(int(shape[-1]), timed, dl, d, du, b)
+            with jax.profiler.TraceAnnotation(spans.SPLIT):
+                return self._cast_out(x), timing
 
     def solve_batched(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
         """Solve B same-size systems given as (B, n) operands."""
@@ -1399,7 +1417,6 @@ class TridiagSession:
     def _solve_batched(
         self, dl: Any, d: Any, du: Any, b: Any, *, timed: bool
     ) -> Tuple[np.ndarray, ChunkTiming]:
-        dl, d, du, b = self._cast(dl, d, du, b)
         if np.ndim(d) != 2:
             raise ValueError(
                 f"solve_batched takes (batch, n) operands, got shape "
@@ -1407,12 +1424,15 @@ class TridiagSession:
                 f"for mixed sizes"
             )
         batch, n = np.shape(d)
-        fused = fuse_systems(dl, d, du, b)
-        x, timing = self._pick_executor(timed).execute(
-            self.plan_for((n,) * batch), *fused
-        )
-        self._engine.record_dispatch(timing)
-        return split_systems(self._cast_out(x), batch), timing
+        with jax.profiler.TraceAnnotation(
+            spans.SOLVE_BATCHED, rows=batch * n, systems=batch
+        ):
+            with jax.profiler.TraceAnnotation(spans.FUSE):
+                dl, d, du, b = self._cast(dl, d, du, b)
+                fused = fuse_systems(dl, d, du, b)
+            x, timing = self._execute((n,) * batch, timed, *fused)
+            with jax.profiler.TraceAnnotation(spans.SPLIT):
+                return split_systems(self._cast_out(x), batch), timing
 
     def solve_many(self, systems: Sequence[System]) -> List[np.ndarray]:
         """Solve a ragged list of ``(dl, d, du, b)`` systems in one dispatch."""
@@ -1426,14 +1446,15 @@ class TridiagSession:
     def _solve_many(
         self, systems: Sequence[System], *, timed: bool
     ) -> Tuple[List[np.ndarray], ChunkTiming]:
-        if self.config.dtype is not None:
-            systems = [self._cast(*s) for s in systems]
-        dl, d, du, b, sizes = fuse_ragged(systems)
-        x, timing = self._pick_executor(timed).execute(
-            self.plan_for(sizes), dl, d, du, b
-        )
-        self._engine.record_dispatch(timing)
-        return split_ragged(self._cast_out(x), sizes), timing
+        with jax.profiler.TraceAnnotation(spans.SOLVE_MANY) as span:
+            with jax.profiler.TraceAnnotation(spans.FUSE):
+                if self.config.dtype is not None:
+                    systems = [self._cast(*s) for s in systems]
+                dl, d, du, b, sizes = fuse_ragged(systems)
+            span.set_metadata(rows=sum(sizes), systems=len(sizes))
+            x, timing = self._execute(sizes, timed, dl, d, du, b)
+            with jax.profiler.TraceAnnotation(spans.SPLIT):
+                return split_ragged(self._cast_out(x), sizes), timing
 
     # -- asynchronous serving ------------------------------------------------
     def submit(self, req: SolveRequest) -> SolveFuture:

@@ -116,7 +116,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core.tridiag import layout as layout_mod
-from repro.core.tridiag import partition
+from repro.core.tridiag import partition, spans
 from repro.core.tridiag.layout import resolve_layout
 from repro.core.tridiag.reference import thomas_numpy
 from repro.core.tridiag.thomas import thomas as thomas_scan
@@ -1171,10 +1171,9 @@ def _sharded_fused_callable(
     def per_shard(dl: Any, d: Any, du: Any, b: Any) -> Any:
         idx = jax.lax.axis_index(MESH_AXIS_CHUNKS)
         perm = [(i, i - 1) for i in range(1, num_shards)]
-        halo = [
-            jax.lax.ppermute(a[:m], MESH_AXIS_CHUNKS, perm)
-            for a in (dl, d, du, b)
-        ]
+        heads = [a[:m] for a in (dl, d, du, b)]
+        with jax.named_scope(spans.HALO):
+            halo = [jax.lax.ppermute(a, MESH_AXIS_CHUNKS, perm) for a in heads]
         # ppermute delivers zeros to the shard nobody sends to (the last):
         # patch its halo diagonal to 1 so the halo is an exact identity
         # block, matching the unsharded end-of-axis convention exactly.
@@ -1190,20 +1189,23 @@ def _sharded_fused_callable(
                 # next block, or the exchanged/patched halo for the last)
                 return jax.lax.slice_in_dim(a, lo * m, (hi + 1) * m, axis=-1)
 
-            coeffs.append(
-                _trim_halo(stage1(sl(ext[0]), sl(ext[1]), sl(ext[2]), sl(ext[3])), hi - lo)
-            )
+            chunk = [sl(a) for a in ext]
+            with jax.named_scope(spans.STAGE1):
+                c = stage1(*chunk)
+            coeffs.append(_trim_halo(c, hi - lo))
         red_local = [
             jnp.concatenate([getattr(c, f) for c in coeffs], axis=-1)
             if len(coeffs) > 1
             else getattr(coeffs[0], f)
             for f in ("red_dl", "red_d", "red_du", "red_b")
         ]
-        red = [
-            jax.lax.all_gather(r, MESH_AXIS_CHUNKS, tiled=True)
-            for r in red_local
-        ]
-        s = reduced_solve(*red)  # replicated (P,) solve on every device
+        with jax.named_scope(spans.REDUCED_GATHER):
+            red = [
+                jax.lax.all_gather(r, MESH_AXIS_CHUNKS, tiled=True)
+                for r in red_local
+            ]
+        with jax.named_scope(spans.STAGE2):
+            s = reduced_solve(*red)  # replicated (P,) solve on every device
 
         base = idx * bps
         outs = []
@@ -1223,7 +1225,8 @@ def _sharded_fused_callable(
                 s_left = jax.lax.dynamic_slice_in_dim(
                     s, base + lo - 1, 1, axis=-1
                 )
-            outs.append(stage3_ghost(c, s_chunk, s_left))
+            with jax.named_scope(spans.STAGE3):
+                outs.append(stage3_ghost(c, s_chunk, s_left))
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
 
     mesh = mesh_for(mesh_devices, MESH_AXIS_CHUNKS)
@@ -1292,9 +1295,12 @@ def _fused_callable(
         wide_reduced = backend.make_wide_reduced_solve()
 
         def wide_pipeline(*ops: Any) -> Any:
-            c = wide_stage1(*ops)
-            s = wide_reduced(c.red_dl, c.red_d, c.red_du, c.red_b)
-            return wide_stage3(c, s)
+            with jax.named_scope(spans.STAGE1):
+                c = wide_stage1(*ops)
+            with jax.named_scope(spans.STAGE2):
+                s = wide_reduced(c.red_dl, c.red_d, c.red_du, c.red_b)
+            with jax.named_scope(spans.STAGE3):
+                return wide_stage3(c, s)
 
         if mesh_devices is not None:
             lane_spec = PartitionSpec(None, None, MESH_AXIS_BATCH)
@@ -1307,9 +1313,11 @@ def _fused_callable(
             )
 
         def fused(dl: Any, d: Any, du: Any, b: Any) -> Any:
-            ops = layout_mod.interleave_operands(dl, d, du, b, sizes, m)
+            with jax.named_scope(spans.INTERLEAVE):
+                ops = layout_mod.interleave_operands(dl, d, du, b, sizes, m)
             xw = wide_pipeline(*ops)
-            return layout_mod.deinterleave(xw, sizes, m)
+            with jax.named_scope(spans.DEINTERLEAVE):
+                return layout_mod.deinterleave(xw, sizes, m)
 
     elif mesh_devices is not None:
         stage2 = backend.reduced_solve_impl((plan.num_blocks,), dtype)
@@ -1329,16 +1337,18 @@ def _fused_callable(
                 def sl(a: Any, lo: int = lo, hi_halo: int = hi_halo) -> Any:
                     return jax.lax.slice_in_dim(a, lo * m, hi_halo * m, axis=-1)
 
-                coeffs.append(
-                    _trim_halo(stage1(sl(dl), sl(d), sl(du), sl(b)), hi - lo)
-                )
+                chunk = [sl(a) for a in (dl, d, du, b)]
+                with jax.named_scope(spans.STAGE1):
+                    c = stage1(*chunk)
+                coeffs.append(_trim_halo(c, hi - lo))
             red = [
                 jnp.concatenate([getattr(c, f) for c in coeffs], axis=-1)
                 if len(coeffs) > 1
                 else getattr(coeffs[0], f)
                 for f in ("red_dl", "red_d", "red_du", "red_b")
             ]
-            s = reduced_solve(*red)
+            with jax.named_scope(spans.STAGE2):
+                s = reduced_solve(*red)
             outs = []
             for (lo, hi), c in zip(plan.chunk_bounds, coeffs):
                 s_chunk = jax.lax.slice_in_dim(s, lo, hi, axis=-1)
@@ -1347,7 +1357,8 @@ def _fused_callable(
                     if lo == 0
                     else jax.lax.slice_in_dim(s, lo - 1, lo, axis=-1)
                 )
-                outs.append(stage3_ghost(c, s_chunk, s_left_edge))
+                with jax.named_scope(spans.STAGE3):
+                    outs.append(stage3_ghost(c, s_chunk, s_left_edge))
             return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
 
     if not donate:
@@ -1370,12 +1381,15 @@ class FusedExecutor:
 
     Where :class:`PlanExecutor` dispatches each chunk from a Python loop and
     round-trips through the host for the Stage-2 reduced solve (the paper's
-    CPU stage — which is what makes its phase breakdown measurable), this
-    executor trades observability for latency: zero host round-trips between
-    operand hand-off and solution split, one dispatch regardless of chunk
-    count. The returned :class:`ChunkTiming` therefore carries only
-    ``t_total_ms`` — per-phase times are structurally unobservable inside a
-    fused executable (use the staged path for the Eq.-5 campaigns).
+    CPU stage — which is what makes its phase breakdown measurable on the
+    host clock), this executor trades that for latency: zero host
+    round-trips between operand hand-off and solution split, one dispatch
+    regardless of chunk count. The returned :class:`ChunkTiming` therefore
+    carries only ``t_total_ms`` (use the staged path for the Eq.-5
+    campaigns). Per-phase times are observable in a profiler trace instead:
+    the executable tags each stage's device ops with a named scope, and
+    :meth:`execute` emits host spans for each call's lookup, launch, wait and
+    fetch (:mod:`repro.core.tridiag.spans`).
 
     ``donate=True`` (default) donates the four diagonals to the executable;
     numpy operands are copied to device per call (always safe to reuse),
@@ -1477,9 +1491,13 @@ class FusedExecutor:
             jax.ShapeDtypeStruct(a.shape, jax.dtypes.canonicalize_dtype(a.dtype))
             for a in ops
         ]
-        fn, stage2 = _fused_callable(
-            plan, self.backend, self.donate, avals, layout, shard_devices
-        )
+        with jax.profiler.TraceAnnotation(
+            spans.COMPILE, layout=layout, rows=plan.total_size
+        ) as span:
+            fn, stage2 = _fused_callable(
+                plan, self.backend, self.donate, avals, layout, shard_devices
+            )
+            span.set_metadata(stage2=stage2)
         entry = (fn, layout, stage2)
         with _CACHE_LOCK:
             existing = _EXEC_CACHE.get(key)
@@ -1500,23 +1518,34 @@ class FusedExecutor:
         du: Any,
         b: Any,
     ) -> Tuple[np.ndarray, ChunkTiming]:
-        ops = [
-            a if isinstance(a, (np.ndarray, jax.Array)) else np.asarray(a)
-            for a in (dl, d, du, b)
-        ]
-        # The AOT-compiled executable is strict about argument dtypes; mirror
-        # jit's canonicalization up front (a no-op unless e.g. fp64 operands
-        # arrive while x64 is disabled).
-        ops = [_canonical_operand(a) for a in ops]
-        n = ops[1].shape[-1]
-        if n != plan.total_size:
-            raise ValueError(
-                f"operands have {n} rows but the plan lays out {plan.total_size}"
-            )
-        self.backend.check_dtype(ops[1].dtype)
-        fn, layout, stage2 = self._executable(plan, ops)
+        with jax.profiler.TraceAnnotation(spans.LOOKUP):
+            ops = [
+                a if isinstance(a, (np.ndarray, jax.Array)) else np.asarray(a)
+                for a in (dl, d, du, b)
+            ]
+            # The AOT-compiled executable is strict about argument dtypes;
+            # mirror jit's canonicalization up front (a no-op unless e.g.
+            # fp64 operands arrive while x64 is disabled).
+            ops = [_canonical_operand(a) for a in ops]
+            n = ops[1].shape[-1]
+            if n != plan.total_size:
+                raise ValueError(
+                    f"operands have {n} rows but the plan lays out {plan.total_size}"
+                )
+            self.backend.check_dtype(ops[1].dtype)
+            fn, layout, stage2 = self._executable(plan, ops)
         t0 = time.perf_counter()
-        x = np.asarray(fn(*ops))  # blocks until the solution is on the host
+        with jax.profiler.TraceAnnotation(spans.LAUNCH):
+            out = fn(*ops)
+            # Queue the device-to-host copy behind the solve now, as
+            # np.asarray alone would: waiting first and copying after would
+            # add a host round trip to every call.
+            out.copy_to_host_async()
+        # The wait is the device's time, the fetch what is left of the copy.
+        with jax.profiler.TraceAnnotation(spans.WAIT):
+            out.block_until_ready()
+        with jax.profiler.TraceAnnotation(spans.FETCH):
+            x = np.asarray(out)
         t1 = time.perf_counter()
         return x, ChunkTiming(
             num_chunks=plan.num_chunks,

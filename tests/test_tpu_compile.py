@@ -152,3 +152,51 @@ def test_wide_thomas_kernel(one_chip, x64):
         *[_f32(one_chip, p, bsz)] * 4,
     )
     assert _has_kernel(c)
+
+
+def _bench_trace():
+    """The benchmark's trace reduction, loaded by path (``bench`` is not a
+    package of the solver)."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    name = "bench_trace_for_tests"
+    if name not in sys.modules:
+        path = Path(__file__).resolve().parents[1] / "bench" / "trace.py"
+        spec = importlib.util.spec_from_file_location(name, path)
+        sys.modules[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[name])
+    return sys.modules[name]
+
+
+@pytest.mark.parametrize(
+    "sizes, layout, kernels",
+    [
+        ((1000,) * 16, "interleaved", ("_stage1_impl_wide", "_thomas_impl_wide", "_stage3_impl_wide")),
+        (40_000, "system-major", ("_stage1_impl", "_thomas_impl", "_stage3_impl")),
+    ],
+    ids=["interleaved", "system-major"],
+)
+def test_fused_kernels_keep_their_trace_names(one_chip, sizes, layout, kernels):
+    """Under the device scopes, the fused executable's Pallas custom calls
+    keep the names the benchmark's trace reduction classes them by, and
+    each carries its stage's scope."""
+    import re
+
+    from repro.core.tridiag import spans
+    from repro.core.tridiag.plan import _fused_callable, build_plan
+
+    trace = _bench_trace()
+    plan = build_plan(sizes, M, num_chunks=1)
+    avals = [_f32(one_chip, plan.total_size)] * 4
+    fn, _ = _fused_callable(plan, PallasBackend(interpret=False), True, avals, layout)
+    calls = re.findall(
+        r"^\s*%?([\w.-]+) = .* custom-call\(.*op_name=\"([^\"]*)\"", fn.as_text(), re.M
+    )
+    found = {trace.op_name(name).rsplit(".", 1)[0]: scope for name, scope in calls}
+    assert tuple(sorted(found)) == tuple(sorted(kernels))
+    for kernel, stage, scope in zip(kernels, ("stage1", "stage2", "stage3"),
+                                    (spans.STAGE1, spans.STAGE2, spans.STAGE3)):
+        assert trace.op_class(kernel + ".1") == stage
+        assert f"/{scope}/" in found[kernel]
