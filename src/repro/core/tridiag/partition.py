@@ -15,11 +15,18 @@ of size P solved in Stage 2.
 Stage 1 and Stage 3 are embarrassingly parallel over blocks — on the GPU of the
 paper each CUDA stream takes a slice of blocks; here the block axis is the one
 we shard/chunk (`chunked.py`, `repro.kernels.partition_stage1`).
+
+The reduced system is the Schur complement of the matrix onto the interface
+unknowns, so it keeps strict diagonal dominance (or symmetric positive
+definiteness) and the method applies to it again:
+:func:`partition_solve_recursive` partitions it until it is small enough for a
+direct solve, which is how the fused Pallas path runs Stage 2 for reduced
+systems too large for the Thomas kernel's VMEM tiles.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -119,3 +126,56 @@ def partition_solve(dl: Array, d: Array, du: Array, b: Array, m: int = 10) -> Ar
     coeffs = partition_stage1(dl, d, du, b, m)
     s = partition_stage2(coeffs)
     return partition_stage3(coeffs, s)
+
+
+def partition_levels(p: int, m: int, fits: Callable[[int], bool]) -> int:
+    """Levels :func:`partition_solve_recursive` takes on ``p`` rows: the
+    fewest partitions, each taking P rows to ceil(P / m), that leave a system
+    ``fits`` accepts (or a single row)."""
+    if m < 2:
+        raise ValueError("sub-system size m must be >= 2")
+    levels = 0
+    while p > 1 and not fits(p):
+        p = -(-p // m)
+        levels += 1
+    return levels
+
+
+def partition_solve_recursive(
+    dl: Array,
+    d: Array,
+    du: Array,
+    b: Array,
+    *,
+    m: int,
+    stage1: Callable[..., PartitionCoeffs],
+    stage3: Callable[[PartitionCoeffs, Array], Array],
+    direct: Callable[..., Array],
+    fits: Callable[[int], bool],
+) -> Array:
+    """Solve ``(..., P)`` tridiagonal systems, partitioning while too large.
+
+    While ``fits(P)`` is false the system is padded to a multiple of ``m``
+    with identity rows (``dl = 0, d = 1, du = 0, b = 0``: their unknowns are
+    exactly 0 and couple to nothing), ``stage1`` reduces it to its ceil(P / m)
+    interface rows, the reduced system is solved the same way, and ``stage3``
+    back-substitutes; the padding is dropped. ``direct`` solves the system
+    that fits. The stages are any pair with the signatures of
+    :func:`partition_stage1` (``m`` bound) and :func:`partition_stage3`, so
+    the same recursion runs on the jnp stages and on the Pallas kernels; the
+    depth is :func:`partition_levels` of P.
+    """
+    p = d.shape[-1]
+    if p <= 1 or fits(p):
+        return direct(dl, d, du, b)
+    extra = -p % m
+    if extra:
+        widths = [(0, 0)] * (d.ndim - 1) + [(0, extra)]
+        dl, du, b = (jnp.pad(a, widths) for a in (dl, du, b))
+        d = jnp.pad(d, widths, constant_values=1)
+    c = stage1(dl, d, du, b)
+    s = partition_solve_recursive(
+        c.red_dl, c.red_d, c.red_du, c.red_b,
+        m=m, stage1=stage1, stage3=stage3, direct=direct, fits=fits,
+    )
+    return stage3(c, s)[..., :p]

@@ -64,7 +64,8 @@ decomposition) observable, and the path every ``measure_*`` campaign times.
 *entire* three-stage solve — chunk slicing via ``lax.slice`` inside the
 trace (halo blocks included), the reduced solve **on device**
 (:class:`StageBackend.make_reduced_solve`: the jnp Thomas scan by default,
-the ``repro.kernels.thomas`` Pallas kernel on the Pallas backend), and the
+the ``repro.kernels.thomas`` Pallas kernel on the Pallas backend, which
+partitions reduced systems too large for it recursively first), and the
 ghost-block splicing of stage 3 — into ONE jitted callable with
 ``donate_argnums`` on the four diagonals. Zero host round-trips between
 operand hand-off and solution split, and a single XLA dispatch instead of
@@ -177,14 +178,17 @@ class StageBackend:
     must be hashable (frozen dataclasses): they key the module-level stage
     cache together with ``m``.
 
-    ``make_reduced_solve()`` returns the *device-side* Stage-2 solver used by
-    the fused dispatch path (``(red_dl, red_d, red_du, red_b) -> s``, traced
-    into the fused executable). The default is the pure-jnp Thomas scan; the
-    Pallas backend routes 1-D/2-D reduced systems that fit VMEM through the
-    ``repro.kernels.thomas`` kernel and larger ones through the scan.
-    :meth:`reduced_solve_impl` names the choice for a shape before anything
-    is traced, so executors can report it. The staged path never calls it —
-    its Stage 2 stays on the host (``thomas_numpy``), as in the paper.
+    ``make_reduced_solve(m)`` returns the *device-side* Stage-2 solver used
+    by the fused dispatch path (``(red_dl, red_d, red_du, red_b) -> s``,
+    traced into the fused executable of a plan with block size ``m``). The
+    default is the pure-jnp Thomas scan; the Pallas backend routes 1-D/2-D
+    reduced systems that fit VMEM through the ``repro.kernels.thomas`` kernel
+    and partitions larger ones recursively on its Stage-1/Stage-3 kernels
+    until they fit. :meth:`reduced_solve_impl` names the choice for a shape
+    before anything is traced, so executors can report it, and
+    :meth:`reduced_solve_levels` counts the recursion's levels. The staged
+    path never calls it — its Stage 2 stays on the host (``thomas_numpy``),
+    as in the paper.
 
     :meth:`check_dtype` refuses operand dtypes the backend cannot run, and
     :meth:`interpret_mode` says whether its kernels run interpreted (None
@@ -209,13 +213,18 @@ class StageBackend:
     def make_stage3(self) -> Callable:
         raise NotImplementedError
 
-    def make_reduced_solve(self) -> Callable:
+    def make_reduced_solve(self, m: int) -> Callable:
         return thomas_scan
 
     def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
         """The Stage-2 implementation ``make_reduced_solve`` runs on reduced
         rows of ``shape`` (``(..., P)``)."""
         return "thomas_scan"
+
+    def reduced_solve_levels(self, shape: Tuple[int, ...], dtype: Any, m: int) -> int:
+        """Partition levels that implementation takes before its direct
+        solve (0 unless it is ``"partition_recursive"``)."""
+        return 0
 
     def wide_reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
         """Same for ``make_wide_reduced_solve`` on ``(P, B)`` rows."""
@@ -263,8 +272,11 @@ class PallasBackend(StageBackend):
     Compiled kernels take fp32 only (Mosaic has no fp64): fp64 operands are
     refused with a ``ValueError`` rather than downcast. The reduced solve
     stays on the Thomas kernel while its tiles fit VMEM
-    (``repro.kernels.thomas.ops.thomas_fits_vmem``, decided from the shape)
-    and runs the XLA scan beyond that.
+    (``repro.kernels.thomas.ops.thomas_fits_vmem``, decided from the shape);
+    beyond that it is ``"partition_recursive"``: the Stage-1 and Stage-3
+    kernels partition it at the plan's ``m``, as often as it takes to fit
+    (:func:`repro.core.tridiag.partition.partition_solve_recursive`).
+    Reduced rows with more than two dimensions take the XLA scan.
     """
 
     name = "pallas"
@@ -336,28 +348,46 @@ class PallasBackend(StageBackend):
                 "or fp32 operands"
             )
 
-    def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+    @staticmethod
+    def _thomas_fits(shape: Tuple[int, ...], dtype: Any) -> Callable[[int], bool]:
+        """Whether the Thomas kernel takes P rows with ``shape``'s lanes."""
         from repro.kernels.thomas.ops import thomas_fits_vmem
 
-        # The kernel's grid is (batch,)-tiled: 1-D and 2-D reduced systems
-        # route through it while their tiles fit VMEM; exotic extra leading
-        # dims and longer systems take the scan.
-        if len(shape) <= 2:
-            lanes = shape[0] if len(shape) == 2 else 1
-            if thomas_fits_vmem(shape[-1], lanes, np.dtype(dtype).itemsize):
-                return "thomas_pallas"
-        return "thomas_scan"
+        lanes = shape[0] if len(shape) == 2 else 1
+        itemsize = np.dtype(dtype).itemsize
+        return lambda p: thomas_fits_vmem(p, lanes, itemsize)
 
-    def make_reduced_solve(self) -> Callable:
+    def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
+        # The kernel's grid is (batch,)-tiled: 1-D and 2-D reduced systems
+        # route through it, partitioned first if its tiles do not fit VMEM;
+        # exotic extra leading dims take the scan.
+        if len(shape) > 2:
+            return "thomas_scan"
+        if self._thomas_fits(shape, dtype)(shape[-1]):
+            return "thomas_pallas"
+        return "partition_recursive"
+
+    def reduced_solve_levels(self, shape: Tuple[int, ...], dtype: Any, m: int) -> int:
+        if len(shape) > 2:
+            return 0
+        return partition.partition_levels(shape[-1], m, self._thomas_fits(shape, dtype))
+
+    def make_reduced_solve(self, m: int) -> Callable:
         from repro.kernels.thomas.ops import thomas_pallas
+
+        stage1, stage3 = jitted_stages(m, self)
+        direct = partial(thomas_pallas, interpret=self.interpret)
 
         def reduced_solve(red_dl: Any, red_d: Any, red_du: Any, red_b: Any) -> Any:
             red_d = jnp.asarray(red_d)
-            if self.reduced_solve_impl(red_d.shape, red_d.dtype) == "thomas_pallas":
-                return thomas_pallas(
-                    red_dl, red_d, red_du, red_b, interpret=self.interpret
-                )
-            return thomas_scan(red_dl, red_d, red_du, red_b)
+            if self.reduced_solve_impl(red_d.shape, red_d.dtype) == "thomas_scan":
+                return thomas_scan(red_dl, red_d, red_du, red_b)
+            # On a system that fits this is the direct kernel call alone.
+            return partition.partition_solve_recursive(
+                red_dl, red_d, red_du, red_b,
+                m=m, stage1=stage1, stage3=stage3, direct=direct,
+                fits=self._thomas_fits(red_d.shape, red_d.dtype),
+            )
 
         return reduced_solve
 
@@ -436,11 +466,14 @@ class AutoBackend(StageBackend):
     def make_stage3(self) -> Callable:
         return self.resolve().make_stage3()
 
-    def make_reduced_solve(self) -> Callable:
-        return self.resolve().make_reduced_solve()
+    def make_reduced_solve(self, m: int) -> Callable:
+        return self.resolve().make_reduced_solve(m)
 
     def reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
         return self.resolve().reduced_solve_impl(shape, dtype)
+
+    def reduced_solve_levels(self, shape: Tuple[int, ...], dtype: Any, m: int) -> int:
+        return self.resolve().reduced_solve_levels(shape, dtype, m)
 
     def wide_reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
         return self.resolve().wide_reduced_solve_impl(shape, dtype)
@@ -1150,7 +1183,7 @@ def _sharded_fused_callable(
       operands to the previous shard, closing the right-neighbour reference
       of every span's last reduced row;
     * one ``all_gather`` of the per-shard reduced rows, after which every
-      device runs the (tiny, replicated) Stage-2 solve locally and slices
+      device runs the replicated Stage-2 solve locally and slices
       out its own interface unknowns — the "scatter" is a local
       ``dynamic_slice`` of the replicated solution, not a collective.
 
@@ -1166,7 +1199,7 @@ def _sharded_fused_callable(
     local_bounds = plan.local_chunk_bounds
     stage1, _ = jitted_stages(m, backend)
     stage3_ghost = jitted_stage3_ghost(backend)
-    reduced_solve = backend.make_reduced_solve()
+    reduced_solve = backend.make_reduced_solve(m)
 
     def per_shard(dl: Any, d: Any, du: Any, b: Any) -> Any:
         idx = jax.lax.axis_index(MESH_AXIS_CHUNKS)
@@ -1254,7 +1287,7 @@ def _fused_callable(
     The chunk structure is baked in from the (static) plan: stage 1 slices
     every chunk + halo out of the fused operands via ``lax.slice`` inside the
     trace, the reduced rows are concatenated and solved ON DEVICE
-    (``backend.make_reduced_solve()``), and stage 3 splices each chunk's
+    (``backend.make_reduced_solve(m)``), and stage 3 splices each chunk's
     ghost block in-trace. With ``donate=True`` the four diagonals are donated
     to XLA (``donate_argnums=(0, 1, 2, 3)``), so the solve can reuse their
     buffers in place — callers passing device arrays give up ownership.
@@ -1329,7 +1362,7 @@ def _fused_callable(
         )
         stage1, _ = jitted_stages(m, backend)
         stage3_ghost = jitted_stage3_ghost(backend)
-        reduced_solve = backend.make_reduced_solve()
+        reduced_solve = backend.make_reduced_solve(m)
 
         def fused(dl: Any, d: Any, du: Any, b: Any) -> Any:
             coeffs = []
@@ -1491,13 +1524,20 @@ class FusedExecutor:
             jax.ShapeDtypeStruct(a.shape, jax.dtypes.canonicalize_dtype(a.dtype))
             for a in ops
         ]
+        levels = (
+            0
+            if layout == "interleaved"
+            else self.backend.reduced_solve_levels(
+                tuple(ops[1].shape[:-1]) + (plan.num_blocks,), avals[1].dtype, plan.m
+            )
+        )
         with jax.profiler.TraceAnnotation(
             spans.COMPILE, layout=layout, rows=plan.total_size
         ) as span:
             fn, stage2 = _fused_callable(
                 plan, self.backend, self.donate, avals, layout, shard_devices
             )
-            span.set_metadata(stage2=stage2)
+            span.set_metadata(stage2=stage2, stage2_levels=levels)
         entry = (fn, layout, stage2)
         with _CACHE_LOCK:
             existing = _EXEC_CACHE.get(key)

@@ -6,7 +6,9 @@ Four kernels (each with ``ops.py`` jit wrapper and ``ref.py`` pure-jnp oracle):
                          Also the device-side Stage-2 reduced solve of the
                          fused dispatch path (`PallasBackend.make_reduced_solve`
                          traces it into the single-dispatch executable, so a
-                         fused solve never round-trips to the host).
+                         fused solve never round-trips to the host; reduced
+                         systems too large for its VMEM tiles are first
+                         partitioned on the two stage kernels below).
 - ``partition_stage1`` — per-block interior elimination producing the three
                          spike solutions (y, v, w); the paper's Stage-1 kernel.
 - ``partition_stage3`` — per-block back-substitution; the paper's Stage-3 kernel.

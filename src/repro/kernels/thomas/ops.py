@@ -4,9 +4,12 @@ Besides its original role (B independent solves), this kernel is the
 device-side Stage-2 reduced solver of the fused dispatch path:
 ``repro.core.tridiag.plan.PallasBackend.make_reduced_solve`` traces
 :func:`thomas_pallas` into the single-dispatch fused executable (1-D reduced
-systems ride the batch-1 path below) while :func:`thomas_fits_vmem` holds,
-and the XLA scan beyond it, so a fused Pallas solve keeps all three
-partition stages on device at every size.
+systems ride the batch-1 path below) while :func:`thomas_fits_vmem` holds.
+Beyond it the backend partitions the reduced system on the Stage-1 and
+Stage-3 kernels until what is left fits, and solves that here
+(:func:`repro.core.tridiag.partition.partition_solve_recursive`), so a fused
+Pallas solve keeps all three partition stages on device, in kernels, at
+every size.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
 """Decisions the TPU path takes before anything compiles, checked on the CPU:
-the Stage-2 choice between the Thomas kernel and the scan, the refusal of
+the Stage-2 choice between the Thomas kernel and the recursive partition,
+the refusal of
 fp64 on compiled Pallas kernels, the compile-cache location, and
 ``chip_smoke.py`` refusing to run without a TPU."""
 
@@ -35,11 +36,23 @@ P_MAX_1D = 4680  # largest fp32 1-D reduced system on one 128-lane tile
 @pytest.mark.parametrize(
     "p, impl",
     [(P_MAX_1D - 8, "thomas_pallas"), (P_MAX_1D, "thomas_pallas"),
-     (P_MAX_1D + 1, "thomas_scan"), (10**6, "thomas_scan")],
+     (P_MAX_1D + 1, "partition_recursive"), (10**6, "partition_recursive")],
 )
 def test_stage2_rule_at_threshold(p, impl):
     assert PallasBackend().reduced_solve_impl((p,), np.float32) == impl
     assert thomas_fits_vmem(p, 1, 4) == (impl == "thomas_pallas")
+
+
+@pytest.mark.parametrize(
+    "shape, levels",
+    [((P_MAX_1D,), 0), ((P_MAX_1D + 1,), 1), ((46_800,), 1), ((10**5,), 2),
+     ((10**6,), 3), ((4, 10**5), 2), ((2, 3, 10**5), 0)],
+)
+def test_stage2_levels_from_the_shape(shape, levels):
+    """Depth: the fewest partitions at the plan's m that bring the reduced
+    system under the Thomas kernel's VMEM rule; the scan (> 2-D) has none."""
+    assert PallasBackend().reduced_solve_levels(shape, np.float32, 10) == levels
+    assert ReferenceBackend().reduced_solve_levels(shape, np.float32, 10) == 0
 
 
 def test_stage2_rule_is_the_kernel_formula():
@@ -52,12 +65,15 @@ def test_stage2_rule_is_the_kernel_formula():
     backend = PallasBackend()
     assert backend.wide_reduced_solve_impl((2336, 1024), np.float32) == "thomas_pallas_wide"
     assert backend.wide_reduced_solve_impl((2344, 1024), np.float32) == "thomas_scan_wide"
-    # fp64 tiles are twice as wide in bytes.
-    assert backend.reduced_solve_impl((P_MAX_1D, ), np.float64) == "thomas_scan"
+    # fp64 tiles are twice as wide in bytes: the rule partitions them once.
+    assert backend.reduced_solve_impl((P_MAX_1D, ), np.float64) == "partition_recursive"
+    assert backend.reduced_solve_levels((P_MAX_1D, ), np.float64, 10) == 1
+    # only reduced rows with more than two dimensions keep the scan
+    assert backend.reduced_solve_impl((2, 3, 8), np.float32) == "thomas_scan"
     assert ReferenceBackend().reduced_solve_impl((8,), np.float32) == "thomas_scan"
 
 
-@pytest.mark.parametrize("n, impl", [(46_800, "thomas_pallas"), (46_810, "thomas_scan")])
+@pytest.mark.parametrize("n, impl", [(46_800, "thomas_pallas"), (46_810, "partition_recursive")])
 def test_session_reports_stage2_either_side(n, impl):
     """Both sides of the threshold solve correctly through the fused path,
     and session.stats names the Stage-2 implementation that ran."""

@@ -163,6 +163,28 @@ class TestShardedParity:
         else:
             assert np.max(np.abs(xs - xu)) / np.max(np.abs(ref)) < tol(dtype)
 
+    def test_recursive_reduced_solve(self, multi_device_count, dtype):
+        """P = 4,682 reduced rows: past the Thomas kernel's VMEM rule, so the
+        Pallas backend partitions the replicated reduced solve on every
+        device, and the sharded solve still matches the unsharded one."""
+        n = 46_820
+        dl, d, du, b, _ = make_diag_dominant_system(n, seed=5, dtype=dtype)
+        ref = thomas_numpy(dl, d, du, b)
+        plan = build_plan(n, M, num_chunks=2, shards=2)
+        assert plan.shards == 2
+        xu, tu = FusedExecutor(backend="pallas", donate=False).execute(
+            plan, dl, d, du, b
+        )
+        xs, ts = FusedExecutor(backend="pallas", donate=False, mesh=2).execute(
+            plan, dl, d, du, b
+        )
+        assert tu.stage2 == ts.stage2 == "partition_recursive"
+        assert rel_err(xs, ref) < tol(dtype)
+        if dtype is np.float64:
+            np.testing.assert_array_equal(xs, xu)
+        else:
+            assert np.max(np.abs(xs - xu)) / np.max(np.abs(ref)) < tol(dtype)
+
     @pytest.mark.parametrize("layout", ["system-major", "interleaved"])
     def test_session_batched(self, multi_device_count, dtype, layout):
         B, n = 64, 320

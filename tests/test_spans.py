@@ -113,7 +113,26 @@ def test_verb_spans_nest_in_order(tmp_path: Path, verb: str):
             assert compiles[0].stats["rows"] == rows
             assert compiles[0].stats["layout"] in ("system-major", "interleaved")
             assert "stage2" in compiles[0].stats
+            assert compiles[0].stats["stage2_levels"] == 0
     assert not [s for s in found if s.name == spans.BATCH]
+
+
+def test_compile_span_carries_the_recursion_depth(tmp_path: Path):
+    """A 10⁶-row solve on the Pallas kernels (interpreted here) reduces to
+    P = 10⁵ rows, past the Thomas kernel's VMEM rule: the compile span names
+    the recursive partition and its two levels, and the session counts each
+    call under that name."""
+    big = tuple(a.astype(np.float32) for a in system(np.random.default_rng(11), 10**6))
+    config = SolverConfig(m=M, backend="pallas", dtype=np.float32, num_chunks=1)
+    clear_executable_cache()
+    with TridiagSession(config) as session:
+        found = record(tmp_path, lambda: session.solve(*big))
+        session.solve(*big)
+        assert session.stats["stage2"] == {"partition_recursive": 2}
+    (compile_span,) = [s for s in found if s.name == spans.COMPILE]
+    assert compile_span.stats["stage2"] == "partition_recursive"
+    assert compile_span.stats["stage2_levels"] == 2
+    assert compile_span.stats["rows"] == 10**6
 
 
 def test_served_batch_span_holds_the_executor_spans(tmp_path: Path):

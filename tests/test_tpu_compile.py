@@ -114,12 +114,26 @@ def test_thomas_kernel_at_largest_kept_p(one_chip, x64):
 
 def test_fused_stage2_choice_p1e6(one_chip, x64):
     """The reduced solve the fused executable traces for n = 10⁷ (P = 10⁶):
-    too large for the kernel's VMEM tiles, so the on-device scan."""
+    too large for the kernel's VMEM tiles, so three levels of the partition
+    on the Stage-1 and Stage-3 kernels (10⁶ → 10⁵ → 10⁴ → 10³ rows), then
+    the Thomas kernel, and no XLA loop."""
+    import re
+
     p = N_LARGE // M
     backend = PallasBackend(interpret=False)
-    assert backend.reduced_solve_impl((p,), np.float32) == "thomas_scan"
-    c = _compile(backend.make_reduced_solve(), *[_f32(one_chip, p)] * 4)
-    assert not _has_kernel(c)
+    assert backend.reduced_solve_impl((p,), np.float32) == "partition_recursive"
+    assert backend.reduced_solve_levels((p,), np.float32, M) == 3
+    text = _compile(backend.make_reduced_solve(M), *[_f32(one_chip, p)] * 4).as_text()
+    kernels = [
+        name.rsplit(".", 1)[0]
+        for name in re.findall(
+            r"^\s*%?([\w.-]+) = .* custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+            text,
+            re.M,
+        )
+    ]
+    assert sorted(kernels) == ["_stage1_impl"] * 3 + ["_stage3_impl"] * 3 + ["_thomas_impl"]
+    assert " while(" not in text
 
 
 def test_wide_stage1_kernel(one_chip, x64):

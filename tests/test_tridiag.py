@@ -1,5 +1,7 @@
 """Unit + property tests for the Thomas and partition tridiagonal solvers."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -7,6 +9,7 @@ from repro.core.tridiag import ensure_x64
 
 ensure_x64()
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core.tridiag import (  # noqa: E402
@@ -15,10 +18,15 @@ from repro.core.tridiag import (  # noqa: E402
     partition_solve,
     partition_stage1,
     partition_stage2,
+    partition_stage3,
     thomas,
     thomas_numpy,
     tridiag_matvec,
     tridiag_to_dense,
+)
+from repro.core.tridiag.partition import (  # noqa: E402
+    partition_levels,
+    partition_solve_recursive,
 )
 
 
@@ -103,6 +111,63 @@ def test_partition_m_must_divide():
     dl, d, du, b, _ = make_diag_dominant_system(20, seed=0)
     with pytest.raises(AssertionError):
         partition_solve(*map(jnp.asarray, (dl, d, du, b)), m=7)
+
+
+# --------------------------------------------------- recursive partition ----
+def _recursive_solve(ops, m, threshold):
+    """The recursion on the jnp stages, direct below ``threshold`` rows;
+    returns the solution and the row counts each Stage 1 saw."""
+    seen = []
+
+    def stage1(*a):
+        seen.append(a[1].shape[-1])
+        return partition_stage1(*a, m=m)
+
+    solve = jax.jit(partial(
+        partition_solve_recursive, m=m, stage1=stage1, stage3=partition_stage3,
+        direct=thomas, fits=lambda p: p <= threshold,
+    ))
+    return np.asarray(solve(*ops)), seen
+
+
+#: (n, m, threshold, the rows Stage 1 sees at each level after padding)
+RECURSION_CASES = [
+    (64, 10, 64, []),                    # fits: the direct solve alone
+    (1000, 10, 100, [1000]),             # every level a multiple of m
+    (1234, 10, 50, [1240, 130]),         # 1234 -> 124 -> 13, both padded
+    (997, 10, 8, [1000, 100, 10]),       # 997 -> 100 -> 10 -> 1
+    (500, 3, 20, [501, 168, 57]),        # odd m: 500 -> 167 -> 56 -> 19
+]
+
+
+@pytest.mark.parametrize("batch", [(), (3,)], ids=["1d", "batched"])
+@pytest.mark.parametrize("n, m, threshold, rows", RECURSION_CASES)
+def test_recursive_partition_fp64(n, m, threshold, rows, batch):
+    dl, d, du, b, x_true = make_diag_dominant_system(n, seed=n + m, batch=batch)
+    x, seen = _recursive_solve((dl, d, du, b), m, threshold)
+    assert seen == rows
+    assert partition_levels(n, m, lambda p: p <= threshold) == len(rows)
+    assert x.shape == d.shape
+    assert _rel_err(x, thomas_numpy(dl, d, du, b)) < 1e-12
+    assert _rel_err(x, x_true) < 1e-9
+
+
+@pytest.mark.parametrize("batch", [(), (3,)], ids=["1d", "batched"])
+def test_recursive_partition_fp32_within_table4_limit(batch):
+    """Three levels in fp32 against the fp64 solve of the same operands,
+    under the benchmark's Table-4 limit (1e-4)."""
+    dl, d, du, b, _ = make_diag_dominant_system(
+        5003, seed=11, batch=batch, dtype=np.float32
+    )
+    x, seen = _recursive_solve((dl, d, du, b), 10, 8)
+    assert len(seen) == 3 and x.dtype == np.float32
+    ref = thomas_numpy(*(a.astype(np.float64) for a in (dl, d, du, b)))
+    assert _rel_err(x, ref) < 1e-4
+
+
+def test_partition_levels_refuses_m_below_two():
+    with pytest.raises(ValueError, match="m must be >= 2"):
+        partition_levels(100, 1, lambda p: p <= 8)
 
 
 # The hypothesis-based partition property test lives in test_properties.py
