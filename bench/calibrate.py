@@ -7,11 +7,13 @@
 In one process, for each of ``--seeds`` seeds, one short run of the cell as
 the benchmark makes it (its operands, its timed verb at its own size, the
 same check), and for each of the first ``--control-seeds`` seeds one more
-with the control in the solver's place: the Thomas algorithm in bfloat16 on
-the device, the precision step below the configurations' float32. Prints
-one JSON line per run and a summary last: the largest reading of the
-solver (the lower reading) and the smallest of the control (the upper).
-The benchmark's own runs never run this.
+with the control in the solver's place: the operand kind's ``control``
+computed in the precision step below the configuration's dtype (for the
+tridiagonal kinds, the Thomas algorithm in bfloat16 on the device). A kind
+with no control runs no control seeds, and says so. Prints one JSON line
+per run and a summary last: the largest reading of the solver (the lower
+reading) and the smallest of the control (the upper). The benchmark's own
+runs never run this.
 """
 
 import time
@@ -27,6 +29,9 @@ sys.path[0] = str(Path(__file__).resolve().parent.parent)
 
 from bench.run import start  # noqa: E402
 
+#: The precision step below each configuration dtype, which a control takes.
+STEP_BELOW = {"float64": "float32", "float32": "bfloat16"}
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -39,10 +44,17 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     manifest, peaks = start(args.workload)
-    from bench import systems
     from bench.harness import run_cell
 
-    control = systems.lowp_thomas("bfloat16")
+    config = manifest.config(manifest.workload(args.workload)["config"])
+    kind_name = config["operands"]["kind"]
+    kind = manifest.operands(kind_name)
+    if hasattr(kind, "control"):
+        control = kind.control(STEP_BELOW[config["dtype"]])
+    else:
+        print(f"calibrate: operand kind {kind_name!r} has no control; "
+              "no control seeds are run", file=sys.stderr)
+        args.control_seeds = 0
     lines = []
     for k in range(args.seeds + args.control_seeds):
         is_control = k >= args.seeds

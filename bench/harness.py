@@ -35,6 +35,8 @@ class Run:
     traffic: dict
     peaks: dict
     shape: Tuple[int, ...] = ()
+    #: bytes any implementation must move per row of ``shape`` (the kind's)
+    least_bytes_per_row: int = 0
     setup_s: float = 0.0
     call_s: List[float] = field(default_factory=list)
     window_s: float = 0.0
@@ -139,16 +141,19 @@ def closed_loop(
 
 
 def check(
-    kept: List[Tuple[int, Any]], pool: List[systems.Operands], limit: float
+    kept: List[Tuple[int, Any]],
+    pool: List[systems.Operands],
+    limit: float,
+    reference: Callable[..., np.ndarray],
 ) -> Dict[str, Any]:
-    """Compare every kept output with the float64 reference of the operands
-    it was computed from."""
+    """Compare every kept output with the float64 ``reference`` of the
+    operands it was computed from."""
     refs: Dict[int, np.ndarray] = {}
     worst = 0.0
     for i, x in kept:
         k = i % len(pool)
         if k not in refs:
-            refs[k] = systems.reference_solve(*pool[k])
+            refs[k] = reference(*pool[k])
         worst = max(worst, systems.max_rel_err(x, refs[k]))
     return {"max_rel_err": worst, "limit": limit, "compared": len(kept)}
 
@@ -199,14 +204,16 @@ def run_cell(
     cell = manifest.workload(workload)
     config = manifest.config(cell["config"])
     traffic = manifest.traffic(cell["traffic"])
+    kind = manifest.operands(config["operands"]["kind"])
     run = Run(
         cell=workload, config=config, traffic=traffic, peaks=peaks,
-        shape=systems.call_shape(config, traffic),
+        shape=kind.shape(config, traffic),
+        least_bytes_per_row=int(kind.least_bytes_per_row(config)),
     )
     metrics = manifest.per_layer(workload) if trace else manifest.end_to_end(workload)
     readers = {m["name"]: manifest.reader(m["name"]) for m in metrics}
 
-    pool = systems.make_pool(config, traffic, seed)
+    pool = systems.make_pool(kind, config, traffic, seed)
     session = TridiagSession(solver_config(config))
     verb = (verb_for or getattr)(session, traffic["verb"])
     for i in range(traffic["warm_calls"]):
@@ -257,7 +264,9 @@ def run_cell(
         if v is not None:
             values[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
-    verdict = check(keep.items, pool, float(config["check"]["max_rel_err"]))
+    verdict = check(
+        keep.items, pool, float(config["check"]["max_rel_err"]), kind.reference
+    )
     correct = (
         run.failed == 0
         and verdict["compared"] > 0

@@ -2,9 +2,12 @@
 
 A cell (``workloads`` entry) names a configuration and a traffic mix. The
 configuration's file is the one the manifest gives; the traffic mix is
-``bench/traffic/<traffic>.json``; every metric, end-to-end or per-layer, is
-read by ``bench/metrics/<name>.py``. Adding any of them is a new file and a
-manifest entry, never an edit.
+``bench/traffic/<traffic>.json``; the operand kind a configuration names
+(``operands.kind``) is ``bench/operands/<kind>.py``, which makes the
+operands, their float64 reference, the control and the bytes a row; every
+metric, end-to-end or per-layer, is read by ``bench/metrics/<name>.py``.
+Adding any of them is a new file and, for a configuration, mix or metric,
+a manifest entry, never an edit.
 """
 
 from __future__ import annotations
@@ -58,6 +61,9 @@ class Manifest:
     def metric_file(self, name: str) -> Path:
         return self.root / "bench" / "metrics" / f"{name}.py"
 
+    def operands_file(self, kind: str) -> Path:
+        return self.root / "bench" / "operands" / f"{kind}.py"
+
     def end_to_end(self, cell: str) -> List[dict]:
         """The end-to-end metrics ``cell`` reports."""
         return [
@@ -77,11 +83,26 @@ class Manifest:
     def reader(self, metric: str) -> ModuleType:
         """The module ``bench/metrics/<metric>.py``; its ``read(run)`` gives
         the metric's value, or None where the run holds nothing to read."""
-        path = self.metric_file(metric)
-        modname = "bench_metric_" + re.sub(r"\W", "_", metric)
-        spec = importlib.util.spec_from_file_location(modname, path)
-        if spec is None or spec.loader is None:
-            raise FileNotFoundError(path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return load_module(self.metric_file(metric), "bench_metric_")
+
+    def operands(self, kind: str) -> ModuleType:
+        """The module ``bench/operands/<kind>.py``. It gives
+        ``shape(config, traffic)``, the operand shape of every call;
+        ``make(rng, index, shape, **params)``, one call's operands in the
+        verb's order, any number of them; ``reference(*operands)``, the
+        float64 solution, computed without the solver; and
+        ``least_bytes_per_row(config)``, the bytes any implementation must
+        move per row of the call shape. Optional: ``control(dtype)``, a
+        drop-in verb that computes in ``dtype``, and ``tiny(config,
+        traffic)``, the small sizes the CPU tests run the kind at."""
+        return load_module(self.operands_file(kind), "bench_operands_")
+
+
+def load_module(path: Path, prefix: str) -> ModuleType:
+    modname = prefix + re.sub(r"\W", "_", path.stem)
+    spec = importlib.util.spec_from_file_location(modname, path)
+    if spec is None or spec.loader is None:
+        raise FileNotFoundError(path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

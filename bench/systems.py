@@ -1,12 +1,12 @@
-"""Operands made from the seed, the fp64 reference and the low-precision control.
+"""What the operand kinds share: the seeded pool, the tridiagonal fp64
+reference, the error measure and the low-precision control.
 
-Nothing here imports the solver under test. The generators follow the
-paper's setting (random strictly diagonally dominant systems) and the
-sweeps of PolyBench/C's ``adi`` kernel; the reference is LAPACK's ``dgtsv``
-(Gaussian elimination with partial pivoting) in float64 on the very fp32
-operands the solver was given; the control is the Thomas algorithm computed
-in bfloat16 on the device, the precision step below the configurations'
-fp32.
+Nothing here imports the solver under test. Each kind
+(``bench/operands/<kind>.py``) makes its own operands; the tridiagonal
+kinds take the reference here, LAPACK's ``dgtsv`` (Gaussian elimination
+with partial pivoting) in float64 on the very fp32 operands the solver was
+given, and the control here, the Thomas algorithm computed on the device in
+the precision step below the configurations' fp32.
 
 Diagonal convention (the solver's): ``dl[i]`` multiplies ``x[i-1]`` and
 ``du[i]`` multiplies ``x[i+1]``; ``dl[..., 0]`` and ``du[..., -1]`` are 0.
@@ -14,11 +14,12 @@ Diagonal convention (the solver's): ``dl[i]`` multiplies ``x[i-1]`` and
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Tuple
+from types import ModuleType
+from typing import Callable, List, Tuple
 
 import numpy as np
 
-Operands = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
+Operands = Tuple[np.ndarray, ...]
 
 
 def pool_rng(seed: int, index: int) -> np.random.Generator:
@@ -26,111 +27,19 @@ def pool_rng(seed: int, index: int) -> np.random.Generator:
     return np.random.default_rng(np.random.SeedSequence([int(seed), int(index)]))
 
 
-def diag_dominant(
-    rng: np.random.Generator, index: int, shape: Tuple[int, ...], dominance: float
-) -> Operands:
-    """Random strictly diagonally dominant system in fp64 (the paper's
-    setting): off-diagonals uniform in [-1, 1], |d| = dominance·(|dl|+|du|)
-    plus uniform [0.5, 1.5], random sign, b = A @ x for a standard-normal x."""
-    n = shape[-1]
-    dl = rng.uniform(-1.0, 1.0, size=shape)
-    du = rng.uniform(-1.0, 1.0, size=shape)
-    dl[..., 0] = 0.0
-    du[..., n - 1] = 0.0
-    mag = np.abs(dl) + np.abs(du)
-    sign = np.where(rng.uniform(size=shape) < 0.5, -1.0, 1.0)
-    d = sign * (mag * dominance + rng.uniform(0.5, 1.5, size=shape))
-    x = rng.standard_normal(shape)
-    b = d * x
-    b[..., 1:] += dl[..., 1:] * x[..., :-1]
-    b[..., :-1] += du[..., :-1] * x[..., 1:]
-    return dl, d, du, b
-
-
-def polybench_adi(
-    rng: np.random.Generator,
-    index: int,
-    shape: Tuple[int, ...],
-    N: int,
-    TSTEPS: int,
-    B1: float,
-    B2: float,
-) -> Operands:
-    """One sweep of PolyBench/C 4.2's ``adi`` kernel (Peaceman–Rachford ADI
-    for the 2-D heat equation on an N × N grid): even ``index`` gives its
-    column sweep, odd its row sweep, each on a field uniform in [0, 2) from
-    ``rng`` (the range of PolyBench's initial field (i + N − j)/N).
-
-    The coefficients are PolyBench's: DX = DY = 1/N, DT = 1/TSTEPS,
-    mul1 = B1·DT/DX², mul2 = B2·DT/DY², a = c = −mul1/2, b = 1 + mul1,
-    d = f = −mul2/2, e = 1 + mul2. The column sweep solves, for each interior
-    line i, a·v[j−1][i] + b·v[j][i] + c·v[j+1][i] =
-    −d·u[j][i−1] + (1+2d)·u[j][i] − f·u[j][i+1]; the row sweep swaps the
-    roles of (a, b, c) and (d, e, f) and of rows and columns. Each line is
-    one system of all N points, its two boundary rows the identity
-    equations of PolyBench's boundary value 1, so the interior solution is
-    PolyBench's. ``shape`` is (N − 2 lines, N points)."""
-    dt, dx2 = 1.0 / TSTEPS, (1.0 / N) ** 2
-    mul1, mul2 = B1 * dt / dx2, B2 * dt / dx2
-    a, b = -mul1 / 2.0, 1.0 + mul1
-    d, e = -mul2 / 2.0, 1.0 + mul2
-    field = rng.uniform(0.0, 2.0, size=(N, N))
-    if index % 2 == 0:  # column sweep: g[i, j] = u[j][i]
-        (lo, di), (ex_lo, ex_di), g = (a, b), (-d, 1.0 + 2.0 * d), field.T
-    else:  # row sweep: g[i, j] = v[i][j]
-        (lo, di), (ex_lo, ex_di), g = (d, e), (-a, 1.0 + 2.0 * a), field
-    lines, n = shape
-    rhs = np.ones((lines, n))
-    rhs[:, 1:-1] = ex_lo * (g[:-2, 1:-1] + g[2:, 1:-1]) + ex_di * g[1:-1, 1:-1]
-    dl = np.full(shape, lo)
-    du = np.full(shape, lo)
-    dg = np.full(shape, di)
-    for diag in (dl, du):  # the boundary rows are x = 1
-        diag[:, 0] = diag[:, -1] = 0.0
-    dg[:, 0] = dg[:, -1] = 1.0
-    return dl, dg, du, rhs
-
-
-def diag_dominant_shape(config: dict, traffic: dict) -> Tuple[int, ...]:
-    """One system of the mix's ``rows``, which must be one of the
-    configuration's ``sizes``."""
-    rows = int(traffic["rows"])
-    if rows not in config["sizes"]:
-        raise ValueError(f"rows {rows} is not one of {config['name']}'s sizes")
-    return (rows,)
-
-
-def polybench_adi_shape(config: dict, traffic: dict) -> Tuple[int, ...]:
-    """The configuration's grid: N − 2 interior lines of N points."""
-    n = int(config["operands"]["N"])
-    return (n - 2, n)
-
-
-#: kind -> (the call's operand shape from configuration and mix, generator)
-GENERATORS: Dict[str, Tuple[Callable[..., Tuple[int, ...]], Callable[..., Operands]]] = {
-    "diag_dominant": (diag_dominant_shape, diag_dominant),
-    "polybench_adi": (polybench_adi_shape, polybench_adi),
-}
-
-
-def call_shape(config: dict, traffic: dict) -> Tuple[int, ...]:
-    """The operand shape of every call of ``traffic`` on ``config``. It is
-    read from one place: the configuration or the mix, as the kind says."""
-    return GENERATORS[config["operands"]["kind"]][0](config, traffic)
-
-
-def make_pool(config: dict, traffic: dict, seed: int) -> List[Operands]:
+def make_pool(
+    kind: ModuleType, config: dict, traffic: dict, seed: int
+) -> List[Operands]:
     """The mix's ``pool`` operand sets for the run seeded ``seed``, in the
-    configuration's dtype. The configuration's ``operands`` entry names a
-    generator by ``kind``; its other keys are the generator's parameters."""
-    operands = config["operands"]
-    params = {k: v for k, v in operands.items() if k != "kind"}
-    gen = GENERATORS[operands["kind"]][1]
-    shape = call_shape(config, traffic)
+    configuration's dtype. ``kind`` is the module the configuration's
+    ``operands.kind`` names; the entry's other keys are the parameters of
+    its ``make``."""
+    params = {k: v for k, v in config["operands"].items() if k != "kind"}
+    shape = kind.shape(config, traffic)
     return [
         tuple(
             np.ascontiguousarray(a, dtype=config["dtype"])
-            for a in gen(pool_rng(seed, i), i, shape, **params)
+            for a in kind.make(pool_rng(seed, i), i, shape, **params)
         )
         for i in range(int(traffic["pool"]))
     ]

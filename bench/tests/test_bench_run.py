@@ -19,8 +19,17 @@ from bench.manifest import Manifest
 
 from .conftest import REPO, copy_benchmark
 
-CELLS = [w["name"] for w in Manifest.load(REPO).data["workloads"]]
+MANIFEST = Manifest.load(REPO)
+CELLS = [w["name"] for w in MANIFEST.data["workloads"]]
 SEED = 2**31 + 977  # wider than 32 signed bits, as a run's --seed may be
+diag_dominant = MANIFEST.operands("diag_dominant")
+polybench_adi = MANIFEST.operands("polybench_adi")
+
+
+def cell_kind(cell: str):
+    """The operand kind of ``cell``'s configuration."""
+    config = MANIFEST.config(MANIFEST.workload(cell)["config"])
+    return MANIFEST.operands(config["operands"]["kind"])
 
 
 def command(cwd: Path, *extra: str) -> subprocess.CompletedProcess:
@@ -96,7 +105,7 @@ def raises_after_warm_up(verb):
 FAULTS = {
     "answer_altered": lambda verb: lambda *ops: altered(verb(*ops)),
     "half_left_out": lambda verb: lambda *ops: half_left_out(verb(*ops)),
-    "state_unchanged": lambda verb: lambda *ops: np.array(ops[3]),
+    "state_unchanged": lambda verb: lambda *ops: np.array(ops[-1]),
     "call_raises": raises_after_warm_up,
 }
 
@@ -112,7 +121,7 @@ def test_broken_timed_path_is_not_correct(tiny_root: Path, cell: str, fault: str
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_bfloat16_control_is_not_correct(tiny_root: Path, cell: str):
-    control = systems.lowp_thomas("bfloat16")
+    control = cell_kind(cell).control("bfloat16")
     res = run_tiny(tiny_root, cell, verb_for=lambda s, name: control)
     assert res["correct"] is False
     err = res["check"]["max_rel_err"]
@@ -122,7 +131,7 @@ def test_bfloat16_control_is_not_correct(tiny_root: Path, cell: str):
 def test_float32_thomas_stands_in_correctly(tiny_root: Path):
     """The control's own algorithm at the configurations' precision passes:
     what fails the control is the precision, not the code."""
-    control = systems.lowp_thomas("float32")
+    control = cell_kind(CELLS[0]).control("float32")
     res = run_tiny(tiny_root, CELLS[0], verb_for=lambda s, name: control)
     assert res["correct"], res["check"]
 
@@ -153,8 +162,8 @@ def test_reservoir_is_uniform_and_seeded():
 
 def test_reference_matches_dense_solve():
     rng = np.random.default_rng(0)
-    ops = systems.diag_dominant(rng, 0, (3, 50), 2.5)
-    x = systems.reference_solve(*ops)
+    ops = diag_dominant.make(rng, 0, (3, 50), 2.5)
+    x = diag_dominant.reference(*ops)
     dl, d, du, b = ops
     for k in range(3):
         a = np.diag(d[k]) + np.diag(dl[k, 1:], -1) + np.diag(du[k, :-1], 1)
@@ -167,9 +176,9 @@ SWEEPS = {"pool": 2}
 
 
 def test_pool_is_seeded():
-    a = systems.make_pool(ADI, SWEEPS, SEED)
-    b = systems.make_pool(ADI, SWEEPS, SEED)
-    c = systems.make_pool(ADI, SWEEPS, SEED + 1)
+    a = systems.make_pool(polybench_adi, ADI, SWEEPS, SEED)
+    b = systems.make_pool(polybench_adi, ADI, SWEEPS, SEED)
+    c = systems.make_pool(polybench_adi, ADI, SWEEPS, SEED + 1)
     assert all(np.array_equal(x, y) for p, q in zip(a, b) for x, y in zip(p, q))
     assert not np.array_equal(a[0][3], c[0][3])
     assert not np.array_equal(a[0][3], a[1][3])
@@ -237,10 +246,10 @@ def test_adi_sweeps_are_polybench(index: int):
     and row i of the row sweep's u."""
     n, tsteps = 30, 20
     field = np.random.default_rng(5).uniform(0.0, 2.0, size=(n, n))
-    ops = systems.polybench_adi(
+    ops = polybench_adi.make(
         np.random.default_rng(5), index, (n - 2, n), N=n, TSTEPS=tsteps, B1=2.0, B2=1.0
     )
-    x = systems.reference_solve(*ops)
+    x = polybench_adi.reference(*ops)
     if index == 0:
         want = polybench_column_sweep(field, tsteps).T[1:-1]
     else:
@@ -250,6 +259,6 @@ def test_adi_sweeps_are_polybench(index: int):
 
 def test_rows_must_be_a_configured_size():
     config = {"name": "t", "sizes": [1000, 4000], "operands": {"kind": "diag_dominant"}}
-    assert systems.call_shape(config, {"rows": 4000}) == (4000,)
+    assert diag_dominant.shape(config, {"rows": 4000}) == (4000,)
     with pytest.raises(ValueError):
-        systems.call_shape(config, {"rows": 3000})
+        diag_dominant.shape(config, {"rows": 3000})
