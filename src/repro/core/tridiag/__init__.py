@@ -49,6 +49,7 @@ batch shape through four verbs::
         x   = s.solve(dl, d, du, b)          # one system
         xb  = s.solve_batched(DL, D, DU, B)  # (B, n) same-size batch
         xs  = s.solve_many(systems)          # ragged mixed-size batch
+        xp  = s.solve_periodic_batched(DL, D, DU, B)  # (B, n) cyclic systems
         fut = s.submit(SolveRequest(0, dl, d, du, b))   # async serving
         x0  = fut.result(timeout=1.0)        # deadline fires w/o poll()
 

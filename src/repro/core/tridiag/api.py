@@ -14,6 +14,10 @@ batch shape through four verbs:
     B same-size systems as ``(B, n)`` operands, fused into one dispatch;
 ``solve_many(systems)``
     a ragged list of mixed-size systems, fused into one dispatch;
+``solve_periodic(dl, d, du, b)`` / ``solve_periodic_batched(dl, d, du, b)``
+    one cyclic system, or B same-size ones as ``(B, n)`` operands, where
+    ``dl[..., 0]`` couples row 0 to ``x[n-1]`` and ``du[..., n-1]`` couples
+    row n-1 to ``x[0]``;
 ``submit(req) -> SolveFuture``
     asynchronous serving — the request joins the session's admission queue
     and the future resolves when its batch dispatches.
@@ -715,6 +719,7 @@ class SolveEngine:
             "queue_high_water": 0,
             "layout": {},
             "stage2": {},
+            "periodic": 0,
         }
 
     # -- predicted-latency admission ------------------------------------------
@@ -1195,11 +1200,13 @@ class SolveEngine:
                     self._results[r.rid] = xi
 
     def record_dispatch(self, timing: ChunkTiming) -> None:
-        """Count the layout and Stage-2 implementation one dispatch ran."""
+        """Count the layout and Stage-2 implementation one dispatch ran, and
+        whether it solved periodic systems."""
         with self._stats_lock:
             for key in ("layout", "stage2"):
                 name = getattr(timing, key)
                 self.stats[key][name] = self.stats[key].get(name, 0) + 1
+            self.stats["periodic"] += int(timing.periodic)
 
     def stats_snapshot(self) -> dict:
         """A consistent copy of :attr:`stats` (``per_batch`` entries
@@ -1315,14 +1322,17 @@ class TridiagSession:
         )
 
     # -- planning ------------------------------------------------------------
-    def plan_for(self, sizes: Sizes) -> SolvePlan:
+    def plan_for(self, sizes: Sizes, periodic: bool = False) -> SolvePlan:
         """The plan this session executes for ``sizes`` (int or sequence).
 
         Priced by the *active* chunk policy — the config's, until a
         live-mode refit swaps in the telemetry-fitted one. With a mesh
         configured, plans are shard-aligned (chunk bounds snapped to shard
         boundaries); the staged ``*_timed`` path runs the same plan on one
-        device, so both executors agree on the chunk layout."""
+        device, so both executors agree on the chunk layout. ``periodic``
+        plans (cyclic systems) are neither chunked nor sharded."""
+        if periodic:
+            return build_plan(sizes, self.config.m, periodic=True)
         with self._cv:
             policy = self._active_policy
         shards = self._plan_shards(sizes)
@@ -1382,12 +1392,22 @@ class TridiagSession:
         return self._solve(dl, d, du, b, timed=True)
 
     def _execute(
-        self, sizes: Sizes, timed: bool, dl: Any, d: Any, du: Any, b: Any
+        self,
+        sizes: Sizes,
+        timed: bool,
+        dl: Any,
+        d: Any,
+        du: Any,
+        b: Any,
+        periodic: bool = False,
     ) -> Tuple[Any, ChunkTiming]:
-        """Plan the fused operands of one verb call and run them."""
+        """Plan the fused operands of one verb call and run them. Periodic
+        plans always take the fused executor: the staged path's chunks and
+        host reduced solve do not wrap."""
         with jax.profiler.TraceAnnotation(spans.LOOKUP):
-            plan = self.plan_for(sizes)
-        x, timing = self._pick_executor(timed).execute(plan, dl, d, du, b)
+            plan = self.plan_for(sizes, periodic)
+        executor = self._fused if periodic else self._pick_executor(timed)
+        x, timing = executor.execute(plan, dl, d, du, b)
         self._engine.record_dispatch(timing)
         return x, timing
 
@@ -1433,6 +1453,64 @@ class TridiagSession:
             x, timing = self._execute((n,) * batch, timed, *fused)
             with jax.profiler.TraceAnnotation(spans.SPLIT):
                 return split_systems(self._cast_out(x), batch), timing
+
+    def solve_periodic(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
+        """Solve one periodic (cyclic) tridiagonal system of 1-D diagonals.
+
+        Row i reads ``dl[i] x[i-1] + d[i] x[i] + du[i] x[i+1] = b[i]`` with
+        the indices taken modulo n: ``dl[0]`` is the coefficient of
+        ``x[n-1]`` in row 0, and ``du[n-1]`` the coefficient of ``x[0]`` in
+        row n-1 (the non-periodic verbs ignore both). n must be a multiple
+        of the config's ``m``. The system runs as a batch of one,
+        :meth:`solve_periodic_batched`."""
+        if np.ndim(d) != 1:
+            raise ValueError(
+                f"solve_periodic takes 1-D operands, got shape {np.shape(d)}; "
+                f"use solve_periodic_batched() for (batch, n) operands"
+            )
+        ops = [a[None] if isinstance(a, jax.Array) else np.asarray(a)[None]
+               for a in (dl, d, du, b)]
+        return self._solve_periodic(*ops, span=spans.SOLVE_PERIODIC)[0]
+
+    def solve_periodic_batched(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
+        """Solve B same-size periodic (cyclic) systems given as (B, n)
+        operands, with the corner convention of :meth:`solve_periodic`.
+
+        The systems are never fused end to end, as :meth:`solve_batched`
+        fuses them, since a corner would couple neighbours: each keeps its
+        wrapped block axis, on the interleaved layout where the config's
+        ``layout`` resolves to it for the batch, else on the batched
+        ``(B, P)`` kernels. n must be a multiple of ``m``."""
+        if np.ndim(d) != 2:
+            raise ValueError(
+                f"solve_periodic_batched takes (batch, n) operands, got shape "
+                f"{np.shape(d)}; use solve_periodic() for one system"
+            )
+        return self._solve_periodic(dl, d, du, b, span=spans.SOLVE_PERIODIC_BATCHED)
+
+    def _solve_periodic(
+        self, dl: Any, d: Any, du: Any, b: Any, *, span: str
+    ) -> np.ndarray:
+        batch, n = np.shape(d)
+        m = self.config.m
+        if n % m:
+            raise ValueError(
+                f"a periodic system of {n} rows needs n % m == 0 (m={m}): the "
+                f"identity rows that pad a system to whole blocks would break "
+                f"its wrap-around"
+            )
+        with jax.profiler.TraceAnnotation(span, rows=batch * n, systems=batch):
+            with jax.profiler.TraceAnnotation(spans.FUSE):
+                # A reshape, not fuse_systems: the corners must survive.
+                ops = [
+                    a.reshape(-1)
+                    if isinstance(a, jax.Array)
+                    else np.ascontiguousarray(np.reshape(a, -1))
+                    for a in self._cast(dl, d, du, b)
+                ]
+            x, _ = self._execute((n,) * batch, False, *ops, periodic=True)
+            with jax.profiler.TraceAnnotation(spans.SPLIT):
+                return split_systems(self._cast_out(x), batch)
 
     def solve_many(self, systems: Sequence[System]) -> List[np.ndarray]:
         """Solve a ragged list of ``(dl, d, du, b)`` systems in one dispatch."""
@@ -1687,7 +1765,8 @@ class TridiagSession:
         run under). ``backend`` names the resolved stage backend and whether
         its kernels run interpreted (None for a backend without kernels);
         ``layout`` and ``stage2`` count every dispatch — synchronous verbs and
-        served batches — by operand layout and Stage-2 implementation.
+        served batches — by operand layout and Stage-2 implementation, and
+        ``periodic`` counts the dispatches of periodic systems.
         """
         with self._cv:
             snap = self._engine.stats_snapshot()

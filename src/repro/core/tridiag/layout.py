@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.tridiag.partition import PartitionCoeffs, partition_stage1
+from repro.core.tridiag.partition import PartitionCoeffs, partition_stage1, prev_block
 from repro.core.tridiag.thomas import thomas
 
 Array = jax.Array
@@ -210,11 +210,12 @@ deinterleave_jit = functools.partial(
 
 
 def partition_stage1_wide(
-    dlw: Array, dw: Array, duw: Array, bw: Array, *, m: int
+    dlw: Array, dw: Array, duw: Array, bw: Array, *, m: int, periodic: bool = False
 ) -> PartitionCoeffs:
     """Stage 1 on wide operands → wide coeffs: spikes (P, m-1, B), reduced
     rows (P, B). Delegates to the batch-polymorphic system-major stage via
-    transposes (XLA folds these into the surrounding gathers)."""
+    transposes (XLA folds these into the surrounding gathers); ``periodic``
+    as there."""
     p, _, bsz = dw.shape
 
     def to_sys(a: Any) -> Any:
@@ -223,7 +224,9 @@ def partition_stage1_wide(
     def spike(a: Any) -> Any:  # (B, P, m-1) -> (P, m-1, B)
         return a.transpose(1, 2, 0)
 
-    c = partition_stage1(to_sys(dlw), to_sys(dw), to_sys(duw), to_sys(bw), m)
+    c = partition_stage1(
+        to_sys(dlw), to_sys(dw), to_sys(duw), to_sys(bw), m, periodic
+    )
     return PartitionCoeffs(
         spike(c.y), spike(c.v), spike(c.w),
         c.red_dl.T, c.red_d.T, c.red_du.T, c.red_b.T,
@@ -235,13 +238,16 @@ def thomas_wide(red_dl: Array, red_d: Array, red_du: Array, red_b: Array) -> Arr
     return thomas(red_dl.T, red_d.T, red_du.T, red_b.T).T
 
 
-def partition_stage3_wide(coeffs: PartitionCoeffs, s: Array) -> Array:
+def partition_stage3_wide(
+    coeffs: PartitionCoeffs, s: Array, periodic: bool = False
+) -> Array:
     """Back-substitution on wide coeffs + (P, B) interface values → (P, m, B).
 
     ``s_left`` is a shift along the block axis; row 0 of every column is a
-    system's first block, so the zero boundary is exact for every system.
+    system's first block, so the zero boundary is exact for every system
+    (and the roll round to its last block exact when ``periodic``).
     """
-    s_left = jnp.concatenate([jnp.zeros_like(s[:1]), s[:-1]], axis=0)
+    s_left = prev_block(s, axis=0, periodic=periodic)
     x_int = (
         coeffs.y - coeffs.v * s_left[:, None, :] - coeffs.w * s[:, None, :]
     )

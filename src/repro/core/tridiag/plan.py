@@ -136,9 +136,10 @@ Sizes = Union[int, Sequence[int]]
 @dataclass
 class ChunkTiming:
     """Wall-clock phase breakdown of one planned solve (milliseconds), and
-    what ran: the operand ``layout`` and the ``stage2`` implementation
+    what ran: the operand ``layout``, the ``stage2`` implementation
     (``"host"`` for the staged path's ``thomas_numpy``, otherwise the name
-    :meth:`StageBackend.reduced_solve_impl` gives)."""
+    :meth:`StageBackend.reduced_solve_impl` gives) and whether the systems
+    were ``periodic``."""
 
     num_chunks: int
     t_stage1_ms: float
@@ -148,6 +149,7 @@ class ChunkTiming:
     n: int = 0
     layout: str = "system-major"
     stage2: str = "host"
+    periodic: bool = False
 
     @property
     def phases(self) -> Tuple[float, float, float]:
@@ -190,6 +192,12 @@ class StageBackend:
     path never calls it — its Stage 2 stays on the host (``thomas_numpy``),
     as in the paper.
 
+    Periodic (cyclic) systems take the same factories with ``periodic=True``:
+    stages whose neighbour shifts wrap round the block axis. Their reduced
+    system is cyclic, and the fused path solves it with the plain reduced
+    solve through :func:`repro.core.tridiag.partition.cyclic_solve`, whose
+    rank-one correction is :meth:`periodic_update`.
+
     :meth:`check_dtype` refuses operand dtypes the backend cannot run, and
     :meth:`interpret_mode` says whether its kernels run interpreted (None
     for a backend without kernels).
@@ -207,11 +215,17 @@ class StageBackend:
 
     name = "abstract"
 
-    def make_stage1(self, m: int) -> Callable:
+    def make_stage1(self, m: int, periodic: bool = False) -> Callable:
         raise NotImplementedError
 
-    def make_stage3(self) -> Callable:
+    def make_stage3(self, periodic: bool = False) -> Callable:
         raise NotImplementedError
+
+    def periodic_update(self, y: Any, z: Any, beta: Any, axis: int) -> Any:
+        """``y - beta * z``, the Sherman–Morrison correction of a cyclic
+        reduced solve along ``axis`` of 2-D ``y`` (``beta`` of length 1
+        there)."""
+        return partition.rank_one_update(y, z, beta, axis)
 
     def make_reduced_solve(self, m: int) -> Callable:
         return thomas_scan
@@ -236,11 +250,13 @@ class StageBackend:
     def check_dtype(self, dtype: Any) -> None:
         """Raise ``ValueError`` for operands this backend cannot run."""
 
-    def make_wide_stage1(self, m: int) -> Callable:
-        return jax.jit(partial(layout_mod.partition_stage1_wide, m=m))
+    def make_wide_stage1(self, m: int, periodic: bool = False) -> Callable:
+        return jax.jit(
+            partial(layout_mod.partition_stage1_wide, m=m, periodic=periodic)
+        )
 
-    def make_wide_stage3(self) -> Callable:
-        return jax.jit(layout_mod.partition_stage3_wide)
+    def make_wide_stage3(self, periodic: bool = False) -> Callable:
+        return jax.jit(partial(layout_mod.partition_stage3_wide, periodic=periodic))
 
     def make_wide_reduced_solve(self) -> Callable:
         return layout_mod.thomas_wide
@@ -252,11 +268,11 @@ class ReferenceBackend(StageBackend):
 
     name = "reference"
 
-    def make_stage1(self, m: int) -> Callable:
-        return jax.jit(partial(partition.partition_stage1, m=m))
+    def make_stage1(self, m: int, periodic: bool = False) -> Callable:
+        return jax.jit(partial(partition.partition_stage1, m=m, periodic=periodic))
 
-    def make_stage3(self) -> Callable:
-        return jax.jit(partition.partition_stage3)
+    def make_stage3(self, periodic: bool = False) -> Callable:
+        return jax.jit(partial(partition.partition_stage3, periodic=periodic))
 
 
 @dataclass(frozen=True)
@@ -287,7 +303,7 @@ class PallasBackend(StageBackend):
     block_rows: int = 32
     interpret: Optional[bool] = None
 
-    def make_stage1(self, m: int) -> Callable:
+    def make_stage1(self, m: int, periodic: bool = False) -> Callable:
         # Imported lazily: the kernel ops import repro.core.tridiag.partition,
         # whose package __init__ imports this module.
         from repro.kernels.partition_stage1.ops import (
@@ -297,7 +313,9 @@ class PallasBackend(StageBackend):
 
         def stage1(dl: Any, d: Any, du: Any, b: Any) -> Any:
             ndim = jnp.asarray(d).ndim
-            kw = dict(m=m, block_p=self.block_p, interpret=self.interpret)
+            kw = dict(
+                m=m, block_p=self.block_p, interpret=self.interpret, periodic=periodic
+            )
             if ndim == 1:
                 return partition_stage1_pallas(dl, d, du, b, **kw)
             if ndim == 2:
@@ -309,7 +327,7 @@ class PallasBackend(StageBackend):
 
         return stage1
 
-    def make_stage3(self) -> Callable:
+    def make_stage3(self, periodic: bool = False) -> Callable:
         from repro.kernels.partition_stage3.ops import (
             partition_stage3_pallas,
             partition_stage3_pallas_batched,
@@ -321,7 +339,7 @@ class PallasBackend(StageBackend):
             # back-substitution runs in the spikes' precision.
             s = jnp.asarray(s, dtype=jnp.asarray(coeffs.y).dtype)
             ndim = s.ndim
-            kw = dict(block_p=self.block_p, interpret=self.interpret)
+            kw = dict(block_p=self.block_p, interpret=self.interpret, periodic=periodic)
             if ndim == 1:
                 return partition_stage3_pallas(coeffs, s, **kw)
             if ndim == 2:
@@ -391,7 +409,7 @@ class PallasBackend(StageBackend):
 
         return reduced_solve
 
-    def make_wide_stage1(self, m: int) -> Callable:
+    def make_wide_stage1(self, m: int, periodic: bool = False) -> Callable:
         from repro.kernels.partition_stage1.ops import partition_stage1_pallas_wide
 
         return partial(
@@ -400,9 +418,10 @@ class PallasBackend(StageBackend):
             block_rows=self.block_rows,
             block_b=self.block_b,
             interpret=self.interpret,
+            periodic=periodic,
         )
 
-    def make_wide_stage3(self) -> Callable:
+    def make_wide_stage3(self, periodic: bool = False) -> Callable:
         from repro.kernels.partition_stage3.ops import partition_stage3_pallas_wide
 
         def wide_stage3(coeffs: Any, s: Any) -> Any:
@@ -415,9 +434,17 @@ class PallasBackend(StageBackend):
                 block_rows=self.block_rows,
                 block_b=self.block_b,
                 interpret=self.interpret,
+                periodic=periodic,
             )
 
         return wide_stage3
+
+    def periodic_update(self, y: Any, z: Any, beta: Any, axis: int) -> Any:
+        from repro.kernels.periodic.ops import periodic_correction_pallas
+
+        return periodic_correction_pallas(
+            y, z, beta, axis=axis, interpret=self.interpret
+        )
 
     def wide_reduced_solve_impl(self, shape: Tuple[int, ...], dtype: Any) -> str:
         from repro.kernels.thomas.ops import thomas_fits_vmem
@@ -460,11 +487,14 @@ class AutoBackend(StageBackend):
     def resolve(self) -> StageBackend:
         return BACKENDS["pallas" if jax.default_backend() == "tpu" else "reference"]
 
-    def make_stage1(self, m: int) -> Callable:
-        return self.resolve().make_stage1(m)
+    def make_stage1(self, m: int, periodic: bool = False) -> Callable:
+        return self.resolve().make_stage1(m, periodic)
 
-    def make_stage3(self) -> Callable:
-        return self.resolve().make_stage3()
+    def make_stage3(self, periodic: bool = False) -> Callable:
+        return self.resolve().make_stage3(periodic)
+
+    def periodic_update(self, y: Any, z: Any, beta: Any, axis: int) -> Any:
+        return self.resolve().periodic_update(y, z, beta, axis)
 
     def make_reduced_solve(self, m: int) -> Callable:
         return self.resolve().make_reduced_solve(m)
@@ -484,11 +514,11 @@ class AutoBackend(StageBackend):
     def check_dtype(self, dtype: Any) -> None:
         self.resolve().check_dtype(dtype)
 
-    def make_wide_stage1(self, m: int) -> Callable:
-        return self.resolve().make_wide_stage1(m)
+    def make_wide_stage1(self, m: int, periodic: bool = False) -> Callable:
+        return self.resolve().make_wide_stage1(m, periodic)
 
-    def make_wide_stage3(self) -> Callable:
-        return self.resolve().make_wide_stage3()
+    def make_wide_stage3(self, periodic: bool = False) -> Callable:
+        return self.resolve().make_wide_stage3(periodic)
 
     def make_wide_reduced_solve(self) -> Callable:
         return self.resolve().make_wide_reduced_solve()
@@ -525,53 +555,57 @@ def resolve_backend(backend: BackendLike) -> StageBackend:
 
 # ------------------------------------------------------------ jitted stages --
 # Module-level cache of the stage callables. Stage 1 is keyed by
-# (m, backend); stage 3 takes no block size, so one callable per backend
-# serves every m. Frontends and services construct solver objects freely (one
-# per chunk count, per request batch, per sweep cell); tracing/compilation
-# must not follow suit. The callables are batch-polymorphic (leading dims
-# pass through), so each cached pair serves the single, batched and ragged
-# paths alike; jax.jit specialises per operand shape internally.
+# (m, backend, periodic); stage 3 takes no block size, so one callable per
+# (backend, periodic) serves every m. Frontends and services construct
+# solver objects freely (one per chunk count, per request batch, per sweep
+# cell); tracing/compilation must not follow suit. The callables are
+# batch-polymorphic (leading dims pass through), so each cached pair serves
+# the single, batched and ragged paths alike; jax.jit specialises per
+# operand shape internally.
 #
 # _CACHE_LOCK guards both stage caches and the plan cache below: a
 # TridiagSession dispatches from its worker thread while its synchronous
 # verbs (and other sessions) run on caller threads, and interleaved dict/LRU
 # mutation would corrupt the OrderedDict order or drop entries.
 _CACHE_LOCK = threading.RLock()
-_STAGE1_CACHE: Dict[Tuple[int, StageBackend], Callable] = {}
-_STAGE3_CACHE: Dict[StageBackend, Callable] = {}
+_STAGE1_CACHE: Dict[Tuple[int, StageBackend, bool], Callable] = {}
+_STAGE3_CACHE: Dict[Tuple[StageBackend, bool], Callable] = {}
 _STAGE3_GHOST_CACHE: Dict[StageBackend, Callable] = {}
-_WIDE_STAGE1_CACHE: Dict[Tuple[int, StageBackend], Callable] = {}
-_WIDE_STAGE3_CACHE: Dict[StageBackend, Callable] = {}
+_WIDE_STAGE1_CACHE: Dict[Tuple[int, StageBackend, bool], Callable] = {}
+_WIDE_STAGE3_CACHE: Dict[Tuple[StageBackend, bool], Callable] = {}
 
 
-def jitted_stages(m: int, backend: BackendLike = None) -> Tuple[Callable, Callable]:
-    """Return the cached ``(stage1, stage3)`` callables for ``(m, backend)``."""
+def jitted_stages(
+    m: int, backend: BackendLike = None, periodic: bool = False
+) -> Tuple[Callable, Callable]:
+    """Return the cached ``(stage1, stage3)`` callables for ``(m, backend)``,
+    of cyclic systems with ``periodic``."""
     backend = resolve_backend(backend)
-    key = (m, backend)
+    key, key3 = (m, backend, periodic), (backend, periodic)
     # make_stage{1,3} only build (cheap) wrappers — tracing happens at first
     # call — so holding the lock across them is fine and keeps one winner.
     with _CACHE_LOCK:
         if key not in _STAGE1_CACHE:
-            _STAGE1_CACHE[key] = backend.make_stage1(m)
-        if backend not in _STAGE3_CACHE:
-            _STAGE3_CACHE[backend] = backend.make_stage3()
-        return _STAGE1_CACHE[key], _STAGE3_CACHE[backend]
+            _STAGE1_CACHE[key] = backend.make_stage1(m, periodic)
+        if key3 not in _STAGE3_CACHE:
+            _STAGE3_CACHE[key3] = backend.make_stage3(periodic)
+        return _STAGE1_CACHE[key], _STAGE3_CACHE[key3]
 
 
 def jitted_wide_stages(
-    m: int, backend: BackendLike = None
+    m: int, backend: BackendLike = None, periodic: bool = False
 ) -> Tuple[Callable, Callable]:
     """Cached ``(wide_stage1, wide_stage3)`` — the interleaved-layout twins
     of :func:`jitted_stages`, consuming (P, m, B) operands (systems on the
     minor axis; see :mod:`repro.core.tridiag.layout`)."""
     backend = resolve_backend(backend)
-    key = (m, backend)
+    key, key3 = (m, backend, periodic), (backend, periodic)
     with _CACHE_LOCK:
         if key not in _WIDE_STAGE1_CACHE:
-            _WIDE_STAGE1_CACHE[key] = backend.make_wide_stage1(m)
-        if backend not in _WIDE_STAGE3_CACHE:
-            _WIDE_STAGE3_CACHE[backend] = backend.make_wide_stage3()
-        return _WIDE_STAGE1_CACHE[key], _WIDE_STAGE3_CACHE[backend]
+            _WIDE_STAGE1_CACHE[key] = backend.make_wide_stage1(m, periodic)
+        if key3 not in _WIDE_STAGE3_CACHE:
+            _WIDE_STAGE3_CACHE[key3] = backend.make_wide_stage3(periodic)
+        return _WIDE_STAGE1_CACHE[key], _WIDE_STAGE3_CACHE[key3]
 
 
 def jitted_stage3_ghost(backend: BackendLike = None) -> Callable:
@@ -587,9 +621,10 @@ def jitted_stage3_ghost(backend: BackendLike = None) -> Callable:
     with _CACHE_LOCK:
         fn = _STAGE3_GHOST_CACHE.get(backend)
         if fn is None:
-            if backend not in _STAGE3_CACHE:
-                _STAGE3_CACHE[backend] = backend.make_stage3()
-            fn = jax.jit(partial(_stage3_with_ghost, _STAGE3_CACHE[backend]))
+            key3 = (backend, False)
+            if key3 not in _STAGE3_CACHE:
+                _STAGE3_CACHE[key3] = backend.make_stage3()
+            fn = jax.jit(partial(_stage3_with_ghost, _STAGE3_CACHE[key3]))
             _STAGE3_GHOST_CACHE[backend] = fn
         return fn
 
@@ -688,6 +723,14 @@ class SolvePlan:
     (each shard needs only the *next* shard's first block), and the in-shard
     chunk loop is the same static program on every device
     (:attr:`local_chunk_bounds`). ``shards=1`` is today's unsharded plan.
+
+    ``periodic`` plans lay out cyclic systems (``build_plan(...,
+    periodic=True)``): same-size systems, each closing on itself, so each
+    keeps its own wrapped block axis. They are never fused end to end (a
+    corner would couple neighbouring systems), never chunked and never
+    sharded along the block axis: one chunk, one shard. A periodic plan is
+    never equal to a non-periodic one, so the two never share an
+    executable.
     """
 
     m: int
@@ -696,6 +739,7 @@ class SolvePlan:
     halo_bounds: Tuple[Tuple[int, int], ...]
     offsets: Tuple[int, ...]
     shards: int = 1
+    periodic: bool = False
 
     @property
     def batch(self) -> int:
@@ -739,7 +783,7 @@ class SolvePlan:
 # capacity bounds memory for adversarial traffic with no repeated mixes;
 # 1024 distinct compositions is far beyond any steady-state queue.
 _PLAN_CACHE_CAPACITY = 1024
-_PLAN_CACHE: "OrderedDict[Tuple[Tuple[int, ...], int, int, int], SolvePlan]" = (
+_PLAN_CACHE: "OrderedDict[Tuple[Tuple[int, ...], int, int, int, bool], SolvePlan]" = (
     OrderedDict()
 )
 _PLAN_STATS = {"hits": 0, "misses": 0}
@@ -783,6 +827,7 @@ def build_plan(
     num_chunks: Optional[int] = None,
     policy: Optional[ChunkPolicy] = None,
     shards: int = 1,
+    periodic: bool = False,
 ) -> SolvePlan:
     """Build the :class:`SolvePlan` for a batch of systems of ``sizes``.
 
@@ -805,10 +850,14 @@ def build_plan(
     result in :attr:`SolvePlan.shards`. ``shards=1`` (the default) is
     exactly today's layout.
 
-    Plans are memoised by their ``(sizes, m, num_chunks, shards)`` signature
-    in a bounded module-level LRU (policies are consulted first, then the
-    resolved counts key the cache), so serving traffic that repeats a batch
-    composition skips replanning; see :func:`plan_cache_stats`.
+    ``periodic`` plans cyclic systems (:attr:`SolvePlan.periodic`): the
+    sizes must be equal, and the plan is unchunked and unsharded, so
+    ``num_chunks``, ``policy`` and ``shards`` beyond one are refused.
+
+    Plans are memoised by their ``(sizes, m, num_chunks, shards, periodic)``
+    signature in a bounded module-level LRU (policies are consulted first,
+    then the resolved counts key the cache), so serving traffic that repeats
+    a batch composition skips replanning; see :func:`plan_cache_stats`.
     """
     if isinstance(sizes, (int, np.integer)):
         sizes = (int(sizes),)
@@ -822,6 +871,14 @@ def build_plan(
             raise ValueError(f"system size {n} not divisible by m={m}")
     if num_chunks is not None and policy is not None:
         raise ValueError("pass num_chunks or policy, not both")
+    if periodic:
+        if len(set(sizes)) != 1:
+            raise ValueError(f"periodic plans take same-size systems, got {sizes}")
+        if policy is not None or (num_chunks or 1) != 1 or shards != 1:
+            raise ValueError(
+                "periodic plans are unchunked and unsharded: a chunk's halo and "
+                "a shard's span do not wrap round a cyclic system"
+            )
     if policy is not None:
         # Clamp the policy's pick into [1, num_blocks] exactly like the upper
         # bound below: heuristics may round to 0 on tiny effective sizes.
@@ -846,7 +903,7 @@ def build_plan(
         per_shard_chunks = max(1, min(per_shard_blocks, round(k / shards)))
         k = per_shard_chunks * shards
 
-    key = (sizes, m, k, shards)
+    key = (sizes, m, k, shards, periodic)
     with _CACHE_LOCK:
         cached = _PLAN_CACHE.get(key)
         if cached is not None:
@@ -889,6 +946,7 @@ def build_plan(
         halo_bounds=halos,
         offsets=tuple(offsets),
         shards=shards,
+        periodic=periodic,
     )
     with _CACHE_LOCK:
         # A racing thread may have built the same plan between the lookup and
@@ -988,6 +1046,11 @@ class PlanExecutor:
         if n != plan.total_size:
             raise ValueError(
                 f"operands have {n} rows but the plan lays out {plan.total_size}"
+            )
+        if plan.periodic:
+            raise ValueError(
+                "periodic plans run on the fused executor only: the staged "
+                "path's chunks and host Stage 2 do not wrap round the systems"
             )
         self.backend.check_dtype(d.dtype if hasattr(d, "dtype") else np.result_type(d))
         layout = resolve_layout(
@@ -1309,6 +1372,13 @@ def _fused_callable(
     interleave/deinterleave gathers bracket the ``shard_map`` region.
     ``None`` (the default) is the single-device trace, unchanged.
 
+    A ``plan.periodic`` trace solves cyclic systems: the stages wrap round
+    each system's block axis and the cyclic reduced system is solved by
+    :func:`repro.core.tridiag.partition.cyclic_solve` over the backend's
+    plain reduced solve. On the interleaved layout that is the wide pipeline
+    with both flags; otherwise the fused operands are viewed as ``(B, n)``
+    and run through the batched stages, one system a row, whole.
+
     Compilation happens HERE (``jit(...).lower(*avals).compile()``), not at
     first call: only one of the four donated buffers can back the single
     output, so XLA warns "Some donated buffers were not usable" once per
@@ -1319,13 +1389,21 @@ def _fused_callable(
     """
     m = plan.m
     dtype = avals[1].dtype
+    periodic = plan.periodic
 
     if layout == "interleaved":
         sizes = plan.sizes
         lanes = len(sizes) // (len(mesh_devices) if mesh_devices else 1)
+        # A periodic solve stacks its two right-hand sides on the lanes.
+        lanes *= 2 if periodic else 1
         stage2 = backend.wide_reduced_solve_impl((max(sizes) // m, lanes), dtype)
-        wide_stage1, wide_stage3 = jitted_wide_stages(m, backend)
+        wide_stage1, wide_stage3 = jitted_wide_stages(m, backend, periodic)
         wide_reduced = backend.make_wide_reduced_solve()
+        if periodic:
+            wide_reduced = partial(
+                partition.cyclic_solve, wide_reduced, axis=0,
+                update=backend.periodic_update,
+            )
 
         def wide_pipeline(*ops: Any) -> Any:
             with jax.named_scope(spans.STAGE1):
@@ -1351,6 +1429,24 @@ def _fused_callable(
             xw = wide_pipeline(*ops)
             with jax.named_scope(spans.DEINTERLEAVE):
                 return layout_mod.deinterleave(xw, sizes, m)
+
+    elif periodic:
+        bsz, n = plan.batch, plan.sizes[0]
+        # the two right-hand sides stacked on the batch axis
+        stage2 = backend.reduced_solve_impl((2 * bsz, n // m), dtype)
+        stage1, stage3 = jitted_stages(m, backend, periodic=True)
+        reduced_solve = partial(
+            partition.cyclic_solve, backend.make_reduced_solve(m),
+            update=backend.periodic_update,
+        )
+
+        def fused(dl: Any, d: Any, du: Any, b: Any) -> Any:
+            with jax.named_scope(spans.STAGE1):
+                c = stage1(*(a.reshape(bsz, n) for a in (dl, d, du, b)))
+            with jax.named_scope(spans.STAGE2):
+                s = reduced_solve(c.red_dl, c.red_d, c.red_du, c.red_b)
+            with jax.named_scope(spans.STAGE3):
+                return stage3(c, s).reshape(bsz * n)
 
     elif mesh_devices is not None:
         stage2 = backend.reduced_solve_impl((plan.num_blocks,), dtype)
@@ -1524,15 +1620,17 @@ class FusedExecutor:
             jax.ShapeDtypeStruct(a.shape, jax.dtypes.canonicalize_dtype(a.dtype))
             for a in ops
         ]
-        levels = (
-            0
-            if layout == "interleaved"
-            else self.backend.reduced_solve_levels(
-                tuple(ops[1].shape[:-1]) + (plan.num_blocks,), avals[1].dtype, plan.m
+        if layout == "interleaved":
+            levels = 0
+        else:
+            reduced = (
+                (2 * plan.batch, plan.sizes[0] // plan.m)
+                if plan.periodic
+                else tuple(ops[1].shape[:-1]) + (plan.num_blocks,)
             )
-        )
+            levels = self.backend.reduced_solve_levels(reduced, avals[1].dtype, plan.m)
         with jax.profiler.TraceAnnotation(
-            spans.COMPILE, layout=layout, rows=plan.total_size
+            spans.COMPILE, layout=layout, rows=plan.total_size, periodic=plan.periodic
         ) as span:
             fn, stage2 = _fused_callable(
                 plan, self.backend, self.donate, avals, layout, shard_devices
@@ -1572,6 +1670,8 @@ class FusedExecutor:
                 raise ValueError(
                     f"operands have {n} rows but the plan lays out {plan.total_size}"
                 )
+            if plan.periodic and ops[1].ndim != 1:
+                raise ValueError("periodic plans take the fused 1-D operands")
             self.backend.check_dtype(ops[1].dtype)
             fn, layout, stage2 = self._executable(plan, ops)
         t0 = time.perf_counter()
@@ -1596,4 +1696,5 @@ class FusedExecutor:
             n=int(n),
             layout=layout,
             stage2=stage2,
+            periodic=plan.periodic,
         )

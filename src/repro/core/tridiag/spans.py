@@ -20,7 +20,14 @@ One synchronous verb call emits, nested under its verb span::
     tridiag.split     the output cast and the split into per-system solutions
 
 ``tridiag.compile`` nests in ``tridiag.lookup`` on an executable-cache miss
-only, and ``tridiag.batch`` wraps one served batch on the serving worker.
+only (its metadata says which ``layout``, ``stage2`` and whether the systems
+are ``periodic``), and ``tridiag.batch`` wraps one served batch on the
+serving worker.
+
+On a periodic solve the Sherman–Morrison correction of the cyclic reduced
+system runs under the device scope ``tridiag/periodic`` (inside
+``tridiag/stage2``), and on the Pallas backend its rank-one update is one
+kernel, a custom call named after its jitted wrapper :data:`PERIODIC_KERNEL`.
 """
 
 from __future__ import annotations
@@ -28,6 +35,8 @@ from __future__ import annotations
 SOLVE = "tridiag.solve"
 SOLVE_BATCHED = "tridiag.solve_batched"
 SOLVE_MANY = "tridiag.solve_many"
+SOLVE_PERIODIC = "tridiag.solve_periodic"
+SOLVE_PERIODIC_BATCHED = "tridiag.solve_periodic_batched"
 FUSE = "tridiag.fuse"
 LOOKUP = "tridiag.lookup"
 LAUNCH = "tridiag.launch"
@@ -39,7 +48,8 @@ BATCH = "tridiag.batch"
 
 #: The spans one verb call emits under its verb span, in order.
 CALL_STEPS = (FUSE, LOOKUP, LAUNCH, WAIT, FETCH, SPLIT)
-HOST_SPANS = (SOLVE, SOLVE_BATCHED, SOLVE_MANY, *CALL_STEPS, COMPILE, BATCH)
+VERB_SPANS = (SOLVE, SOLVE_BATCHED, SOLVE_MANY, SOLVE_PERIODIC, SOLVE_PERIODIC_BATCHED)
+HOST_SPANS = (*VERB_SPANS, *CALL_STEPS, COMPILE, BATCH)
 
 INTERLEAVE = "tridiag/interleave"
 DEINTERLEAVE = "tridiag/deinterleave"
@@ -48,5 +58,12 @@ STAGE2 = "tridiag/stage2"
 STAGE3 = "tridiag/stage3"
 HALO = "tridiag/halo"
 REDUCED_GATHER = "tridiag/reduced_gather"
+PERIODIC = "tridiag/periodic"
 
-DEVICE_SCOPES = (INTERLEAVE, DEINTERLEAVE, STAGE1, STAGE2, STAGE3, HALO, REDUCED_GATHER)
+DEVICE_SCOPES = (
+    INTERLEAVE, DEINTERLEAVE, STAGE1, STAGE2, STAGE3, HALO, REDUCED_GATHER, PERIODIC
+)
+
+#: The jitted wrapper of the periodic correction's kernel; a TPU trace
+#: names the kernel's op after it (``_periodic_correction.1``).
+PERIODIC_KERNEL = "_periodic_correction"

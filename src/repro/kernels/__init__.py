@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the partition method's GPU hot spots.
 
-Four kernels (each with ``ops.py`` jit wrapper and ``ref.py`` pure-jnp oracle):
+Five kernels (each with ``ops.py`` jit wrapper and ``ref.py`` pure-jnp oracle):
 
 - ``thomas``           — batched independent Thomas solves (B systems × n rows).
                          Also the device-side Stage-2 reduced solve of the
@@ -12,6 +12,9 @@ Four kernels (each with ``ops.py`` jit wrapper and ``ref.py`` pure-jnp oracle):
 - ``partition_stage1`` — per-block interior elimination producing the three
                          spike solutions (y, v, w); the paper's Stage-1 kernel.
 - ``partition_stage3`` — per-block back-substitution; the paper's Stage-3 kernel.
+- ``periodic``         — the Sherman–Morrison rank-one update x = y - β z of a
+                         cyclic reduced solve (periodic systems; traced into
+                         the fused executable by `PallasBackend.periodic_update`).
 - ``tridiag_matvec``   — residual matvec r = A·x (verification/benchmark util).
 
 TPU adaptation notes (DESIGN.md §2): the solve dimension is laid out on
@@ -33,6 +36,7 @@ from repro.kernels.partition_stage3.ops import (
     partition_stage3_pallas_batched,
     partition_stage3_pallas_wide,
 )
+from repro.kernels.periodic.ops import periodic_correction_pallas
 from repro.kernels.tridiag_matvec.ops import tridiag_matvec_pallas
 
 __all__ = [
@@ -44,5 +48,6 @@ __all__ = [
     "partition_stage3_pallas",
     "partition_stage3_pallas_batched",
     "partition_stage3_pallas_wide",
+    "periodic_correction_pallas",
     "tridiag_matvec_pallas",
 ]

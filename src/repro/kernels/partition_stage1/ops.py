@@ -1,4 +1,10 @@
-"""Jitted wrapper: Stage-1 Pallas kernel + reduced-row assembly."""
+"""Jitted wrapper: Stage-1 Pallas kernel + reduced-row assembly.
+
+``periodic`` (static) assembles the reduced rows of cyclic systems: the last
+block's right neighbour is block 0, so the next-block shift rolls along the
+block axis instead of filling with zeros (``partition.py``). The kernel is
+the same either way; non-periodic calls trace exactly as without the flag.
+"""
 
 from __future__ import annotations
 
@@ -16,8 +22,10 @@ from repro.kernels.partition_stage1.stage1 import (
 )
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block_p", "interpret"))
-def _stage1_impl(dl, d, du, b, *, m: int, block_p: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("m", "block_p", "interpret", "periodic"))
+def _stage1_impl(
+    dl, d, du, b, *, m: int, block_p: int, interpret: bool, periodic: bool = False
+):
     n = d.shape[-1]
     p = n // m
     pp = common.round_up(p, block_p)
@@ -34,6 +42,8 @@ def _stage1_impl(dl, d, du, b, *, m: int, block_p: int, interpret: bool):
     dlb, db, dub, bb = (a.reshape(p, m) for a in (dl, d, du, b))
     aL, bL, cL, dL = dlb[:, m - 1], db[:, m - 1], dub[:, m - 1], bb[:, m - 1]
     def pad(a):
+        if periodic:
+            return jnp.roll(a[:, 0], -1)
         return jnp.concatenate([a[1:, 0], jnp.zeros_like(a[:1, 0])])
 
     y_nf, v_nf, w_nf = pad(y), pad(v), pad(w)
@@ -53,8 +63,10 @@ def partition_stage1_pallas(
     m: int = 10,
     block_p: int = 512,
     interpret: bool | None = None,
+    periodic: bool = False,
 ) -> PartitionCoeffs:
-    """Stage 1 of the partition method for a single (N,) system via Pallas."""
+    """Stage 1 of the partition method for a single (N,) system via Pallas
+    (a cyclic one with ``periodic``)."""
     if interpret is None:
         interpret = common.interpret_default()
     dl, d, du, b = (jnp.asarray(a) for a in (dl, d, du, b))
@@ -62,11 +74,15 @@ def partition_stage1_pallas(
     if n % m:
         raise ValueError(f"system size {n} not divisible by m={m}")
     block_p = min(block_p, common.round_up(n // m, common.LANES))
-    return _stage1_impl(dl, d, du, b, m=m, block_p=block_p, interpret=interpret)
+    return _stage1_impl(
+        dl, d, du, b, m=m, block_p=block_p, interpret=interpret, periodic=periodic
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block_p", "interpret"))
-def _stage1_impl_batched(dl, d, du, b, *, m: int, block_p: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("m", "block_p", "interpret", "periodic"))
+def _stage1_impl_batched(
+    dl, d, du, b, *, m: int, block_p: int, interpret: bool, periodic: bool = False
+):
     bsz, n = d.shape
     p = n // m
     pp = common.round_up(p, block_p)
@@ -85,6 +101,8 @@ def _stage1_impl_batched(dl, d, du, b, *, m: int, block_p: int, interpret: bool)
     dlb, db, dub, bb = (a.reshape(bsz, p, m) for a in (dl, d, du, b))
     aL, bL, cL, dL = dlb[:, :, m - 1], db[:, :, m - 1], dub[:, :, m - 1], bb[:, :, m - 1]
     def pad(a):
+        if periodic:
+            return jnp.roll(a[:, :, 0], -1, axis=1)
         return jnp.concatenate(
             [a[:, 1:, 0], jnp.zeros_like(a[:, :1, 0])], axis=1
         )
@@ -98,10 +116,11 @@ def _stage1_impl_batched(dl, d, du, b, *, m: int, block_p: int, interpret: bool)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("m", "block_rows", "block_b", "interpret")
+    jax.jit, static_argnames=("m", "block_rows", "block_b", "interpret", "periodic")
 )
 def _stage1_impl_wide(
-    dlw, dw, duw, bw, *, m: int, block_rows: int, block_b: int, interpret: bool
+    dlw, dw, duw, bw, *, m: int, block_rows: int, block_b: int, interpret: bool,
+    periodic: bool = False,
 ):
     p, _, bsz = dw.shape
     pr = common.round_up(p, block_rows)
@@ -122,6 +141,8 @@ def _stage1_impl_wide(
     # along axis 0 = the block axis of each lane's system ----
     aL, bL, cL, dL = dlw[:, m - 1, :], dw[:, m - 1, :], duw[:, m - 1, :], bw[:, m - 1, :]
     def nxt(a):
+        if periodic:
+            return jnp.roll(a[:, 0, :], -1, axis=0)
         return jnp.concatenate(
             [a[1:, 0, :], jnp.zeros_like(a[:1, 0, :])], axis=0
         )
@@ -144,8 +165,10 @@ def partition_stage1_pallas_wide(
     block_rows: int = 32,
     block_b: int = 256,
     interpret: bool | None = None,
+    periodic: bool = False,
 ) -> PartitionCoeffs:
-    """Stage 1 on batch-interleaved (P, m, B) operands (systems on lanes).
+    """Stage 1 on batch-interleaved (P, m, B) operands (systems on lanes),
+    of cyclic systems with ``periodic``.
 
     Returns wide coeffs: spikes (P, m-1, B), reduced rows (P, B). See
     ``repro.core.tridiag.layout`` for the layout contract and the exactness
@@ -164,6 +187,7 @@ def partition_stage1_pallas_wide(
     return _stage1_impl_wide(
         dlw, dw, duw, bw,
         m=m, block_rows=block_rows, block_b=block_b, interpret=interpret,
+        periodic=periodic,
     )
 
 
@@ -176,8 +200,10 @@ def partition_stage1_pallas_batched(
     m: int = 10,
     block_p: int = 512,
     interpret: bool | None = None,
+    periodic: bool = False,
 ) -> PartitionCoeffs:
-    """Stage 1 for a (B, N) batch of systems via one batched-grid Pallas call."""
+    """Stage 1 for a (B, N) batch of systems via one batched-grid Pallas call
+    (cyclic systems with ``periodic``)."""
     if interpret is None:
         interpret = common.interpret_default()
     dl, d, du, b = (jnp.asarray(a) for a in (dl, d, du, b))
@@ -187,4 +213,6 @@ def partition_stage1_pallas_batched(
     if n % m:
         raise ValueError(f"system size {n} not divisible by m={m}")
     block_p = min(block_p, common.round_up(n // m, common.LANES))
-    return _stage1_impl_batched(dl, d, du, b, m=m, block_p=block_p, interpret=interpret)
+    return _stage1_impl_batched(
+        dl, d, du, b, m=m, block_p=block_p, interpret=interpret, periodic=periodic
+    )

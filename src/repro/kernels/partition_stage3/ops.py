@@ -1,4 +1,9 @@
-"""Jitted wrapper for the Stage-3 Pallas kernel + full pallas solve driver."""
+"""Jitted wrapper for the Stage-3 Pallas kernel, and the full Pallas solve.
+
+``periodic`` (static) back-substitutes cyclic systems: block 0's left
+interface value is the last block's (``s_left`` rolls along the block axis)
+instead of zero. Non-periodic calls trace exactly as without the flag.
+"""
 
 from __future__ import annotations
 
@@ -17,15 +22,18 @@ from repro.kernels.partition_stage3.stage3 import (
 )
 
 
-@functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
-def _stage3_impl(y, v, w, s, *, block_p: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("block_p", "interpret", "periodic"))
+def _stage3_impl(y, v, w, s, *, block_p: int, interpret: bool, periodic: bool = False):
     p, mi = y.shape
     m = mi + 1
     pp = common.round_up(p, block_p)
     def padT(a):
         return common.pad_axis_to(a.T, pp, axis=1)
 
-    s_left = jnp.concatenate([jnp.zeros_like(s[:1]), s[:-1]])
+    if periodic:
+        s_left = jnp.roll(s, 1)
+    else:
+        s_left = jnp.concatenate([jnp.zeros_like(s[:1]), s[:-1]])
     xT = stage3_tiled(
         padT(y), padT(v), padT(w),
         common.pad_axis_to(s[None, :], pp, axis=1),
@@ -41,14 +49,17 @@ def partition_stage3_pallas(
     *,
     block_p: int = 512,
     interpret: bool | None = None,
+    periodic: bool = False,
 ) -> jax.Array:
-    """Back-substitute interface values into block interiors via Pallas."""
+    """Back-substitute interface values into block interiors via Pallas
+    (of a cyclic system with ``periodic``)."""
     if interpret is None:
         interpret = common.interpret_default()
     p = s.shape[-1]
     block_p = min(block_p, common.round_up(p, common.LANES))
     return _stage3_impl(
-        coeffs.y, coeffs.v, coeffs.w, s, block_p=block_p, interpret=interpret
+        coeffs.y, coeffs.v, coeffs.w, s,
+        block_p=block_p, interpret=interpret, periodic=periodic,
     )
 
 
@@ -70,15 +81,21 @@ def partition_solve_pallas(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_rows", "block_b", "interpret")
+    jax.jit, static_argnames=("block_rows", "block_b", "interpret", "periodic")
 )
-def _stage3_impl_wide(y, v, w, s, *, block_rows: int, block_b: int, interpret: bool):
+def _stage3_impl_wide(
+    y, v, w, s, *, block_rows: int, block_b: int, interpret: bool,
+    periodic: bool = False,
+):
     p, mi, bsz = y.shape
     m = mi + 1
     pr = common.round_up(p, block_rows)
     bp = common.round_up(bsz, block_b)
     # s_left shifts along the block axis; row 0 is every system's first block.
-    s_left = jnp.concatenate([jnp.zeros_like(s[:1]), s[:-1]], axis=0)
+    if periodic:
+        s_left = jnp.roll(s, 1, axis=0)
+    else:
+        s_left = jnp.concatenate([jnp.zeros_like(s[:1]), s[:-1]], axis=0)
     def pad3(a):
         return common.pad_axis_to(common.pad_axis_to(a, bp, axis=2), pr, axis=0)
 
@@ -97,9 +114,11 @@ def partition_stage3_pallas_wide(
     block_rows: int = 32,
     block_b: int = 256,
     interpret: bool | None = None,
+    periodic: bool = False,
 ) -> jax.Array:
     """Back-substitution on batch-interleaved coeffs: (P, m-1, B) spikes +
-    (P, B) interface values → (P, m, B) wide solution."""
+    (P, B) interface values → (P, m, B) wide solution (cyclic systems with
+    ``periodic``)."""
     if interpret is None:
         interpret = common.interpret_default()
     p, _, bsz = coeffs.y.shape
@@ -108,18 +127,24 @@ def partition_stage3_pallas_wide(
     return _stage3_impl_wide(
         coeffs.y, coeffs.v, coeffs.w, s,
         block_rows=block_rows, block_b=block_b, interpret=interpret,
+        periodic=periodic,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
-def _stage3_impl_batched(y, v, w, s, *, block_p: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("block_p", "interpret", "periodic"))
+def _stage3_impl_batched(
+    y, v, w, s, *, block_p: int, interpret: bool, periodic: bool = False
+):
     bsz, p, mi = y.shape
     m = mi + 1
     pp = common.round_up(p, block_p)
     def padT(a):
         return common.pad_axis_to(a.transpose(0, 2, 1), pp, axis=2)
 
-    s_left = jnp.concatenate([jnp.zeros_like(s[:, :1]), s[:, :-1]], axis=1)
+    if periodic:
+        s_left = jnp.roll(s, 1, axis=1)
+    else:
+        s_left = jnp.concatenate([jnp.zeros_like(s[:, :1]), s[:, :-1]], axis=1)
     xT = stage3_tiled_batched(
         padT(y), padT(v), padT(w),
         common.pad_axis_to(s[:, None, :], pp, axis=2),
@@ -135,14 +160,17 @@ def partition_stage3_pallas_batched(
     *,
     block_p: int = 512,
     interpret: bool | None = None,
+    periodic: bool = False,
 ) -> jax.Array:
-    """Batched-grid back-substitution for (B, P, m-1) spikes and (B, P) s."""
+    """Batched-grid back-substitution for (B, P, m-1) spikes and (B, P) s
+    (cyclic systems with ``periodic``)."""
     if interpret is None:
         interpret = common.interpret_default()
     p = s.shape[-1]
     block_p = min(block_p, common.round_up(p, common.LANES))
     return _stage3_impl_batched(
-        coeffs.y, coeffs.v, coeffs.w, s, block_p=block_p, interpret=interpret
+        coeffs.y, coeffs.v, coeffs.w, s,
+        block_p=block_p, interpret=interpret, periodic=periodic,
     )
 
 

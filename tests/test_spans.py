@@ -79,6 +79,10 @@ VERBS = {
     "solve": (spans.SOLVE, lambda s: s.solve(*ONE), 400, 1),
     "solve_batched": (spans.SOLVE_BATCHED, lambda s: s.solve_batched(*BATCH), 800, 4),
     "solve_many": (spans.SOLVE_MANY, lambda s: s.solve_many(MANY), 600, 3),
+    "solve_periodic": (spans.SOLVE_PERIODIC, lambda s: s.solve_periodic(*ONE), 400, 1),
+    "solve_periodic_batched": (
+        spans.SOLVE_PERIODIC_BATCHED, lambda s: s.solve_periodic_batched(*BATCH), 800, 4
+    ),
 }
 
 #: The executable lookup follows the session's plan lookup, so a call
@@ -115,6 +119,24 @@ def test_verb_spans_nest_in_order(tmp_path: Path, verb: str):
             assert "stage2" in compiles[0].stats
             assert compiles[0].stats["stage2_levels"] == 0
     assert not [s for s in found if s.name == spans.BATCH]
+
+
+def test_periodic_call_is_marked_and_counted(tmp_path: Path):
+    """A periodic call's compile span says ``periodic``, a plain call's of
+    the same shape does not, and the session counts the periodic calls."""
+    clear_executable_cache()
+    with TridiagSession(SolverConfig(m=M)) as session:
+        found = record(
+            tmp_path,
+            lambda: [
+                session.solve_batched(*BATCH),
+                session.solve_periodic_batched(*BATCH),
+                session.solve_periodic_batched(*BATCH),
+            ],
+        )
+        assert session.stats["periodic"] == 2
+    compiles = [s for s in found if s.name == spans.COMPILE]
+    assert [bool(c.stats["periodic"]) for c in compiles] == [False, True]
 
 
 def test_compile_span_carries_the_recursion_depth(tmp_path: Path):
@@ -181,6 +203,20 @@ def test_interleaved_executable_carries_layout_and_stage_scopes():
 
 def test_system_major_executable_carries_stage_scopes():
     assert scopes_in(compiled_hlo(4000, "system-major")) == STAGES
+
+
+@pytest.mark.parametrize("layout", ["system-major", "interleaved"])
+def test_periodic_correction_sits_in_its_scope_inside_stage2(layout: str):
+    plan = build_plan((200,) * 4, M, periodic=True)
+    avals = [jax.ShapeDtypeStruct((plan.total_size,), jnp.float32)] * 4
+    fn, _ = _fused_callable(plan, ReferenceBackend(), True, avals, layout)
+    hlo = fn.as_text()
+    # same-size systems interleave by a relayout that may fuse away
+    gathers = {spans.INTERLEAVE, spans.DEINTERLEAVE} if layout == "interleaved" else set()
+    assert STAGES | {spans.PERIODIC} <= scopes_in(hlo) <= STAGES | {spans.PERIODIC} | gathers
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    periodic = [n for n in names if f"/{spans.PERIODIC}/" in n]
+    assert periodic and all(f"/{spans.STAGE2}/{spans.PERIODIC}/" in n for n in periodic)
 
 
 def test_sharded_executable_carries_collective_scopes(multi_device_count: int):

@@ -168,6 +168,35 @@ def test_wide_thomas_kernel(one_chip, x64):
     assert _has_kernel(c)
 
 
+def test_periodic_pencil_executable(one_chip, x64):
+    """The periodic fused executable at the cell's shape (16,384 cyclic
+    systems of 512 rows, m = 8): the wide kernels with the wrap, the Thomas
+    kernel on the two stacked right-hand sides (32,768 lanes), and the
+    correction's kernel, named after its wrapper, in the periodic scope."""
+    import re
+
+    from repro.core.tridiag import spans
+    from repro.core.tridiag.plan import _fused_callable, build_plan
+
+    plan = build_plan((512,) * 16384, 8, periodic=True)
+    avals = [_f32(one_chip, plan.total_size)] * 4
+    fn, stage2 = _fused_callable(
+        plan, PallasBackend(interpret=False), True, avals, "interleaved"
+    )
+    assert stage2 == "thomas_pallas_wide"
+    calls = dict(
+        (name.rsplit(".", 1)[0], scope)
+        for name, scope in re.findall(
+            r"^\s*%?([\w.-]+) = .* custom-call\(.*op_name=\"([^\"]*)\"", fn.as_text(), re.M
+        )
+    )
+    assert sorted(calls) == sorted(
+        ["_stage1_impl_wide", "_thomas_impl_wide", spans.PERIODIC_KERNEL, "_stage3_impl_wide"]
+    )
+    assert f"/{spans.STAGE2}/{spans.PERIODIC}/" in calls[spans.PERIODIC_KERNEL]
+    assert _bench_trace().op_class(spans.PERIODIC_KERNEL + ".1") == "xla_glue"
+
+
 def _bench_trace():
     """The benchmark's trace reduction, loaded by path (``bench`` is not a
     package of the solver)."""
