@@ -159,10 +159,13 @@ def interleave(a: Array, sizes: Sequence[int], m: int, *, fill: float = 0.0) -> 
     a = jnp.asarray(a)
     fwd, _, uniform = _index_maps(sizes, m)
     if uniform:
-        # Pure reshape/transpose — no gather, no fill needed.
+        # Pure reshape/transpose — no gather, no fill needed. One 2-D
+        # transpose (B, n) -> (n, B), then the row split: nothing in between
+        # holds ``m`` on the minor (lane) axis, which the TPU's (8, 128)
+        # tiling would pad 128/m-fold at small ``m``.
         bsz = len(sizes)
-        p = sizes[0] // m
-        return a.reshape(bsz, p, m).transpose(1, 2, 0)
+        n = sizes[0]
+        return a.reshape(bsz, n).T.reshape(n // m, m, bsz)
     a_ext = jnp.concatenate([a, jnp.full((1,), fill, a.dtype)])
     return jnp.take(a_ext, fwd, axis=0)
 
@@ -185,8 +188,10 @@ def deinterleave(xw: Array, sizes: Sequence[int], m: int) -> Array:
     xw = jnp.asarray(xw)
     _, inv, uniform = _index_maps(sizes, m)
     if uniform:
-        total = sum(sizes)
-        return xw.transpose(2, 0, 1).reshape(total)
+        # The reverse of interleave's lane-dense path: (P, m, B) -> (n, B),
+        # one transpose to (B, n), then flat.
+        p, _, bsz = xw.shape
+        return xw.reshape(p * m, bsz).T.reshape(-1)
     return jnp.take(xw.reshape(-1), inv, axis=0)
 
 

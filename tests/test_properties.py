@@ -100,6 +100,27 @@ def test_property_interleave_roundtrip(bsz, p_max, m, ragged, dtype, seed):
         assert np.all(w[pad] == 0.0)
 
 
+@pytest.mark.parametrize("bsz", [1, 33, 130])
+@pytest.mark.parametrize("m", [3, 8, 10])
+def test_uniform_interleave_is_the_index_map_gather(m, bsz):
+    """The uniform branches (reshape/transpose, no gather) equal, bit for
+    bit, the ragged branch's definition: the gather through ``_index_maps``'
+    ``fwd`` map, and its ``inv`` map back."""
+    from repro.core.tridiag.layout import _index_maps
+
+    sizes = (5 * m,) * bsz
+    fwd, inv, uniform = _index_maps(sizes, m)
+    assert uniform
+    rng = np.random.default_rng(1000 * m + bsz)
+    a = rng.standard_normal(sum(sizes)).astype(np.float32)
+    wide = np.asarray(interleave(a, sizes, m))
+    np.testing.assert_array_equal(wide, a[fwd])
+    xw = rng.standard_normal(wide.shape).astype(np.float32)
+    np.testing.assert_array_equal(
+        np.asarray(deinterleave(xw, sizes, m)), xw.reshape(-1)[inv]
+    )
+
+
 @settings(max_examples=15, deadline=None)
 @given(
     bsz=st.integers(min_value=1, max_value=6),

@@ -12,6 +12,7 @@ for n = 10⁷: at that size the Stage-1 wrapper's XLA relayouts take about
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,8 +118,6 @@ def test_fused_stage2_choice_p1e6(one_chip, x64):
     too large for the kernel's VMEM tiles, so three levels of the partition
     on the Stage-1 and Stage-3 kernels (10⁶ → 10⁵ → 10⁴ → 10³ rows), then
     the Thomas kernel, and no XLA loop."""
-    import re
-
     p = N_LARGE // M
     backend = PallasBackend(interpret=False)
     assert backend.reduced_solve_impl((p,), np.float32) == "partition_recursive"
@@ -173,8 +172,6 @@ def test_periodic_pencil_executable(one_chip, x64):
     systems of 512 rows, m = 8): the wide kernels with the wrap, the Thomas
     kernel on the two stacked right-hand sides (32,768 lanes), and the
     correction's kernel, named after its wrapper, in the periodic scope."""
-    import re
-
     from repro.core.tridiag import spans
     from repro.core.tridiag.plan import _fused_callable, build_plan
 
@@ -225,8 +222,6 @@ def test_fused_kernels_keep_their_trace_names(one_chip, sizes, layout, kernels):
     """Under the device scopes, the fused executable's Pallas custom calls
     keep the names the benchmark's trace reduction classes them by, and
     each carries its stage's scope."""
-    import re
-
     from repro.core.tridiag import spans
     from repro.core.tridiag.plan import _fused_callable, build_plan
 
@@ -243,3 +238,43 @@ def test_fused_kernels_keep_their_trace_names(one_chip, sizes, layout, kernels):
                                     (spans.STAGE1, spans.STAGE2, spans.STAGE3)):
         assert trace.op_class(kernel + ".1") == stage
         assert f"/{scope}/" in found[kernel]
+
+
+def _minor_dims(hlo_type: str):
+    """The minor (lane) dimension of each array in an HLO result type, e.g.
+    ``f32[64,8,16384]{1,0,2:T(8,128)}`` → 8: a layout lists dimensions
+    minor to major."""
+    for dims, layout in re.findall(r"\w+\[([\d,]*)\]\{([\d,]*)", hlo_type):
+        if dims and layout:
+            yield int(dims.split(",")[int(layout.split(",")[0])])
+
+
+@pytest.mark.parametrize(
+    "n, bsz, m, periodic, max_bytes",
+    [(512, 16384, 8, True, 3e9), (1000, 998, 10, False, 1.06e9)],
+    ids=["compact6-pencil", "adi-sweep"],
+)
+def test_interleave_keeps_systems_on_lanes(one_chip, x64, n, bsz, m, periodic, max_bytes):
+    """The uniform interleave and deinterleave at the benchmark's shapes: no
+    op in their scopes holds ``m`` on the minor axis (the (8, 128) tiling
+    would pad it to 128 lanes), and the executable's bytes accessed stay
+    bounded (the m-minor form read 9.47 GB and 1.06 GB)."""
+    from repro.core.tridiag import spans
+    from repro.core.tridiag.plan import _fused_callable, build_plan
+
+    plan = build_plan((n,) * bsz, m, periodic=periodic)
+    avals = [_f32(one_chip, plan.total_size)] * 4
+    fn, _ = _fused_callable(plan, PallasBackend(interpret=False), True, avals, "interleaved")
+    scoped = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.-]+) = (.*?) [\w-]+\(.*op_name=\"[^\"]*/("
+        + "|".join(map(re.escape, (spans.INTERLEAVE, spans.DEINTERLEAVE)))
+        + r")/",
+        fn.as_text(),
+        re.M,
+    )
+    assert {scope for _, _, scope in scoped} == {spans.INTERLEAVE, spans.DEINTERLEAVE}
+    padded = [name for name, hlo_type, _ in scoped if m in _minor_dims(hlo_type)]
+    assert not padded
+    cost = fn.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] <= max_bytes
