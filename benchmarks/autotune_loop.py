@@ -103,7 +103,6 @@ def _seed_observations() -> List[BatchObservation]:
                         num_chunks=k,
                         backend="seed",
                         layout="system-major",
-                        dispatch="fused",
                         latency_ms=lat,
                         mean_wait_ms=0.0,
                         max_wait_ms=0.0,

@@ -1,15 +1,17 @@
-"""Staged vs fused dispatch: end-to-end solve latency per (backend × size × chunks).
+"""Staged instrument vs fused session: solve latency per (backend × size × chunks).
 
 The paper's core premise is that dispatch overhead — not FLOPs — decides
 partition-method latency at small system sizes (it models stream-creation
 overhead separately from the non-dominant operation times for exactly this
-reason). This bench makes our two execution paths comparable on that axis:
+reason). This bench makes the repo's two executors comparable on that axis:
 
-- **staged** (`PlanExecutor`): per-chunk device dispatch from a Python loop
-  plus a host round-trip for the Stage-2 reduced solve — the paper's layout,
-  and the one whose per-phase breakdown the measurement campaigns consume;
-- **fused** (`FusedExecutor`): the whole three-stage solve compiled into ONE
-  donated-buffer XLA dispatch with the reduced solve on device.
+- **staged** (`measure.staged_timing`, on `PlanExecutor`): per-chunk device
+  dispatch from a Python loop plus a host round-trip for the Stage-2 reduced
+  solve — the paper's layout, and the one whose per-phase breakdown the
+  measurement campaigns consume;
+- **fused** (`TridiagSession.solve`, on `FusedExecutor`): the whole
+  three-stage solve compiled into ONE donated-buffer XLA dispatch with the
+  reduced solve on device.
 
 Every cell is fp64-oracle-checked on BOTH paths before it is timed, and the
 row carries the fused:staged speedup. At small sizes (n ≤ ~2560) the staged
@@ -30,6 +32,7 @@ import time
 import jax
 import numpy as np
 
+from repro.core.streams.measure import staged_timing
 from repro.core.tridiag.api import SolverConfig, TridiagSession
 from repro.core.tridiag.reference import make_diag_dominant_system, thomas_numpy
 
@@ -50,10 +53,10 @@ def dispatch_latency(
 ):
     """best-of-reps latency for both dispatch paths + fused:staged speedup.
 
-    Both sessions per cell derive from ONE ``SolverConfig`` via
-    ``replace(dispatch=...)`` — the exact knob a deployment flips — and both
-    solutions are checked against the fp64 ``thomas_numpy`` oracle before
-    timing; an off-oracle path is a bug, not a data point.
+    Each cell runs the same plan (``m``, ``backend``, ``num_chunks=k``) on
+    both executors, and both solutions are checked against the fp64
+    ``thomas_numpy`` oracle before timing; an off-oracle path is a bug, not
+    a data point.
     """
     prev_x64 = jax.config.jax_enable_x64
     jax.config.update("jax_enable_x64", True)
@@ -76,23 +79,29 @@ def _dispatch_latency(sizes, chunk_counts, backends, *, m, reps, tol):
         ref = thomas_numpy(dl, d, du, b)
         scale = np.max(np.abs(ref)) + 1e-30
         for backend in backends:
-            base = SolverConfig(m=m, backend=backend, num_chunks=1)
             for k in chunk_counts:
-                cfg = base.replace(num_chunks=k)
+                session = TridiagSession(
+                    SolverConfig(m=m, backend=backend, num_chunks=k)
+                )
+                solvers = {
+                    "staged": lambda: staged_timing(
+                        n, dl, d, du, b, m=m, num_chunks=k, backend=backend
+                    )[0],
+                    "fused": lambda: session.solve(dl, d, du, b),
+                }
                 cell = {}
-                for mode in ("staged", "fused"):
-                    session = TridiagSession(cfg.replace(dispatch=mode))
-                    x = session.solve(dl, d, du, b)  # warmup + oracle probe
+                for mode, solve in solvers.items():
+                    x = solve()  # warmup + oracle probe
                     err = float(np.max(np.abs(x - ref)) / scale)
                     if err > tol:
                         raise RuntimeError(
-                            f"{mode} dispatch off fp64 oracle on backend "
+                            f"{mode} path off fp64 oracle on backend "
                             f"{backend!r}: n={n} k={k} err={err:.2e}"
                         )
                     best = np.inf
                     for _ in range(reps):
                         t0 = time.perf_counter()
-                        session.solve(dl, d, du, b)
+                        solve()
                         best = min(best, time.perf_counter() - t0)
                     cell[mode] = (best, err)
                 (t_staged, err_s), (t_fused, err_f) = cell["staged"], cell["fused"]

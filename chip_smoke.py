@@ -4,8 +4,8 @@
     python chip_smoke.py [--seed N]     # one chip: the five phases below
     python chip_smoke.py --chips 4      # four chips: sharded vs one device
 
-One chip, every phase in fp32 through ``TridiagSession`` with
-``backend="auto"``:
+One chip, every phase in fp32 with ``backend="auto"``, all but the last
+through ``TridiagSession``:
 
   single_1e6 / single_1e7  ``solve`` of one system of 10⁶ and 10⁷ rows
                            (the paper's Table-4 sizes);
@@ -13,8 +13,9 @@ One chip, every phase in fp32 through ``TridiagSession`` with
                            which must take the interleaved layout;
   served_32                32 requests of 1k–100k rows through ``submit``
                            (``max_batch=8, max_wait_ms=5``);
-  timed_1e6                one ``solve_timed``: the staged path with its
-                           host reduced solve.
+  timed_1e6                one ``measure.staged_timing``: the paper's
+                           per-phase instrument, with its host reduced
+                           solve.
 
 ``--chips 4`` runs only what exists across chips: the 10⁷-row system on the
 system-major sharded path and the 1024-system batch on the lane-sharded
@@ -194,13 +195,20 @@ def phase_served(seed: int) -> None:
     check_err("served", err)
 
 
-def phase_timed(seed: int) -> None:
-    from repro.api import SolverConfig, TridiagSession
+def phase_staged(seed: int) -> None:
+    from repro.core.streams.measure import staged_timing
+    from repro.core.tridiag import resolve_backend
 
     ops, ref = system(TIMED_N, seed)
-    with TridiagSession(SolverConfig(m=M, dtype=np.float32)) as s:
-        (x, timing), times = first_and_steady(lambda: s.solve_timed(*ops))
-        stats = s.stats
+    backend = resolve_backend("auto")
+    (x, timing), times = first_and_steady(
+        lambda: staged_timing(TIMED_N, *ops, m=M, num_chunks=1, backend=backend)
+    )
+    stats = {
+        "backend": {"name": backend.name, "interpret": backend.interpret_mode()},
+        "layout": {timing.layout: 1},
+        "stage2": {timing.stage2: 1},
+    }
     err = rel_err(x, ref)
     report(
         f"timed_{TIMED_N:.0e}".replace("+0", ""),
@@ -219,7 +227,7 @@ def run_one_chip(seed: int) -> None:
         phase_single(n, seed + i)
     phase_batch(seed + 10)
     phase_served(seed + 20)
-    phase_timed(seed)
+    phase_staged(seed)
 
 
 # ----------------------------------------------------------- four chips --

@@ -85,9 +85,11 @@ def main():
     )
     with TridiagSession(cfg) as session:
         dl, d, du, b, _ = make_diag_dominant_system(400_000, seed=0)
-        _, timing = session.solve_timed(dl, d, du, b)
-        print(f"session solve: N=400,000 -> policy picked {timing.num_chunks} "
-              f"chunks, {timing.t_total_ms:.2f} ms wall")
+        t0 = time.perf_counter()
+        session.solve(dl, d, du, b)
+        print(f"session solve: N=400,000 -> policy picked "
+              f"{session.plan_for(400_000).num_chunks} chunks, "
+              f"{(time.perf_counter() - t0) * 1e3:.2f} ms wall")
 
         # Ragged mixed-size fused batch: three heterogeneous systems, one plan.
         mix = (200, 1_000, 5_000)
@@ -95,23 +97,23 @@ def main():
             make_diag_dominant_system(n, seed=i)[:4] for i, n in enumerate(mix)
         ]
         plan = session.plan_for(mix)
-        xs, timing = session.solve_many_timed(systems)
+        t0 = time.perf_counter()
+        xs = session.solve_many(systems)
+        wall_ms = (time.perf_counter() - t0) * 1e3
         err = max(
             float(np.max(np.abs(xi - thomas_numpy(*s))))
             for xi, s in zip(xs, systems)
         )
         print(f"session solve_many: sizes={mix} -> effective "
               f"{plan.effective_size:,}, {plan.num_chunks} chunks, "
-              f"{timing.t_total_ms:.2f} ms, "
+              f"{wall_ms:.2f} ms, "
               f"max |err| vs per-system Thomas = {err:.2e}")
 
         # Served traffic through the SAME config: submit returns futures and
         # the session's worker dispatches at max_batch/the 5 ms deadline —
-        # autotune fit to serving, one object, no poll() anywhere. Under the
-        # config's default dispatch="auto" every served batch runs the FUSED
-        # path: one compiled XLA dispatch per batch (device-side reduced
-        # solve, donated buffers), while the solve_timed calls above stayed
-        # staged so their phase breakdown existed.
+        # autotune fit to serving, one object, no poll() anywhere. Every
+        # served batch runs the FUSED path: one compiled XLA dispatch per
+        # batch (device-side reduced solve, donated buffers).
         futs = []
         for rid, n in enumerate((200, 1_000, 5_000, 200, 1_000)):
             system = make_diag_dominant_system(n, seed=10 + rid)[:4]
@@ -153,7 +155,7 @@ def main():
                 for _ in range(3):
                     session.telemetry.record(BatchObservation(
                         t=t, sizes=(n,), num_chunks=k, backend="demo",
-                        layout="system-major", dispatch="fused",
+                        layout="system-major",
                         latency_ms=t_non - gained,
                         mean_wait_ms=0.0, max_wait_ms=0.0,
                     ))

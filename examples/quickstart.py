@@ -17,11 +17,11 @@ plus the ML heuristic of the paper: fit it on a stream campaign, wrap it in a
 ``HeuristicChunkPolicy``, and the same session picks the optimum chunk
 ("virtual stream") count per dispatch.
 
-Under the default ``dispatch="auto"`` the plain verbs (and served batches)
-run the FUSED path — the whole three-stage solve compiled into one
-donated-buffer XLA dispatch, reduced solve on device — while the ``*_timed``
-verbs keep the staged per-chunk path whose phase breakdown the paper's
-analysis needs. Step 1b below shows the difference.
+Every verb (and every served batch) runs the FUSED path — the whole
+three-stage solve compiled into one donated-buffer XLA dispatch, reduced
+solve on device. The per-phase breakdown the paper's analysis needs comes
+from its own instrument, ``repro.core.streams.measure.staged_timing``, which
+runs the same plan on the staged per-chunk path. Step 1b below shows both.
 """
 
 import time
@@ -40,6 +40,7 @@ from repro.api import (  # noqa: E402
 )
 from repro.configs.paper_tridiag import CONFIG  # noqa: E402
 from repro.core.autotune.heuristic import fit_stream_heuristic  # noqa: E402
+from repro.core.streams.measure import staged_timing  # noqa: E402
 from repro.core.streams.simulator import StreamSimulator  # noqa: E402
 from repro.core.tridiag import make_diag_dominant_system, thomas_numpy  # noqa: E402
 
@@ -54,25 +55,30 @@ def main():
     dl, d, du, b, x_true = make_diag_dominant_system(n, seed=0)
 
     with TridiagSession(cfg) as session:
-        # 1) one system through the chunked partition method (solve_timed
-        #    runs the STAGED path, so the per-phase breakdown exists)
-        x, timing = session.solve_timed(dl, d, du, b)
-        print(f"solve         n={n:,}: max|x - x_true| = "
-              f"{np.max(np.abs(x - x_true)):.3e}  "
-              f"({timing.num_chunks} chunks, {timing.t_total_ms:.2f} ms)")
-
-        # 1b) the plain verb runs the FUSED path: one compiled XLA dispatch
-        #     for all three stages, reduced solve on device, donated buffers.
-        #     Both paths get a warm rerun so neither number carries compile
-        #     time.
-        _, staged_warm = session.solve_timed(dl, d, du, b)
-        session.solve(dl, d, du, b)  # warmup (compiles the fused executable)
+        # 1) one system through the chunked partition method: one compiled
+        #    XLA dispatch for all three stages, reduced solve on device
+        x = session.solve(dl, d, du, b)  # first call compiles the executable
         t0 = time.perf_counter()
         session.solve(dl, d, du, b)
         t_fused_ms = (time.perf_counter() - t0) * 1e3
-        print(f"dispatch      staged {staged_warm.t_total_ms:.2f} ms vs "
-              f"fused {t_fused_ms:.2f} ms for the same plan "
-              f"({staged_warm.t_total_ms / max(t_fused_ms, 1e-9):.1f}x)")
+        print(f"solve         n={n:,}: max|x - x_true| = "
+              f"{np.max(np.abs(x - x_true)):.3e}  "
+              f"({session.plan_for(n).num_chunks} chunks, {t_fused_ms:.2f} ms)")
+
+        # 1b) the same plan on the paper's per-phase instrument: per-chunk
+        #     dispatch with a host round trip for Stage 2, which makes the
+        #     phase times observable. A warm rerun keeps compile time out.
+        def staged():
+            return staged_timing(n, dl, d, du, b, m=m,
+                                 num_chunks=cfg.num_chunks, backend=cfg.backend)
+
+        staged()
+        _, phases = staged()
+        print("phases        stage1/2/3 = "
+              + " / ".join(f"{t:.2f}" for t in phases.phases)
+              + f" ms; staged {phases.t_total_ms:.2f} ms vs fused "
+              f"{t_fused_ms:.2f} ms "
+              f"({phases.t_total_ms / max(t_fused_ms, 1e-9):.1f}x)")
 
         # 2) a batch of same-size systems, fused into one dispatch
         DL, D, DU, B, _ = make_diag_dominant_system(2_000, seed=1, batch=(8,))

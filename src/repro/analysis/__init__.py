@@ -36,9 +36,6 @@ TRD003    trace-purity        functions traced by ``jax.jit`` / ``pl.pallas_call
                               must not call host ops (``np.*`` on traced values,
                               ``time.*``, Python RNG, ``print``) or mutate
                               nonlocal/global state.
-TRD004    deprecated-frontend no construction of ``ChunkedPartitionSolver`` /
-                              ``BatchedPartitionSolver`` / ``RaggedPartitionSolver``
-                              / ``serve.BatchedSolveService`` outside ``tests/``.
 TRD005    api-surface         every ``repro.api`` ``__all__`` name resolves and
                               (for classes/functions) carries a docstring; every
                               ``SolverConfig`` field appears in its docstring.

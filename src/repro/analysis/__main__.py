@@ -37,7 +37,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m repro.analysis",
         description="Repo-specific invariant checker (lock discipline, "
-        "donation safety, trace purity, deprecated frontends, api surface).",
+        "donation safety, trace purity, api surface).",
     )
     sub = parser.add_subparsers(dest="command", required=True)
     chk = sub.add_parser("check", help="run the rules over files/directories")

@@ -56,13 +56,12 @@ class FileContext:
 def _load_rules() -> Dict[str, object]:
     from repro.analysis import (
         api_surface,
-        deprecated,
         donation,
         lock_guard,
         purity,
     )
 
-    modules = (lock_guard, donation, purity, deprecated, api_surface)
+    modules = (lock_guard, donation, purity, api_surface)
     return {m.CODE: m for m in modules}
 
 
@@ -124,7 +123,7 @@ def check_paths(
 ) -> List[Violation]:
     """Run every selected rule over ``paths`` (files or directories).
 
-    Per-file rules (TRD001-TRD004) run on each discovered ``*.py`` file;
+    Per-file rules (TRD001-TRD003) run on each discovered ``*.py`` file;
     repo-level rules (TRD005) run once per invocation. Returns the combined
     findings sorted by location.
     """

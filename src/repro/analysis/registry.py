@@ -113,25 +113,16 @@ class Registry:
     guarded_attrs: Tuple[GuardedAttrs, ...] = ()
     donating_calls: Tuple[DonatingCall, ...] = (DonatingCall(),)
     purity: PurityConfig = field(default_factory=PurityConfig)
-    #: Deprecated frontends: constructing these outside ``tests/`` is TRD004.
-    deprecated_frontends: Tuple[str, ...] = (
-        "ChunkedPartitionSolver",
-        "BatchedPartitionSolver",
-        "RaggedPartitionSolver",
-        "BatchedSolveService",
-    )
-    #: Path fragments under which TRD004 does not apply.
-    deprecated_allowed_under: Tuple[str, ...] = ("tests/",)
     #: The public surface TRD005 audits (module, config class in its __all__).
     api_module: str = "repro.api"
     api_config_class: str = "SolverConfig"
 
 
 #: The engine's queue-side state is owner-serialised: ``TridiagSession``
-#: holds ``_cv`` around every engine call, and the legacy shim is documented
-#: single-threaded — so the engine's own methods are the allowlist, and the
-#: rule's job is catching *outside* touches (a session or test reaching into
-#: ``engine._queue`` without the lock).
+#: holds ``_cv`` around every engine call, and a direct caller of the engine
+#: is documented single-threaded — so the engine's own methods are the
+#: allowlist, and the rule's job is catching *outside* touches (a session
+#: or test reaching into ``engine._queue`` without the lock).
 _ENGINE_METHODS = tuple(
     f"SolveEngine.{name}"
     for name in (

@@ -9,7 +9,6 @@ docstring and the root README for the full tour.
 from repro.core.tridiag.api import (
     AUTOTUNE_MODES,
     BACKEND_NAMES,
-    DISPATCH_MODES,
     AdmissionPolicy,
     PredictedTimeoutError,
     QueueFullError,
@@ -52,7 +51,6 @@ __all__ = [
     "BatchObservation",
     "BACKENDS",
     "ChunkPolicy",
-    "DISPATCH_MODES",
     "FixedChunkPolicy",
     "FusedExecutor",
     "HeuristicChunkPolicy",

@@ -3,24 +3,54 @@ THIS machine and feed the same ML pipeline the simulator feeds (DESIGN.md §2.2
 — demonstrates the heuristic is hardware-agnostic; on a TPU host the identical
 code measures chunked device execution).
 
-All three campaigns drive the facade (`repro.api.SolverConfig` /
-`TridiagSession`): one base config names the solve setup (m, backend) and
-each campaign cell is ``base.replace(num_chunks=k)`` — the exact config
-object a fitted heuristic will later serve through, so the calibration and
-the serving path cannot drift apart.
+All three campaigns time each solve with :func:`staged_timing`: the plan a
+session would build for the same sizes and chunk count, run by the staged
+:class:`~repro.core.tridiag.plan.PlanExecutor`, whose host round trips
+between the stages make the per-phase times (``sum = t1 + t3``, the Eq.-5
+overhead) observable.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.streams.simulator import StreamDataset
 from repro.core.streams.timemodel import overhead_from_measurement
-from repro.core.tridiag.api import SolverConfig, TridiagSession
-from repro.core.tridiag.plan import ChunkTiming
+from repro.core.tridiag.batched import fuse_systems
+from repro.core.tridiag.plan import (
+    BackendLike,
+    ChunkTiming,
+    PlanExecutor,
+    Sizes,
+    build_plan,
+)
+from repro.core.tridiag.ragged import fuse_ragged
 from repro.core.tridiag.reference import make_diag_dominant_system
+
+
+def staged_timing(
+    sizes: Sizes,
+    dl: Any,
+    d: Any,
+    du: Any,
+    b: Any,
+    *,
+    m: int,
+    num_chunks: int,
+    backend: BackendLike,
+) -> Tuple[np.ndarray, ChunkTiming]:
+    """One solve of fused operands with its per-phase wall-clock breakdown.
+
+    ``sizes`` is the system size, or the sizes of the systems fused into
+    ``dl, d, du, b`` (:func:`~repro.core.tridiag.batched.fuse_systems`,
+    :func:`~repro.core.tridiag.ragged.fuse_ragged`). The plan is the one a
+    session builds for ``num_chunks`` without a mesh; it runs on the staged
+    executor with ``backend`` (None: the reference stages). Returns the
+    fused solution and its :class:`ChunkTiming`."""
+    plan = build_plan(sizes, m, num_chunks=num_chunks)
+    return PlanExecutor(backend).execute(plan, dl, d, du, b)
 
 
 def _measure_cell(
@@ -69,25 +99,6 @@ def _measure_cell(
             rows.append(row)
 
 
-def _base_config(m: int, backend) -> SolverConfig:
-    # Campaigns historically measured the reference stages when no backend
-    # was named; keep that (pass backend="auto"/"pallas" explicitly to
-    # profile the kernel path).
-    #
-    # Dispatch is pinned to "staged" — the campaigns' whole dataset is the
-    # per-phase breakdown (sum = t1 + t3, Eq. 5 overhead), which only the
-    # staged path's host round-trips make observable. The fused path's
-    # end-to-end latency is benchmarked separately in
-    # benchmarks/dispatch_latency.py. ("auto" would also route the *_timed
-    # verbs to staged; pinning makes the dependency explicit and survives
-    # any future change to the auto rule.)
-    return SolverConfig(
-        m=m,
-        backend=backend if backend is not None else "reference",
-        dispatch="staged",
-    )
-
-
 def measure_dataset(
     sizes: Sequence[int],
     candidates: Sequence[int] = (1, 2, 4, 8, 16, 32),
@@ -103,13 +114,12 @@ def measure_dataset(
     ``backend`` selects the stage implementation being profiled (reference jnp
     stages by default; ``"pallas"`` measures the kernel path), so one campaign
     pipeline calibrates the heuristic for whichever backend will serve."""
-    base = _base_config(m, backend)
     rows: List[Dict] = []
     for n in sizes:
         dl, d, du, b, _ = make_diag_dominant_system(n, seed=seed, dtype=dtype)
-        def run(k, dl=dl, d=d, du=du, b=b):
-            return TridiagSession(base.replace(num_chunks=k)).solve_timed(
-                dl, d, du, b
+        def run(k, n=n, ops=(dl, d, du, b)):
+            return staged_timing(
+                n, *ops, m=m, num_chunks=k, backend=backend
             )[1]
         _measure_cell(
             rows, run, size=n, batch=None, candidates=candidates, reps=reps
@@ -130,20 +140,20 @@ def measure_batched_dataset(
 ) -> StreamDataset:
     """Wall-clock campaign over the 2-D (size × batch) grid.
 
-    Each cell solves a batch of B independent size-n systems through the
-    session's fused batched verb (on ``backend``); rows carry the ``batch``
-    key consumed by ``fit_batched_stream_heuristic``."""
-    base = _base_config(m, backend)
+    Each cell fuses a batch of B independent size-n systems into one solve
+    (on ``backend``); rows carry the ``batch`` key consumed by
+    ``fit_batched_stream_heuristic``."""
     rows: List[Dict] = []
     for n in sizes:
         for batch in batches:
             dl, d, du, b, _ = make_diag_dominant_system(
                 n, seed=seed, batch=(batch,), dtype=dtype
             )
-            def run(k, dl=dl, d=d, du=du, b=b):
-                return TridiagSession(
-                    base.replace(num_chunks=k)
-                ).solve_batched_timed(dl, d, du, b)[1]
+            ops = fuse_systems(dl, d, du, b)
+            def run(k, group=(n,) * batch, ops=ops):
+                return staged_timing(
+                    group, *ops, m=m, num_chunks=k, backend=backend
+                )[1]
             _measure_cell(
                 rows, run, size=n, batch=batch, candidates=candidates, reps=reps
             )
@@ -163,22 +173,21 @@ def measure_ragged_dataset(
     """Wall-clock campaign over ragged mixed-size batches.
 
     Each cell fuses one *mix* — a tuple of heterogeneous system sizes — into a
-    single ``solve_many`` dispatch (on ``backend``) and sweeps the chunk
-    candidates. Rows carry ``size = Σ nᵢ`` (the effective size the heuristic
-    prices ragged batches by) and the originating ``mix``, so the same
+    single solve (on ``backend``) and sweeps the chunk candidates. Rows
+    carry ``size = Σ nᵢ`` (the effective size the heuristic prices ragged
+    batches by) and the originating ``mix``, so the same
     ``fit_batched_stream_heuristic`` pipeline consumes them unchanged."""
-    base = _base_config(m, backend)
     rows: List[Dict] = []
     for mix in mixes:
         mix = tuple(int(n) for n in mix)
-        systems = [
+        *ops, fused_sizes = fuse_ragged([
             make_diag_dominant_system(n, seed=seed + i, dtype=dtype)[:4]
             for i, n in enumerate(mix)
-        ]
-        def run(k, systems=systems):
-            return TridiagSession(
-                base.replace(num_chunks=k)
-            ).solve_many_timed(systems)[1]
+        ])
+        def run(k, group=fused_sizes, ops=tuple(ops)):
+            return staged_timing(
+                group, *ops, m=m, num_chunks=k, backend=backend
+            )[1]
         _measure_cell(
             rows, run, size=sum(mix), batch=None, candidates=candidates,
             reps=reps, mix=mix,

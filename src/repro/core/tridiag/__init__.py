@@ -10,9 +10,10 @@ N-row tridiagonal system into P = N/m sub-systems ("blocks") of m rows:
   Stage 2 (serial, CPU in the paper): solve the reduced P-size system.
   Stage 3 (parallel over blocks): back-substitute s into block interiors.
 
-`chunked.py` adds the CUDA-stream analogue: the block dimension is split into
-`num_chunks` slices whose host staging / device compute overlap via JAX async
-dispatch (see DESIGN.md §2.1).
+`plan.py` adds the CUDA-stream analogue: the block dimension is split into
+`num_chunks` slices ("virtual streams"); the staged ``PlanExecutor`` overlaps
+their host staging and device compute via JAX async dispatch (DESIGN.md
+§2.1).
 
 Batched solving & autotune
 --------------------------
@@ -21,16 +22,14 @@ production regime of the ROADMAP north star. A batch of B size-n systems
 fuses (by concatenation, with boundary couplings zeroed) into one B·n solve
 whose reduced system decouples exactly, so chunks span system boundaries::
 
-    from repro.core.tridiag.batched import BatchedPartitionSolver, solve_batched
+    from repro.core.tridiag.batched import solve_batched
 
     x = solve_batched(dl, d, du, b, m=10)            # (B, n) -> (B, n)
-    solver = BatchedPartitionSolver(m=10, num_chunks=8)
-    x, timing = solver.solve_timed(dl, d, du, b)     # chunked + wall-clock
 
 The optimum chunk count over the 2-D (size, batch) grid is fitted/predicted
 by ``repro.core.autotune.heuristic.BatchedStreamHeuristic`` (ground truth:
-``StreamSimulator.actual_optimum(n, batch=B)``), and served by
-``repro.serve.solve.BatchedSolveService``.
+``StreamSimulator.actual_optimum(n, batch=B)``), and served through
+``HeuristicChunkPolicy`` by the session below.
 
 The front door: config + session (``api.py``)
 ---------------------------------------------
@@ -54,29 +53,25 @@ batch shape through four verbs::
         x0  = fut.result(timeout=1.0)        # deadline fires w/o poll()
 
 ``submit`` is backed by a daemon worker thread running the admission loop
-(`api.SolveEngine`, which also powers the deprecated
-``serve.BatchedSolveService`` shim); ``close()``/the context manager drains
-the queue. The legacy ``ChunkedPartitionSolver`` / ``BatchedPartitionSolver``
-/ ``RaggedPartitionSolver`` classes survive as deprecated wrappers that
-delegate to an equivalently-configured session.
+(`api.SolveEngine`); ``close()``/the context manager drains the queue.
 
 Plan/execute architecture
 -------------------------
 `plan.py` is the single execution path: an immutable ``SolvePlan`` (fused
 block layout, chunk bounds, halo map, per-system offsets; chunk count from a
-pluggable ``ChunkPolicy``) executed by two executors behind
-``SolverConfig.dispatch`` — ``PlanExecutor`` (staged: per-chunk dispatch +
-host reduced solve, per-phase ``ChunkTiming``) and ``FusedExecutor`` (the
-whole three-stage solve AOT-compiled into one donated-buffer executable,
-cached in a bounded LRU). Stage callables are cached module-wide per
-``(m, backend)``; the stage implementation is itself pluggable
-(``ReferenceBackend`` jnp stages, ``PallasBackend`` kernels, ``"auto"``
-resolving per host), and plans are memoised by their
-``(sizes, m, num_chunks)`` signature (all caches lock-protected: sessions
-solve from two threads). `ragged.py` fuses *mixed-size* systems into one
-block axis (exact decoupling via zeroed boundary couplings), so one fused
-chunked solve covers a heterogeneous batch — priced by its effective size
-``Σ nᵢ`` through the stream heuristic.
+pluggable ``ChunkPolicy``) executed by ``FusedExecutor`` (the whole
+three-stage solve AOT-compiled into one donated-buffer executable, cached in
+a bounded LRU), the session's only executor. ``PlanExecutor`` (per-chunk
+dispatch + host reduced solve, per-phase ``ChunkTiming``) is the paper's
+measuring instrument, run by ``repro.core.streams.measure.staged_timing``.
+Stage callables are cached module-wide per ``(m, backend)``; the stage
+implementation is itself pluggable (``ReferenceBackend`` jnp stages,
+``PallasBackend`` kernels, ``"auto"`` resolving per host), and plans are
+memoised by their ``(sizes, m, num_chunks)`` signature (all caches
+lock-protected: sessions solve from two threads). `ragged.py` fuses
+*mixed-size* systems into one block axis (exact decoupling via zeroed
+boundary couplings), so one fused chunked solve covers a heterogeneous
+batch — priced by its effective size ``Σ nᵢ`` through the stream heuristic.
 
 Operand layouts (``layout.py``)
 -------------------------------
@@ -104,10 +99,8 @@ tiny reduced system is gathered (``all_gather`` of per-shard reduced rows,
 replicated device Thomas solve). On the interleaved layout the lane axis
 splits over a ``"batch"`` axis with no collectives, and the ``"auto"``
 interleave threshold counts per-shard lanes. Sharded executables are cached
-under the device-set signature; ``mesh`` composes with ``dispatch="fused"``
-/ ``"auto"`` only (the staged path is the per-chunk measurement harness),
-and ``mesh=None`` stays bit-identical to the single-device build. CPU rigs
-exercise the whole path under
+under the device-set signature, and ``mesh=None`` stays bit-identical to
+the single-device build. CPU rigs exercise the whole path under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (``tests/conftest
 .py``, ``benchmarks/sharded_throughput.py``).
 
@@ -172,22 +165,17 @@ from repro.core.tridiag.plan import (
     resolve_backend,
     set_executable_cache_capacity,
 )
-from repro.core.tridiag.chunked import ChunkedPartitionSolver
 from repro.core.tridiag.batched import (
-    BatchedPartitionSolver,
     fuse_systems,
     solve_batched,
     split_systems,
     thomas_batched,
 )
 from repro.core.tridiag.ragged import (
-    RaggedPartitionSolver,
     fuse_ragged,
-    solve_ragged,
     split_ragged,
 )
 from repro.core.tridiag.api import (
-    DISPATCH_MODES,
     AdmissionPolicy,
     QueueFullError,
     RequestCancelledError,
@@ -217,7 +205,6 @@ __all__ = [
     "BACKENDS",
     "ChunkPolicy",
     "ChunkTiming",
-    "DISPATCH_MODES",
     "FixedChunkPolicy",
     "FusedExecutor",
     "HeuristicChunkPolicy",
@@ -244,15 +231,11 @@ __all__ = [
     "price_chunks",
     "resolve_backend",
     "set_executable_cache_capacity",
-    "ChunkedPartitionSolver",
-    "BatchedPartitionSolver",
     "solve_batched",
     "thomas_batched",
     "fuse_systems",
     "split_systems",
-    "RaggedPartitionSolver",
     "fuse_ragged",
-    "solve_ragged",
     "split_ragged",
     "AdmissionPolicy",
     "QueueFullError",

@@ -22,12 +22,10 @@ batch shape through four verbs:
     asynchronous serving — the request joins the session's admission queue
     and the future resolves when its batch dispatches.
 
-How each verb *executes* is the config's ``dispatch`` knob: ``"staged"``
-(per-chunk dispatch + host reduced solve, per-phase timing), ``"fused"``
-(the whole three-stage solve compiled into one donated-buffer XLA dispatch,
-reduced solve on device), or ``"auto"`` (default) — fused for the plain
-verbs and served batches, staged for the ``*_timed`` verbs so the
-measurement campaigns keep their phase breakdown.
+Every verb and every served batch runs the fused executor: the whole
+three-stage solve compiled into one donated-buffer XLA dispatch, reduced
+solve on device. Per-phase timing (the paper's Eq.-5 breakdown) is an
+instrument of its own, :func:`repro.core.streams.measure.staged_timing`.
 
 ``submit`` is backed by a daemon worker thread driving the
 :class:`AdmissionPolicy` loop, so a deadline (``max_wait_ms``) fires without
@@ -62,9 +60,8 @@ Serving under load (the heavy-traffic contract):
   rejected/timed-out/cancelled/failed counts, and the plan- and
   executable-cache counters.
 
-The queue/admission/dispatch core is :class:`SolveEngine` — the rebuilt
-``serve.solve.BatchedSolveService``, which survives there as a thin deprecated
-shim over this engine with its legacy ``submit/poll/flush`` contract.
+The queue/admission/dispatch core is :class:`SolveEngine`; driven directly,
+without callbacks, it keeps a ``submit/poll/flush`` contract of its own.
 
 Usage::
 
@@ -103,7 +100,6 @@ from repro.core.tridiag.plan import (
     ChunkPolicy,
     ChunkTiming,
     FusedExecutor,
-    PlanExecutor,
     SolvePlan,
     Sizes,
     build_plan,
@@ -126,7 +122,6 @@ from repro.telemetry.ring import BatchObservation, TelemetryBuffer
 __all__ = [
     "AUTOTUNE_MODES",
     "AdmissionPolicy",
-    "DISPATCH_MODES",
     "LAYOUTS",
     "PredictedTimeoutError",
     "QueueFullError",
@@ -219,8 +214,9 @@ class AdmissionPolicy:
     ``max_batch``    dispatch as soon as this many requests are waiting;
     ``max_wait_ms``  dispatch (a possibly partial batch) once the oldest
                      request has waited this long — the session's worker
-                     thread sleeps exactly until this deadline, the legacy
-                     service checks it on :meth:`SolveEngine.poll`;
+                     thread sleeps exactly until this deadline, a caller
+                     driving the engine directly checks it on
+                     :meth:`SolveEngine.poll`;
     ``allow_ragged`` fuse a mixed-size FIFO prefix into one ragged plan.
                      When False, a batch only takes queue entries matching the
                      head request's size (the PR-1 size-segregated behaviour,
@@ -236,10 +232,6 @@ class AdmissionPolicy:
             raise ValueError("max_batch must be >= 1")
         if self.max_wait_ms < 0:
             raise ValueError("max_wait_ms must be >= 0")
-
-
-#: Valid ``SolverConfig.dispatch`` values.
-DISPATCH_MODES = ("staged", "fused", "auto")
 
 
 # ------------------------------------------------------------------- config --
@@ -259,15 +251,9 @@ class SolverConfig:
     ``backend``    stage implementation: ``"auto"`` (default — Pallas kernels
                    on TPU hosts, reference jnp stages elsewhere),
                    ``"reference"``, ``"pallas"``, or a ``StageBackend``.
-    ``dispatch``   execution mode: ``"staged"`` (per-chunk dispatch + host
-                   reduced solve — the paper's layout, with the per-phase
-                   ``ChunkTiming`` breakdown), ``"fused"`` (the whole solve
-                   compiled into one donated-buffer XLA dispatch, reduced
-                   solve on device — fastest, but phase times are
-                   structurally unobservable), or ``"auto"`` (default):
-                   fused for the plain verbs and the serving path, staged
-                   for the ``*_timed`` verbs so measurement campaigns keep
-                   the breakdown the paper's Eq.-5 analysis needs.
+    ``dispatch``   ``"auto"``, its only value: kept so stored configs that
+                   name it still load. Per-phase timing is
+                   :func:`repro.core.streams.measure.staged_timing`.
     ``layout``     operand layout for the device stages: ``"system-major"``
                    (fused systems stay concatenated; chunk bounds slice the
                    block axis), ``"interleaved"`` (batch-interleaved /
@@ -287,13 +273,7 @@ class SolverConfig:
                    Sharded sessions build shard-aligned plans (chunk bounds
                    snapped to shard boundaries) and run stage 1/stage 3
                    per-shard under ``shard_map`` with only the reduced
-                   system gathered. Requires a fused dispatch mode: a mesh
-                   with ``dispatch="staged"`` is rejected by
-                   :meth:`validate`; under ``dispatch="auto"`` the
-                   ``*_timed`` verbs keep their staged single-device path
-                   (phase timing is structurally per-chunk, not per-shard)
-                   while the plain verbs and the serving path shard. On CPU
-                   hosts, export
+                   system gathered. On CPU hosts, export
                    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
                    before jax initialises to get an 8-device mesh.
     ``policy``     a :class:`~repro.core.tridiag.plan.ChunkPolicy` pricing
@@ -395,11 +375,11 @@ class SolverConfig:
         backend = resolve_backend(self.backend)  # raises naming the known ones
         if self.dtype is not None:
             backend.check_dtype(self.dtype)
-        if self.dispatch not in DISPATCH_MODES:
+        if self.dispatch != "auto":
             raise ValueError(
-                f"dispatch={self.dispatch!r}: must be one of "
-                f"{sorted(DISPATCH_MODES)} ('auto' = fused solves, staged "
-                f"*_timed verbs)"
+                f"dispatch={self.dispatch!r}: the session runs one executor "
+                f"and 'auto' is the only value; for per-phase timing call "
+                f"repro.core.streams.measure.staged_timing"
             )
         if self.layout not in LAYOUTS:
             raise ValueError(
@@ -408,14 +388,6 @@ class SolverConfig:
                 f"otherwise)"
             )
         if self.mesh is not None:
-            if self.dispatch == "staged":
-                raise ValueError(
-                    f"mesh={self.mesh!r} with dispatch='staged': the staged "
-                    f"path dispatches chunks from a host loop on one device "
-                    f"and cannot shard; use dispatch='fused', or 'auto' "
-                    f"(sharded plain verbs, staged single-device *_timed "
-                    f"verbs)"
-                )
             resolve_mesh_devices(self.mesh)  # raises on a bad spec
         if self.policy is not None:
             if not isinstance(self.policy, ChunkPolicy):
@@ -578,9 +550,8 @@ class SolveEngine:
     """Admission-controlled fused solving of a request queue (the core).
 
     This is the serving engine behind :meth:`TridiagSession.submit` (driven
-    by the session's worker thread) and the legacy
-    ``serve.solve.BatchedSolveService`` shim (driven by its caller's
-    ``submit/poll/flush``). The engine itself is synchronous and not
+    by the session's worker thread); driven directly, its caller runs
+    ``submit/poll/flush``. The engine itself is synchronous and not
     thread-safe — the session serialises access around it.
 
     Chunk pricing: ``policy`` (a :class:`ChunkPolicy`) prices each dispatch,
@@ -589,15 +560,14 @@ class SolveEngine:
     run through the plan/execute layer, whose module-level jit/plan caches
     make per-batch construction free of retracing and replanning.
 
-    ``dispatch`` selects the execution path: ``"auto"`` (default) and
-    ``"fused"`` serve each batch as ONE compiled XLA dispatch
+    Each batch runs as ONE compiled XLA dispatch
     (:class:`~repro.core.tridiag.plan.FusedExecutor` — device-side reduced
-    solve, donated buffers); ``"staged"`` keeps the per-chunk host-loop path
-    (:class:`~repro.core.tridiag.plan.PlanExecutor`).
+    solve, donated buffers); ``executor=`` replaces it, which is the
+    fault-injection seam of the serving tests and the stress benchmark.
 
     Results surface either through the ``on_result``/``on_error`` callbacks
     (the session's futures) or, with no callbacks, an internal ``{rid: x}``
-    store drained by :meth:`poll`/:meth:`flush` (the legacy contract).
+    store drained by :meth:`poll`/:meth:`flush` (the direct-caller contract).
 
     ``clock`` (default ``time.perf_counter``) is injectable so deadline tests
     can drive virtual time; batch latency is always real wall time.
@@ -607,7 +577,7 @@ class SolveEngine:
     (higher admits first, FIFO within) and ``timeout_ms`` (expired entries
     are shed before any batch is taken and fail via ``on_error`` with
     :class:`RequestTimedOutError`; with no ``on_error`` attached — the
-    legacy poll/flush contract — timeouts are inert, since that contract
+    poll/flush contract — timeouts are inert, since that contract
     has no error channel).
 
     Failure containment: with ``on_error`` attached, *nothing* a dispatch
@@ -615,7 +585,7 @@ class SolveEngine:
     recording, and each ``on_result`` delivery are all guarded, and any
     failure resolves exactly the affected requests via ``on_error`` (see
     :meth:`_dispatch`). Without callbacks, a dispatch error propagates to
-    the caller of ``poll``/``flush`` (the legacy shim's contract).
+    the caller of ``poll``/``flush``.
 
     Stats: ``stats["batches"]/["systems"]/["wall_s"]`` aggregate throughput
     (``systems_per_sec``); ``stats["per_batch"]`` records one dict per
@@ -640,7 +610,6 @@ class SolveEngine:
         clock: Callable[[], float] = time.perf_counter,
         backend: BackendLike = None,
         dtype: Any = None,
-        dispatch: str = "auto",
         layout: str = "auto",
         mesh: Any = None,
         max_queue: Optional[int] = None,
@@ -650,10 +619,6 @@ class SolveEngine:
         telemetry: Optional[TelemetryBuffer] = None,
         max_predicted_ms: Optional[float] = None,
     ) -> None:
-        if dispatch not in DISPATCH_MODES:
-            raise ValueError(
-                f"dispatch={dispatch!r}: must be one of {sorted(DISPATCH_MODES)}"
-            )
         if layout not in LAYOUTS:
             raise ValueError(
                 f"layout={layout!r}: must be one of {sorted(LAYOUTS)}"
@@ -668,27 +633,17 @@ class SolveEngine:
         self.m = m
         self.default_chunks = default_chunks
         self.dtype = dtype
-        self.dispatch = dispatch
         self.layout = layout
-        self.mesh_devices = resolve_mesh_devices(mesh) if dispatch != "staged" else None
+        self.mesh_devices = resolve_mesh_devices(mesh)
         self._eager = eager
         self._clock = clock
-        # Serving dispatches are plain solves (no phase breakdown consumed),
-        # so "auto" resolves to the fused single-dispatch path here; the
-        # engine always fuses request operands into fresh host arrays, so
-        # buffer donation never consumes a caller's array. ``executor=``
-        # overrides the choice — primarily the fault-injection seam for the
-        # serving tests and the stress benchmark.
-        if executor is not None:
-            self._executor = executor
-        else:
-            self._executor = (
-                PlanExecutor(backend=backend, layout=layout)
-                if dispatch == "staged"
-                else FusedExecutor(
-                    backend=backend, layout=layout, mesh=self.mesh_devices
-                )
-            )
+        # The engine always fuses request operands into fresh host arrays, so
+        # buffer donation never consumes a caller's array.
+        self._executor = (
+            executor
+            if executor is not None
+            else FusedExecutor(backend=backend, layout=layout, mesh=self.mesh_devices)
+        )
         self._on_result = on_result
         self._on_error = on_error
         # Telemetry is optional and bounded: with no buffer (or capacity 0)
@@ -701,8 +656,8 @@ class SolveEngine:
         self._queue: List[_Pending] = []
         self._seq = 0
         self._results: Dict[int, np.ndarray] = {}
-        # The queue is serialised by the owner (session lock / single-threaded
-        # shim), but stats are ALSO written by _dispatch, which the session
+        # The queue is serialised by the owner (session lock / a single-thread
+        # direct caller), but stats are ALSO written by _dispatch, which the session
         # runs outside its lock so submits keep flowing during a solve —
         # hence their own lock, shared with stats_snapshot().
         self._stats_lock = threading.Lock()
@@ -901,7 +856,7 @@ class SolveEngine:
         failing each via ``on_error`` with :class:`RequestTimedOutError`;
         returns how many were shed. Runs automatically before any batch is
         taken, so an expired request never rides (or delays) a dispatch.
-        No-op without an ``on_error`` channel (legacy poll/flush contract).
+        No-op without an ``on_error`` channel (the poll/flush contract).
         """
         if self._on_error is None or not self._queue:
             return 0
@@ -1050,8 +1005,8 @@ class SolveEngine:
     def plan_shards(self, sizes: Sequence[int]) -> int:
         """Shard count for a batch's plan: the largest divisor of the fused
         block axis within the mesh's device budget, or 1 without a mesh.
-        Shard-aligned plans are harmless on the unsharded/staged paths, so
-        one plan serves every executor this engine may route to."""
+        Shard-aligned plans are harmless on the unsharded path, so one plan
+        serves a replacement ``executor=`` too."""
         if self.mesh_devices is None:
             return 1
         num_blocks = effective_size(tuple(sizes)) // self.m
@@ -1064,7 +1019,7 @@ class SolveEngine:
     def _fail_group(self, reqs: Sequence[SolveRequest], e: BaseException) -> None:
         """Fail every request in ``reqs`` via ``on_error`` (each delivery
         guarded — a raising error channel must not take the others down);
-        re-raise when there is no error channel (legacy poll/flush)."""
+        re-raise when there is no error channel (poll/flush)."""
         with self._stats_lock:
             self.stats["failed"] += len(reqs)
         if self._on_error is None:
@@ -1165,15 +1120,11 @@ class SolveEngine:
                                     self.layout,
                                     sizes,
                                     self.m,
-                                    fused=self.dispatch != "staged",
                                     batch_shards=(
                                         shard_count(len(sizes), len(self.mesh_devices))
                                         if self.mesh_devices is not None
                                         else 1
                                     ),
-                                ),
-                                dispatch=(
-                                    "staged" if self.dispatch == "staged" else "fused"
                                 ),
                                 latency_ms=dt * 1e3,
                                 mean_wait_ms=float(np.mean(waits_ms)),
@@ -1185,7 +1136,7 @@ class SolveEngine:
                         pass
             except Exception as e:
                 # A bad dispatch fails *these* requests and leaves the engine
-                # serving; the legacy shim (no on_error) keeps the raise.
+                # serving; a direct caller (no on_error) gets the raise.
                 self._fail_group(reqs, e)
                 return
             for r, xi in zip(reqs, solutions):
@@ -1235,8 +1186,8 @@ class TridiagSession:
     """The facade: one configured object serving every batch shape.
 
     Synchronous verbs (:meth:`solve`, :meth:`solve_batched`,
-    :meth:`solve_many` and their ``*_timed`` variants) run on the caller's
-    thread through the plan/execute layer. :meth:`submit` is asynchronous: a
+    :meth:`solve_many` and the periodic pair) run on the caller's thread
+    through the plan/execute layer. :meth:`submit` is asynchronous: a
     daemon worker thread drives the admission loop, so ``max_wait_ms``
     deadlines fire on time without any polling. Both sides share the
     module-level plan/stage caches (lock-protected for exactly this reason),
@@ -1261,7 +1212,6 @@ class TridiagSession:
         # Resolved once: every executor, plan and stats report sees the same
         # device set even if jax's visible devices change later.
         self._mesh_devices = resolve_mesh_devices(self.config.mesh)
-        self._executor = PlanExecutor(backend=self.backend, layout=self.config.layout)
         self._fused = FusedExecutor(
             backend=self.backend,
             layout=self.config.layout,
@@ -1311,7 +1261,6 @@ class TridiagSession:
             eager=False,  # the worker owns every dispatch
             backend=self.backend,
             dtype=self.config.dtype,
-            dispatch=self.config.dispatch,
             layout=self.config.layout,
             mesh=self._mesh_devices,
             max_queue=self.config.max_queue,
@@ -1328,9 +1277,8 @@ class TridiagSession:
         Priced by the *active* chunk policy — the config's, until a
         live-mode refit swaps in the telemetry-fitted one. With a mesh
         configured, plans are shard-aligned (chunk bounds snapped to shard
-        boundaries); the staged ``*_timed`` path runs the same plan on one
-        device, so both executors agree on the chunk layout. ``periodic``
-        plans (cyclic systems) are neither chunked nor sharded."""
+        boundaries). ``periodic`` plans (cyclic systems) are neither chunked
+        nor sharded."""
         if periodic:
             return build_plan(sizes, self.config.m, periodic=True)
         with self._cv:
@@ -1365,55 +1313,14 @@ class TridiagSession:
             return x
         return np.asarray(x, dtype=self.config.dtype)
 
-    def _pick_executor(self, timed: bool) -> "PlanExecutor | FusedExecutor":
-        """``dispatch`` routing: "staged"/"fused" are unconditional; "auto"
-        fuses plain solves but keeps the ``*_timed`` verbs on the staged path,
-        whose host round-trips are what make the per-phase ``ChunkTiming``
-        (the paper's Eq.-5 decomposition) observable."""
-        mode = self.config.dispatch
-        if mode == "fused" or (mode == "auto" and not timed):
-            return self._fused
-        return self._executor
-
     # -- synchronous verbs ---------------------------------------------------
     def solve(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
         """Solve one system (1-D diagonals; leading batch dims pass through).
 
-        Under ``dispatch="auto"``/``"fused"`` this is one compiled XLA
-        dispatch with donated operand buffers: numpy operands are always safe
-        to reuse (copied to device per call), but *device* arrays are
-        consumed by the solve — pass fresh ones, or use dispatch="staged".
+        This is one compiled XLA dispatch with donated operand buffers: numpy
+        operands are always safe to reuse (copied to device per call), but
+        *device* arrays are consumed by the solve — pass fresh ones.
         """
-        return self._solve(dl, d, du, b, timed=False)[0]
-
-    def solve_timed(
-        self, dl: Any, d: Any, du: Any, b: Any
-    ) -> Tuple[np.ndarray, ChunkTiming]:
-        return self._solve(dl, d, du, b, timed=True)
-
-    def _execute(
-        self,
-        sizes: Sizes,
-        timed: bool,
-        dl: Any,
-        d: Any,
-        du: Any,
-        b: Any,
-        periodic: bool = False,
-    ) -> Tuple[Any, ChunkTiming]:
-        """Plan the fused operands of one verb call and run them. Periodic
-        plans always take the fused executor: the staged path's chunks and
-        host reduced solve do not wrap."""
-        with jax.profiler.TraceAnnotation(spans.LOOKUP):
-            plan = self.plan_for(sizes, periodic)
-        executor = self._fused if periodic else self._pick_executor(timed)
-        x, timing = executor.execute(plan, dl, d, du, b)
-        self._engine.record_dispatch(timing)
-        return x, timing
-
-    def _solve(
-        self, dl: Any, d: Any, du: Any, b: Any, *, timed: bool
-    ) -> Tuple[np.ndarray, ChunkTiming]:
         shape = np.shape(d)
         systems = math.prod(shape[:-1])
         with jax.profiler.TraceAnnotation(
@@ -1421,22 +1328,28 @@ class TridiagSession:
         ):
             with jax.profiler.TraceAnnotation(spans.FUSE):
                 dl, d, du, b = self._cast(dl, d, du, b)
-            x, timing = self._execute(int(shape[-1]), timed, dl, d, du, b)
+            x = self._execute(int(shape[-1]), dl, d, du, b)
             with jax.profiler.TraceAnnotation(spans.SPLIT):
-                return self._cast_out(x), timing
+                return self._cast_out(x)
+
+    def _execute(
+        self,
+        sizes: Sizes,
+        dl: Any,
+        d: Any,
+        du: Any,
+        b: Any,
+        periodic: bool = False,
+    ) -> Any:
+        """Plan the fused operands of one verb call and run them."""
+        with jax.profiler.TraceAnnotation(spans.LOOKUP):
+            plan = self.plan_for(sizes, periodic)
+        x, timing = self._fused.execute(plan, dl, d, du, b)
+        self._engine.record_dispatch(timing)
+        return x
 
     def solve_batched(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
         """Solve B same-size systems given as (B, n) operands."""
-        return self._solve_batched(dl, d, du, b, timed=False)[0]
-
-    def solve_batched_timed(
-        self, dl: Any, d: Any, du: Any, b: Any
-    ) -> Tuple[np.ndarray, ChunkTiming]:
-        return self._solve_batched(dl, d, du, b, timed=True)
-
-    def _solve_batched(
-        self, dl: Any, d: Any, du: Any, b: Any, *, timed: bool
-    ) -> Tuple[np.ndarray, ChunkTiming]:
         if np.ndim(d) != 2:
             raise ValueError(
                 f"solve_batched takes (batch, n) operands, got shape "
@@ -1450,9 +1363,9 @@ class TridiagSession:
             with jax.profiler.TraceAnnotation(spans.FUSE):
                 dl, d, du, b = self._cast(dl, d, du, b)
                 fused = fuse_systems(dl, d, du, b)
-            x, timing = self._execute((n,) * batch, timed, *fused)
+            x = self._execute((n,) * batch, *fused)
             with jax.profiler.TraceAnnotation(spans.SPLIT):
-                return split_systems(self._cast_out(x), batch), timing
+                return split_systems(self._cast_out(x), batch)
 
     def solve_periodic(self, dl: Any, d: Any, du: Any, b: Any) -> np.ndarray:
         """Solve one periodic (cyclic) tridiagonal system of 1-D diagonals.
@@ -1508,31 +1421,21 @@ class TridiagSession:
                     else np.ascontiguousarray(np.reshape(a, -1))
                     for a in self._cast(dl, d, du, b)
                 ]
-            x, _ = self._execute((n,) * batch, False, *ops, periodic=True)
+            x = self._execute((n,) * batch, *ops, periodic=True)
             with jax.profiler.TraceAnnotation(spans.SPLIT):
                 return split_systems(self._cast_out(x), batch)
 
     def solve_many(self, systems: Sequence[System]) -> List[np.ndarray]:
         """Solve a ragged list of ``(dl, d, du, b)`` systems in one dispatch."""
-        return self._solve_many(systems, timed=False)[0]
-
-    def solve_many_timed(
-        self, systems: Sequence[System]
-    ) -> Tuple[List[np.ndarray], ChunkTiming]:
-        return self._solve_many(systems, timed=True)
-
-    def _solve_many(
-        self, systems: Sequence[System], *, timed: bool
-    ) -> Tuple[List[np.ndarray], ChunkTiming]:
         with jax.profiler.TraceAnnotation(spans.SOLVE_MANY) as span:
             with jax.profiler.TraceAnnotation(spans.FUSE):
                 if self.config.dtype is not None:
                     systems = [self._cast(*s) for s in systems]
                 dl, d, du, b, sizes = fuse_ragged(systems)
             span.set_metadata(rows=sum(sizes), systems=len(sizes))
-            x, timing = self._execute(sizes, timed, dl, d, du, b)
+            x = self._execute(sizes, dl, d, du, b)
             with jax.profiler.TraceAnnotation(spans.SPLIT):
-                return split_ragged(self._cast_out(x), sizes), timing
+                return split_ragged(self._cast_out(x), sizes)
 
     # -- asynchronous serving ------------------------------------------------
     def submit(self, req: SolveRequest) -> SolveFuture:
@@ -1821,8 +1724,7 @@ class TridiagSession:
             pending = self._engine.pending()
         return (
             f"TridiagSession(m={self.config.m}, backend={self.backend.name!r}, "
-            f"dispatch={self.config.dispatch!r}, {state}, "
-            f"pending={pending})"
+            f"{state}, pending={pending})"
         )
 
 

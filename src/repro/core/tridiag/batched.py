@@ -21,8 +21,7 @@ So the batched solve reuses the single-system pipeline on the fused
 ``(B·n,)`` arrays, and chunks ("virtual streams") may span system boundaries
 — the whole point of batching small systems.
 
-API example (the facade ``repro.api.TridiagSession`` is the front door;
-``BatchedPartitionSolver`` survives as a deprecated wrapper)::
+API example (the facade ``repro.api.TridiagSession`` is the front door)::
 
     from repro.api import SolverConfig, TridiagSession
     from repro.core.tridiag.batched import solve_batched
@@ -30,9 +29,9 @@ API example (the facade ``repro.api.TridiagSession`` is the front door;
     # functional, jit/vmap-friendly: (B, n) diagonals in, (B, n) solutions out
     x = solve_batched(dl, d, du, b, m=10)
 
-    # chunked execution with wall-clock timing (the stream analogue)
+    # chunked execution (the stream analogue), one fused dispatch
     session = TridiagSession(SolverConfig(m=10, num_chunks=8))
-    x, timing = session.solve_batched_timed(dl, d, du, b)
+    x = session.solve_batched(dl, d, du, b)
 """
 
 from __future__ import annotations
@@ -45,7 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tridiag import partition
-from repro.core.tridiag.plan import ChunkTiming
 from repro.core.tridiag.thomas import thomas
 
 Array = jax.Array
@@ -114,62 +112,3 @@ def split_systems(x: np.ndarray, batch: int) -> np.ndarray:
     """Inverse of :func:`fuse_systems` for the solution vector."""
     return np.asarray(x).reshape(*x.shape[:-1], batch, x.shape[-1] // batch)
 
-
-# ------------------------------------------------------------ chunked solver --
-class BatchedPartitionSolver:
-    """Deprecated: use ``repro.api.TridiagSession(...).solve_batched(...)``.
-
-    ``num_chunks`` slices the *fused* block axis (B·n/m blocks), so chunks
-    span system boundaries — a batch of B systems offers B× the overlappable
-    work of one system, which is exactly the knob the batched stream
-    heuristic (`repro.core.autotune.heuristic.BatchedStreamHeuristic`) tunes.
-
-    Deprecated delegating wrapper: all calls route to an
-    equivalently-configured :class:`~repro.api.TridiagSession` (the batch is
-    fused by concatenation and laid out as a ``(n,)*B`` `SolvePlan`; chunk
-    bounds and halo handling live in `repro.core.tridiag.plan.PlanExecutor`).
-    ``backend`` picks the stage implementation (``"reference"`` jnp stages,
-    ``"pallas"`` kernels, or a
-    :class:`~repro.core.tridiag.plan.StageBackend` instance).
-    """
-
-    def __init__(self, m: int = 10, num_chunks: int = 1, *, backend=None):
-        import warnings
-
-        warnings.warn(
-            "BatchedPartitionSolver is deprecated: use repro.api."
-            "TridiagSession(SolverConfig(m=..., num_chunks=..., backend=...))"
-            ".solve_batched(...)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        from repro.core.tridiag.api import SolverConfig, TridiagSession
-
-        self.m = m
-        self.num_chunks = num_chunks
-        # dispatch pinned to "staged": the legacy classes predate the fused
-        # path and their contract is the bit-exact staged numerics.
-        self._session = TridiagSession(
-            SolverConfig(
-                m=m,
-                num_chunks=num_chunks,
-                backend=backend if backend is not None else "reference",
-                dispatch="staged",
-            )
-        )
-
-    def solve(
-        self, dl: np.ndarray, d: np.ndarray, du: np.ndarray, b: np.ndarray
-    ) -> np.ndarray:
-        x, _ = self.solve_timed(dl, d, du, b)
-        return x
-
-    def solve_timed(
-        self, dl: np.ndarray, d: np.ndarray, du: np.ndarray, b: np.ndarray
-    ) -> Tuple[np.ndarray, ChunkTiming]:
-        if np.asarray(d).ndim != 2:
-            raise ValueError(f"expected (batch, n) operands, got shape {np.asarray(d).shape}")
-        n = np.asarray(d).shape[1]
-        if n % self.m:
-            raise ValueError(f"system size {n} not divisible by m={self.m}")
-        return self._session.solve_batched_timed(dl, d, du, b)

@@ -29,8 +29,7 @@ maps, so they trace into the fused executable — callers and the serving
 engine never observe the transposed layout, and ``donate_argnums`` still
 refers to the caller-visible 1-D buffers.
 
-Layout selection (``resolve_layout``) is shared by both executors:
-``"auto"`` interleaves only the fused dispatch path, only for flat (no
+Layout selection (``resolve_layout``): ``"auto"`` interleaves only flat (no
 stacked leading dims) batches of at least :data:`AUTO_INTERLEAVE_MIN_BATCH`
 systems, and only when ragged padding would not blow the footprint up past
 :data:`AUTO_INTERLEAVE_MAX_WASTE`.
@@ -66,17 +65,16 @@ def resolve_layout(
     sizes: Sequence[int],
     m: int,
     *,
-    fused: bool,
     lead_ndim: int = 0,
     batch_shards: int = 1,
 ) -> str:
     """Resolve a config layout to a concrete one for a given batch.
 
-    ``fused`` says which executor is asking; ``lead_ndim`` is the number of
-    stacked leading dims on the operands (``solve`` on (K, n) inputs). The
-    interleave transforms are defined on flat fused operands only, so
-    stacked inputs always stay system-major — explicitly requesting
-    ``"interleaved"`` for them is an error rather than a silent fallback.
+    ``lead_ndim`` is the number of stacked leading dims on the operands
+    (``solve`` on (K, n) inputs). The interleave transforms are defined on
+    flat fused operands only, so stacked inputs always stay system-major —
+    explicitly requesting ``"interleaved"`` for them is an error rather than
+    a silent fallback.
 
     ``batch_shards`` is the lane-axis shard count a mesh-configured executor
     would split the batch over: the ``"auto"`` threshold compares the
@@ -99,7 +97,7 @@ def resolve_layout(
             )
         return "interleaved"
     # auto
-    if lead_ndim or not fused:
+    if lead_ndim:
         return "system-major"
     bsz = len(sizes)
     if bsz // batch_shards < AUTO_INTERLEAVE_MIN_BATCH:
@@ -193,16 +191,6 @@ def deinterleave(xw: Array, sizes: Sequence[int], m: int) -> Array:
         p, _, bsz = xw.shape
         return xw.reshape(p * m, bsz).T.reshape(-1)
     return jnp.take(xw.reshape(-1), inv, axis=0)
-
-
-# Jitted entry points for the staged executor (the fused executor traces the
-# plain functions straight into its executable). ``sizes``/``m`` are static.
-interleave_operands_jit = functools.partial(
-    jax.jit, static_argnames=("sizes", "m")
-)(interleave_operands)
-deinterleave_jit = functools.partial(
-    jax.jit, static_argnames=("sizes", "m")
-)(deinterleave)
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ of size P solved in Stage 2.
 
 Stage 1 and Stage 3 are embarrassingly parallel over blocks — on the GPU of the
 paper each CUDA stream takes a slice of blocks; here the block axis is the one
-we shard/chunk (`chunked.py`, `repro.kernels.partition_stage1`).
+we shard/chunk (`plan.py`, `repro.kernels.partition_stage1`).
 
 A *periodic* (cyclic) system closes on itself: ``dl[0]`` couples row 0 to
 ``x[n-1]`` and ``du[n-1]`` couples row n-1 to ``x[0]``. The same blocks then

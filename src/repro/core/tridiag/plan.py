@@ -5,17 +5,12 @@ kernel launches; this module is the repo's structural analogue of that
 "decide, then dispatch" split.  A :class:`SolvePlan` is an immutable layout
 decision — which systems are fused onto the block axis, where the chunk
 ("virtual stream") boundaries fall, which halo block each chunk carries, and
-where each system's solution lives in the fused vector.  A
-:class:`PlanExecutor` then runs the three partition stages from the plan:
+where each system's solution lives in the fused vector.  An executor then
+runs the three partition stages from the plan.
 
-  Stage 1  per-chunk staged dispatch (H2D + kernel overlap — the CUDA-stream
-           analogue, see ``chunked.py``'s module docstring for the mapping),
-  Stage 2  host-side reduced solve (the paper keeps it on the CPU),
-  Stage 3  per-chunk back-substitution with a ghost block for the left edge.
-
-The front door (`api.TridiagSession` and its `SolveEngine`, plus the
-deprecated solver-class wrappers that delegate to it) only *builds plans*;
-chunk bounds, halo handling and ghost splicing live here and nowhere else.
+The front door (`api.TridiagSession` and its `SolveEngine`) only *builds
+plans*; chunk bounds, halo handling and ghost splicing live here and nowhere
+else.
 
 The chunk count is either given explicitly or chosen by a pluggable
 :class:`ChunkPolicy` — :class:`FixedChunkPolicy` or
@@ -50,16 +45,9 @@ replanning.
 tests and capacity planning; ``set_plan_cache_capacity()`` resizes the LRU
 (``SolverConfig.plan_cache_capacity`` threads it through the facade).
 
-Dispatch modes
---------------
-*When* the stages are dispatched is the third axis. The classic
-:class:`PlanExecutor` runs the **staged** path: per-chunk device dispatch
-from a Python loop, a host round-trip for the Stage-2 reduced solve (the
-paper keeps it on the CPU), then per-chunk back-substitution — the layout
-that makes the per-phase :class:`ChunkTiming` breakdown (the paper's Eq. 5
-decomposition) observable, and the path every ``measure_*`` campaign times.
-
-:class:`FusedExecutor` is the **fused** path: for a given
+Executors
+---------
+:class:`FusedExecutor` runs every session verb and served batch: for a given
 ``(plan, backend, operand dtypes, leading-batch shape)`` it traces the
 *entire* three-stage solve — chunk slicing via ``lax.slice`` inside the
 trace (halo blocks included), the reduced solve **on device**
@@ -76,10 +64,14 @@ lock-protected LRU beside the plan cache
 donated, callers passing *device* arrays give up ownership (numpy operands
 are copied to device per call and are always safe to reuse).
 
-``SolverConfig.dispatch`` selects the mode per session: ``"staged"``,
-``"fused"``, or ``"auto"`` (the default) — fused for the plain solve verbs
-and the serving path, staged for the ``*_timed`` verbs so measurement
-campaigns keep their phase breakdown.
+:class:`PlanExecutor` is the paper's measuring instrument, which no session
+uses: per-chunk device dispatch from a Python loop (the CUDA-stream
+analogue: each chunk's H2D copy and Stage-1 kernel overlap its
+neighbours'), a host round-trip for the Stage-2 reduced solve (the paper
+keeps it on the CPU), then per-chunk back-substitution with a ghost block
+for the left edge. Those round-trips make the per-phase :class:`ChunkTiming`
+breakdown (the paper's Eq. 5 decomposition) observable;
+:func:`repro.core.streams.measure.staged_timing` runs it for the campaigns.
 
 Sharded dispatch
 ----------------
@@ -91,8 +83,8 @@ spans, stage 1 and stage 3 run per-shard under ``shard_map`` with one
 (``all_gather`` of the per-shard reduced rows + a replicated device Stage-2
 solve). Interleaved executables shard the lane axis instead, with no
 collectives at all. See :func:`_sharded_fused_callable` and
-:mod:`repro.parallel.solver`; the staged :class:`PlanExecutor` never shards
-(its raison d'être is per-phase timing on one device).
+:mod:`repro.parallel.solver`; :class:`PlanExecutor` never shards (its
+raison d'être is per-phase timing on one device).
 
 Both module-level caches (plans and jitted stages) are lock-protected:
 ``TridiagSession.submit`` solves from a worker thread while the session's
@@ -137,7 +129,7 @@ Sizes = Union[int, Sequence[int]]
 class ChunkTiming:
     """Wall-clock phase breakdown of one planned solve (milliseconds), and
     what ran: the operand ``layout``, the ``stage2`` implementation
-    (``"host"`` for the staged path's ``thomas_numpy``, otherwise the name
+    (``"host"`` for :class:`PlanExecutor`'s ``thomas_numpy``, otherwise the name
     :meth:`StageBackend.reduced_solve_impl` gives) and whether the systems
     were ``periodic``."""
 
@@ -633,7 +625,7 @@ def jitted_stage3_ghost(backend: BackendLike = None) -> Callable:
 def price_chunks(heuristic: Any, sizes: Sizes, *, fp32: bool = False) -> int:
     """THE chunk-pricing rule: one heuristic call for every entry point.
 
-    `HeuristicChunkPolicy` and `serve.solve.BatchedSolveService` both route
+    `HeuristicChunkPolicy` and the serving engine's ``heuristic=`` both route
     through here, so a batch can never get a different chunk count depending
     on whether it arrives via a plan policy or the serving queue. Heuristics
     exposing ``predict_optimum_ragged`` (the batched/ragged-aware pricing) are
@@ -1000,38 +992,24 @@ def set_executable_cache_capacity(capacity: int) -> None:
             _EXEC_STATS["evictions"] += 1
 
 
-# -------------------------------------------------------------- the executor --
+# ------------------------------------------------ the per-phase instrument --
 class PlanExecutor:
     """Runs stage-1 dispatch, host reduced solve and stage-3 from a plan.
 
+    The per-phase measuring instrument of the paper's campaigns
+    (:func:`repro.core.streams.measure.staged_timing`); no session runs it.
     ``backend`` (a :class:`StageBackend`, a registry name, or None for the
     reference stages) decides *how* the chunked device stages execute; the
     executor itself carries no mutable state — the stage callables come from
-    the module-level ``(m, backend)`` cache, so executors (and the frontends
-    that own them) are free to construct. Operands are the *fused*
-    diagonals/RHS — 1-D over ``plan.total_size``, or with extra leading dims
-    that pass straight through the stages (on `PallasBackend` a single
-    leading batch axis routes to the batched-grid kernels).
-
-    ``layout`` picks the operand layout for the device stages. The default
-    ``"auto"`` resolves to system-major on this (staged) executor — the
-    chunked per-phase timing campaigns are its raison d'être, and chunk
-    bounds slice the system-major block axis. An explicit ``"interleaved"``
-    runs the whole-batch wide-stage variant instead (one lane-major stage-1
-    and stage-3 dispatch, host reduced solve on (P, B) rows): per-phase
-    timing stays observable, but the plan's chunk partition does not apply —
-    the wide grid itself is the parallel axis.
+    the module-level ``(m, backend)`` cache, so executors are free to
+    construct. Operands are the *fused* system-major diagonals/RHS — 1-D
+    over ``plan.total_size``, or with extra leading dims that pass straight
+    through the stages (on `PallasBackend` a single leading batch axis
+    routes to the batched-grid kernels).
     """
 
-    def __init__(
-        self, backend: BackendLike = None, *, layout: str = "auto"
-    ) -> None:
+    def __init__(self, backend: BackendLike = None) -> None:
         self.backend = resolve_backend(backend)
-        if layout not in layout_mod.LAYOUTS:
-            raise ValueError(
-                f"layout must be one of {layout_mod.LAYOUTS}, got {layout!r}"
-            )
-        self.layout = layout
 
     def execute(
         self,
@@ -1053,11 +1031,6 @@ class PlanExecutor:
                 "path's chunks and host Stage 2 do not wrap round the systems"
             )
         self.backend.check_dtype(d.dtype if hasattr(d, "dtype") else np.result_type(d))
-        layout = resolve_layout(
-            self.layout, plan.sizes, m, fused=False, lead_ndim=np.ndim(d) - 1
-        )
-        if layout == "interleaved":
-            return self._execute_interleaved(plan, dl, d, du, b)
 
         def row(a: Any, lo: int, hi: int) -> jax.Array:
             # Fast path: operands already on device slice lazily — no host
@@ -1125,48 +1098,6 @@ class PlanExecutor:
             t_stage3_ms=(t3 - t2) * 1e3,
             t_total_ms=(t3 - t0) * 1e3,
             n=n,
-        )
-        return x, timing
-
-    def _execute_interleaved(
-        self, plan: SolvePlan, dl: Any, d: Any, du: Any, b: Any
-    ) -> Tuple[np.ndarray, ChunkTiming]:
-        """Whole-batch staged solve on the wide (lane-major) layout.
-
-        Same three-phase structure as :meth:`execute` — device stage 1, host
-        fp64 reduced solve, device stage 3 — but on interleaved operands: one
-        wide dispatch per stage (the lane-block grid replaces the chunk
-        loop), and the host Stage 2 solves B parallel length-P systems.
-        """
-        m, sizes = plan.m, plan.sizes
-        wide_stage1, wide_stage3 = jitted_wide_stages(m, self.backend)
-
-        t0 = time.perf_counter()
-        ops = layout_mod.interleave_operands_jit(dl, d, du, b, sizes=sizes, m=m)
-        c = wide_stage1(*ops)
-        # Block only when the host needs the reduced rows (D2H analogue).
-        red = [
-            np.asarray(getattr(c, f))
-            for f in ("red_dl", "red_d", "red_du", "red_b")
-        ]  # (P, B) each
-        t1 = time.perf_counter()
-
-        # ---- Stage 2: host-side reduced solve, batched over the B lanes.
-        s = thomas_numpy(*(r.T for r in red)).T
-        t2 = time.perf_counter()
-
-        xw = wide_stage3(c, jnp.asarray(s, dtype=c.y.dtype))
-        x = np.asarray(layout_mod.deinterleave_jit(xw, sizes=sizes, m=m))
-        t3 = time.perf_counter()
-
-        timing = ChunkTiming(
-            num_chunks=plan.num_chunks,
-            t_stage1_ms=(t1 - t0) * 1e3,
-            t_stage2_ms=(t2 - t1) * 1e3,
-            t_stage3_ms=(t3 - t2) * 1e3,
-            t_total_ms=(t3 - t0) * 1e3,
-            n=plan.total_size,
-            layout="interleaved",
         )
         return x, timing
 
@@ -1514,11 +1445,12 @@ class FusedExecutor:
     host clock), this executor trades that for latency: zero host
     round-trips between operand hand-off and solution split, one dispatch
     regardless of chunk count. The returned :class:`ChunkTiming` therefore
-    carries only ``t_total_ms`` (use the staged path for the Eq.-5
-    campaigns). Per-phase times are observable in a profiler trace instead:
-    the executable tags each stage's device ops with a named scope, and
-    :meth:`execute` emits host spans for each call's lookup, launch, wait and
-    fetch (:mod:`repro.core.tridiag.spans`).
+    carries only ``t_total_ms`` (the Eq.-5 campaigns use
+    :func:`repro.core.streams.measure.staged_timing`). Per-phase times are
+    observable in a profiler trace instead: the executable tags each
+    stage's device ops with a named scope, and :meth:`execute` emits host
+    spans for each call's lookup, launch, wait and fetch
+    (:mod:`repro.core.tridiag.spans`).
 
     ``donate=True`` (default) donates the four diagonals to the executable;
     numpy operands are copied to device per call (always safe to reuse),
@@ -1592,7 +1524,6 @@ class FusedExecutor:
             self.layout,
             plan.sizes,
             plan.m,
-            fused=True,
             lead_ndim=lead_ndim,
             batch_shards=batch_shards,
         )

@@ -32,8 +32,7 @@ its batch index. (Silently fusing a short diagonal would shift every
 subsequent system's rows and corrupt *all* their solutions, which is fatal in
 the serving path where one bad request rides with innocent neighbours.)
 
-API example (the facade is the front door; ``RaggedPartitionSolver`` and
-``solve_ragged`` survive as deprecated wrappers over it)::
+API example (the facade is the front door)::
 
     from repro.api import SolverConfig, TridiagSession
 
@@ -41,31 +40,20 @@ API example (the facade is the front door; ``RaggedPartitionSolver`` and
     session = TridiagSession(
         SolverConfig(m=10, policy=HeuristicChunkPolicy(heur), backend="pallas")
     )
-    xs, timing = session.solve_many_timed(systems)       # list of solutions
+    xs = session.solve_many(systems)                     # list of solutions
 """
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.tridiag.plan import (
-    BackendLike,
-    ChunkPolicy,
-    ChunkTiming,
-    SolvePlan,
-    effective_size,
-)
-
-if TYPE_CHECKING:  # circular at runtime: api builds on this module
-    from repro.core.tridiag.api import TridiagSession
+from repro.core.tridiag.plan import effective_size
 
 __all__ = [
-    "RaggedPartitionSolver",
     "effective_size",
     "fuse_ragged",
-    "solve_ragged",
     "split_ragged",
 ]
 
@@ -127,98 +115,3 @@ def split_ragged(x: np.ndarray, sizes: Sequence[int]) -> List[np.ndarray]:
         )
     return [x[..., lo:hi] for lo, hi in zip(offsets[:-1], offsets[1:])]
 
-
-def _session_for(
-    m: int,
-    num_chunks: int,
-    policy: "Optional[ChunkPolicy]",
-    backend: BackendLike,
-) -> "TridiagSession":
-    """Equivalent TridiagSession config for the legacy ctor arguments."""
-    from repro.core.tridiag.api import SolverConfig, TridiagSession
-
-    # dispatch pinned to "staged": the legacy frontends predate the fused
-    # path and their contract is the bit-exact staged numerics.
-    return TridiagSession(
-        SolverConfig(
-            m=m,
-            num_chunks=None if policy is not None else num_chunks,
-            policy=policy,
-            backend=backend if backend is not None else "reference",
-            dispatch="staged",
-        )
-    )
-
-
-class RaggedPartitionSolver:
-    """Deprecated: use ``repro.api.TridiagSession(...).solve_many(...)``.
-
-    ``policy`` (a :class:`~repro.core.tridiag.plan.ChunkPolicy`) prices each
-    batch by effective size at solve time; a fixed ``num_chunks`` is the
-    no-policy baseline. Chunks slice the fused block axis, so they span system
-    boundaries exactly as in the same-size batched solver. ``backend`` picks
-    the stage implementation (``"reference"``/``"pallas"`` or a
-    :class:`~repro.core.tridiag.plan.StageBackend` instance). All calls
-    delegate to an equivalently-configured session.
-    """
-
-    def __init__(
-        self,
-        m: int = 10,
-        num_chunks: int = 1,
-        *,
-        policy: Optional[ChunkPolicy] = None,
-        backend: BackendLike = None,
-    ) -> None:
-        import warnings
-
-        warnings.warn(
-            "RaggedPartitionSolver is deprecated: use repro.api."
-            "TridiagSession(SolverConfig(m=..., policy=... or num_chunks=..., "
-            "backend=...)).solve_many(...)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        if policy is not None and num_chunks != 1:
-            raise ValueError("pass num_chunks or policy, not both")
-        self.m = m
-        self.num_chunks = num_chunks
-        self.policy = policy
-        self._session = _session_for(m, num_chunks, policy, backend)
-
-    def plan_for(self, sizes: Sequence[int]) -> SolvePlan:
-        return self._session.plan_for(tuple(sizes))
-
-    def solve(self, systems: Sequence[System]) -> List[np.ndarray]:
-        xs, _ = self.solve_timed(systems)
-        return xs
-
-    def solve_timed(
-        self, systems: Sequence[System]
-    ) -> Tuple[List[np.ndarray], ChunkTiming]:
-        return self._session.solve_many_timed(systems)
-
-
-def solve_ragged(
-    systems: Sequence[System],
-    *,
-    m: int = 10,
-    num_chunks: int = 1,
-    policy: Optional[ChunkPolicy] = None,
-    backend: BackendLike = None,
-) -> List[np.ndarray]:
-    """One-shot ragged fused solve; returns the per-system solutions.
-
-    Deprecated: use ``repro.api.TridiagSession(...).solve_many(systems)``.
-    """
-    import warnings
-
-    warnings.warn(
-        "solve_ragged is deprecated: use repro.api.TridiagSession("
-        "SolverConfig(...)).solve_many(systems)",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    if policy is not None and num_chunks != 1:
-        raise ValueError("pass num_chunks or policy, not both")
-    return _session_for(m, num_chunks, policy, backend).solve_many(systems)

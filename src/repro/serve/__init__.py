@@ -1,7 +1,6 @@
 from repro.serve.steps import make_decode_step, make_prefill_step
 from repro.serve.solve import (
     AdmissionPolicy,
-    BatchedSolveService,
     SolveEngine,
     SolveRequest,
     make_batched_solve_step,
@@ -11,7 +10,6 @@ __all__ = [
     "make_decode_step",
     "make_prefill_step",
     "AdmissionPolicy",
-    "BatchedSolveService",
     "SolveEngine",
     "SolveRequest",
     "make_batched_solve_step",

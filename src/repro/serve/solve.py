@@ -1,40 +1,21 @@
-"""Legacy serving entry point — now a deprecated shim over the session engine.
+"""Tridiagonal serving entry points beside the LM serving steps.
 
 The serving story lives in :mod:`repro.core.tridiag.api` (re-exported as
 ``repro.api``): a :class:`~repro.api.SolverConfig` names the admission knobs
 once and :meth:`~repro.api.TridiagSession.submit` returns a
-:class:`~repro.api.SolveFuture` resolved by the session's worker thread — the
-deadline fires without anyone calling ``poll()``.
-
-:class:`BatchedSolveService` is preserved here with its original
-``submit/poll/flush`` contract for existing callers: it is a thin subclass of
-:class:`repro.core.tridiag.api.SolveEngine` (the rebuilt core that also backs
-the session) and emits a ``DeprecationWarning`` at construction. Migration::
-
-    # before                                   # after
-    svc = BatchedSolveService(                 cfg = SolverConfig(
-        heuristic=h,                               m=10,
-        admission=AdmissionPolicy(                 policy=HeuristicChunkPolicy(h),
-            max_batch=64, max_wait_ms=5.0))        max_batch=64, max_wait_ms=5.0)
-    svc.submit(SolveRequest(...))              with TridiagSession(cfg) as s:
-    done.update(svc.poll())      # polling!        fut = s.submit(SolveRequest(...))
-    done.update(svc.flush())                       x = fut.result(timeout=1.0)
-
-``SolveRequest`` and ``AdmissionPolicy`` moved to the api module; they are
-re-exported here unchanged.
+:class:`~repro.api.SolveFuture` resolved by the session's worker thread.
+``SolveEngine``, ``SolveRequest`` and ``AdmissionPolicy`` are re-exported
+here unchanged.
 """
 
 from __future__ import annotations
 
-import time
-import warnings
 from functools import partial
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 
-from repro.core.autotune.heuristic import BatchedStreamHeuristic
-from repro.core.tridiag.api import (  # noqa: F401  (compat re-exports)
+from repro.core.tridiag.api import (  # noqa: F401  (re-exports)
     AdmissionPolicy,
     SolveEngine,
     SolveRequest,
@@ -45,73 +26,3 @@ from repro.core.tridiag.batched import solve_batched
 def make_batched_solve_step(m: int = 10) -> Callable:
     """Jitted (B, n) solve step, mirror of ``serve.steps`` step builders."""
     return jax.jit(partial(solve_batched, m=m))
-
-
-class BatchedSolveService(SolveEngine):
-    """Deprecated: use ``repro.api.TridiagSession`` (``submit`` → future).
-
-    Original contract, fully preserved:
-
-    - constructed without ``admission=``, ``submit`` only enqueues and
-      ``flush`` dispatches everything in ``max_batch`` groups (the PR-1
-      contract; mixed sizes still fuse via ragged plans);
-    - constructed with ``admission=``, full batches dispatch inside
-      ``submit`` and deadline-expired batches dispatch on ``poll()`` —
-      which is exactly the polling burden ``TridiagSession`` removes.
-
-    ``dispatch`` rides along to the engine. The default here is ``"staged"``
-    — like the other deprecated frontends, this shim's contract is the
-    bit-exact pre-fused numerics; pass ``dispatch="auto"``/``"fused"`` to
-    opt in to the single-dispatch fused path (or migrate to
-    ``TridiagSession``, whose default already serves fused).
-
-    ``max_queue`` rides along too (``submit`` raises
-    :class:`~repro.api.QueueFullError` at the bound). Note the rest of the
-    serving-hardening layer — per-request ``timeout_ms``, ``cancel()``,
-    ``try_submit`` — needs the session's future-based error channel; this
-    shim's poll/flush dict has nowhere to surface a shed request, which is
-    one more reason to migrate.
-    """
-
-    def __init__(
-        self,
-        heuristic: Optional[BatchedStreamHeuristic] = None,
-        *,
-        m: int = 10,
-        max_batch: Optional[int] = None,
-        default_chunks: int = 1,
-        admission: Optional[AdmissionPolicy] = None,
-        clock: Callable[[], float] = time.perf_counter,
-        backend=None,
-        dispatch: str = "staged",
-        max_queue: Optional[int] = None,
-    ):
-        warnings.warn(
-            "BatchedSolveService is deprecated: build a repro.api.SolverConfig "
-            "and serve through TridiagSession.submit(), whose worker thread "
-            "fires deadlines without poll()",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        if admission is None:
-            # Legacy construction: submit only enqueues; batches form when
-            # flush() (or an explicit poll()) runs.
-            admission = AdmissionPolicy(max_batch=64 if max_batch is None else max_batch)
-            eager = False
-        else:
-            if max_batch is not None:
-                raise ValueError(
-                    "pass max_batch inside AdmissionPolicy when admission= is given"
-                )
-            eager = True
-        super().__init__(
-            m=m,
-            heuristic=heuristic,
-            default_chunks=default_chunks,
-            admission=admission,
-            eager=eager,
-            clock=clock,
-            backend=backend,
-            dispatch=dispatch,
-            max_queue=max_queue,
-        )

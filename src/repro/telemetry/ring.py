@@ -2,7 +2,7 @@
 
 Every serving dispatch (``SolveEngine._dispatch``) records one
 :class:`BatchObservation` — the batch composition, the chunk pick that
-priced it, the resolved backend/layout/dispatch, queue wait, dispatch
+priced it, the resolved backend/layout, queue wait, dispatch
 latency, and (when a fitted :class:`~repro.core.streams.timemodel
 .LatencyModel` is active) the predicted latency — into a
 :class:`TelemetryBuffer`. The buffer is the collection layer of the
@@ -37,7 +37,7 @@ class BatchObservation:
     ``t`` is the engine clock's timestamp at admission (the same injectable
     clock deadlines run on); ``sizes`` is the batch composition (one entry
     per fused system); ``num_chunks`` the chunk ("virtual stream") pick the
-    plan actually used; ``backend``/``layout``/``dispatch`` are the
+    plan actually used; ``backend``/``layout`` are the
     *resolved* execution route (never ``"auto"``); ``latency_ms`` the wall
     time of the dispatch, ``mean_wait_ms``/``max_wait_ms`` the batch's queue
     waits; ``predicted_ms`` the active latency model's pre-dispatch
@@ -50,7 +50,6 @@ class BatchObservation:
     num_chunks: int
     backend: str
     layout: str
-    dispatch: str
     latency_ms: float
     mean_wait_ms: float
     max_wait_ms: float
@@ -82,7 +81,6 @@ class BatchObservation:
             "num_chunks": self.num_chunks,
             "backend": self.backend,
             "layout": self.layout,
-            "dispatch": self.dispatch,
             "latency_ms": self.latency_ms,
             "mean_wait_ms": self.mean_wait_ms,
             "max_wait_ms": self.max_wait_ms,

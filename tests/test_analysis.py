@@ -285,48 +285,6 @@ def test_trd003_good_jnp_inside_trace():
     assert found == []
 
 
-# ------------------------------------------------------------------- TRD004 --
-def test_trd004_bad_construction_in_src():
-    found = run(
-        "from repro.core.tridiag.chunked import ChunkedPartitionSolver\n"
-        "s = ChunkedPartitionSolver(8, num_chunks=2)\n",
-        select="TRD004",
-    )
-    assert codes(found) == ["TRD004"]
-    assert "TridiagSession" in found[0].fixit
-
-
-def test_trd004_bad_qualified_construction():
-    found = run(
-        "import repro.serve.solve as serve\n"
-        "svc = serve.BatchedSolveService(m=10)\n",
-        select="TRD004",
-    )
-    assert codes(found) == ["TRD004"]
-
-
-def test_trd004_good_under_tests():
-    found = check_source(
-        "s = ChunkedPartitionSolver(8, num_chunks=2)\n",
-        "tests/test_legacy.py",
-        registry=FIXTURE_REGISTRY,
-        select=["TRD004"],
-    )
-    assert found == []
-
-
-def test_trd004_good_reference_without_construction():
-    # Re-exports and subclassing keep the shims alive without new call paths.
-    found = run(
-        "from repro.core.tridiag.chunked import ChunkedPartitionSolver\n"
-        "__all__ = ['ChunkedPartitionSolver']\n"
-        "class Shim(ChunkedPartitionSolver):\n"
-        "    pass\n",
-        select="TRD004",
-    )
-    assert found == []
-
-
 # ------------------------------------------------------------------- TRD005 --
 def _module(name, **attrs):
     mod = types.ModuleType(name)
@@ -395,7 +353,7 @@ def test_syntax_error_reports_trd000():
 
 
 def test_rule_table_is_complete():
-    assert sorted(RULES) == ["TRD001", "TRD002", "TRD003", "TRD004", "TRD005"]
+    assert sorted(RULES) == ["TRD001", "TRD002", "TRD003", "TRD005"]
     for rule in RULES.values():
         assert rule.SUMMARY and rule.FIXIT and rule.NAME
 

@@ -1,8 +1,7 @@
 """Tests for the one front door: SolverConfig validation, TridiagSession's
-four verbs (fp64+fp32 parity with the Thomas oracle and the legacy solver
-classes on both backends from a single shared config), the async SolveFuture
-path (deadline admission fires via the worker thread — no poll() anywhere),
-session lifecycle, and the legacy frontends' deprecation."""
+four verbs (fp64+fp32 parity with the Thomas oracle on both backends from a
+single shared config), the async SolveFuture path (deadline admission fires
+via the worker thread — no poll() anywhere), and session lifecycle."""
 
 import math
 import threading
@@ -139,41 +138,6 @@ def test_all_four_verbs_match_thomas_from_one_config(backend, dtype):
             assert _rel_err(futs[rid].result(timeout=30.0), thomas_numpy(*s)) < tol
 
 
-@pytest.mark.parametrize("backend", ["reference", "pallas"])
-def test_session_matches_legacy_solver_classes(backend):
-    """End-to-end parity: the facade and the deprecated frontends produce
-    bit-identical solutions for the same configuration.
-
-    The legacy classes are pinned to the staged dispatch path (their
-    pre-fused contract), so the bitwise comparison uses a staged session;
-    the fused-vs-staged tolerance parity lives in tests/test_dispatch.py.
-    """
-    cfg = SolverConfig(m=10, num_chunks=4, backend=backend, dispatch="staged")
-    session = TridiagSession(cfg)
-    with pytest.warns(DeprecationWarning):
-        from repro.core.tridiag import (
-            BatchedPartitionSolver,
-            ChunkedPartitionSolver,
-            RaggedPartitionSolver,
-        )
-
-        chunked = ChunkedPartitionSolver(m=10, num_chunks=4, backend=backend)
-        batched = BatchedPartitionSolver(m=10, num_chunks=4, backend=backend)
-        ragged = RaggedPartitionSolver(m=10, num_chunks=4, backend=backend)
-
-    dl, d, du, b, _ = make_diag_dominant_system(300, seed=3)
-    np.testing.assert_array_equal(
-        session.solve(dl, d, du, b), chunked.solve(dl, d, du, b)
-    )
-    DL, D, DU, B, _ = make_diag_dominant_system(120, seed=4, batch=(3,))
-    np.testing.assert_array_equal(
-        session.solve_batched(DL, D, DU, B), batched.solve(DL, D, DU, B)
-    )
-    systems = _mk_systems((60, 240, 120), seed0=5)
-    for a, bb in zip(session.solve_many(systems), ragged.solve(systems)):
-        np.testing.assert_array_equal(a, bb)
-
-
 def test_solve_batched_rejects_1d_operands():
     dl, d, du, b, _ = make_diag_dominant_system(60, seed=0)
     with pytest.raises(ValueError, match="solve_batched takes"):
@@ -185,8 +149,9 @@ def test_policy_config_prices_each_dispatch():
     session = TridiagSession(cfg)
     assert session.plan_for(600).num_chunks == 5
     dl, d, du, b, _ = make_diag_dominant_system(600, seed=6)
-    _, timing = session.solve_timed(dl, d, du, b)
-    assert timing.num_chunks == 5
+    x = session.solve(dl, d, du, b)
+    assert _rel_err(x, thomas_numpy(dl, d, du, b)) < TOL[np.float64]
+    assert session.plan_for(600).num_chunks == 5
 
 
 # --------------------------------------------------------- async / futures ---
@@ -307,25 +272,3 @@ def test_context_manager_closes():
     with pytest.raises(RuntimeError):
         dl, d, du, b, _ = make_diag_dominant_system(60, seed=32)
         session.submit(SolveRequest(0, dl, d, du, b))
-
-
-# -------------------------------------------------------------- deprecation --
-def test_legacy_frontends_warn_deprecation():
-    from repro.core.tridiag import (
-        BatchedPartitionSolver,
-        ChunkedPartitionSolver,
-        RaggedPartitionSolver,
-        solve_ragged,
-    )
-    from repro.serve.solve import BatchedSolveService
-
-    with pytest.warns(DeprecationWarning, match="ChunkedPartitionSolver"):
-        ChunkedPartitionSolver(m=10, num_chunks=2)
-    with pytest.warns(DeprecationWarning, match="BatchedPartitionSolver"):
-        BatchedPartitionSolver(m=10, num_chunks=2)
-    with pytest.warns(DeprecationWarning, match="RaggedPartitionSolver"):
-        RaggedPartitionSolver(m=10, num_chunks=2)
-    with pytest.warns(DeprecationWarning, match="solve_ragged"):
-        solve_ragged(_mk_systems((60,)), m=10)
-    with pytest.warns(DeprecationWarning, match="BatchedSolveService"):
-        BatchedSolveService(m=10)

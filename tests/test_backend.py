@@ -12,12 +12,13 @@ ensure_x64()
 
 from repro.core.tridiag import (  # noqa: E402
     BACKENDS,
-    BatchedPartitionSolver,
-    ChunkedPartitionSolver,
+    AdmissionPolicy,
     PallasBackend,
     PlanExecutor,
-    RaggedPartitionSolver,
     ReferenceBackend,
+    SolveEngine,
+    SolverConfig,
+    TridiagSession,
     build_plan,
     clear_plan_cache,
     jitted_stages,
@@ -26,13 +27,17 @@ from repro.core.tridiag import (  # noqa: E402
     resolve_backend,
     thomas_numpy,
 )
-from repro.serve.solve import BatchedSolveService, SolveRequest  # noqa: E402
+from repro.serve.solve import SolveRequest  # noqa: E402
 
 TOL = {np.float64: 1e-11, np.float32: 2e-4}
 
 
 def _rel_err(x, ref):
     return np.max(np.abs(x - ref)) / (np.max(np.abs(ref)) + 1e-30)
+
+
+def _session(num_chunks, backend="pallas"):
+    return TridiagSession(SolverConfig(m=10, num_chunks=num_chunks, backend=backend))
 
 
 def _mk_systems(sizes, dtype=np.float64, seed0=0):
@@ -71,17 +76,16 @@ def test_stage_cache_keyed_by_m_and_backend():
 @pytest.mark.parametrize("num_chunks", [1, 3, 7])
 def test_pallas_backend_single_matches_thomas(num_chunks):
     dl, d, du, b, _ = make_diag_dominant_system(400, seed=num_chunks)
-    solver = ChunkedPartitionSolver(m=10, num_chunks=num_chunks, backend="pallas")
-    x, timing = solver.solve_timed(dl, d, du, b)
+    session = _session(num_chunks)
+    x = session.solve(dl, d, du, b)
     assert _rel_err(x, thomas_numpy(dl, d, du, b)) < 1e-11
-    assert timing.num_chunks == num_chunks
+    assert session.plan_for(400).num_chunks == num_chunks
+    assert session.stats["backend"]["name"] == "pallas"
 
 
 def test_pallas_backend_batched_matches_per_system_thomas():
     dl, d, du, b, _ = make_diag_dominant_system(240, seed=2, batch=(4,))
-    x = BatchedPartitionSolver(m=10, num_chunks=5, backend="pallas").solve(
-        dl, d, du, b
-    )
+    x = _session(5).solve_batched(dl, d, du, b)
     for i in range(4):
         assert _rel_err(x[i], thomas_numpy(dl[i], d[i], du[i], b[i])) < 1e-11
 
@@ -103,31 +107,29 @@ def test_pallas_backend_ragged_matches_per_system_thomas(dtype, num_chunks):
     per-system Thomas in both precisions."""
     sizes = (60, 240, 120, 500)
     systems = _mk_systems(sizes, dtype=dtype)
-    solver = RaggedPartitionSolver(m=10, num_chunks=num_chunks, backend="pallas")
-    xs, timing = solver.solve_timed(systems)
+    session = _session(num_chunks)
+    xs = session.solve_many(systems)
     refs = [
         thomas_numpy(*[np.asarray(a, np.float64) for a in s]) for s in systems
     ]
     for x, ref in zip(xs, refs):
         assert _rel_err(np.asarray(x, np.float64), ref) < TOL[dtype]
-    assert timing.num_chunks == min(num_chunks, sum(sizes) // 10)
+    assert session.plan_for(sizes).num_chunks == min(num_chunks, sum(sizes) // 10)
 
 
 def test_pallas_and_reference_backends_agree_exactly_on_layout():
     """Same plan, same operands: the two backends must agree to fp64
     round-off, chunk by chunk (not just against the oracle)."""
     systems = _mk_systems((200, 1000, 300))
-    xs_ref = RaggedPartitionSolver(m=10, num_chunks=6).solve(systems)
-    xs_pal = RaggedPartitionSolver(m=10, num_chunks=6, backend="pallas").solve(
-        systems
-    )
+    xs_ref = _session(6, "reference").solve_many(systems)
+    xs_pal = _session(6).solve_many(systems)
     for a, b in zip(xs_ref, xs_pal):
         np.testing.assert_allclose(a, b, rtol=1e-12, atol=1e-12)
 
 
 def test_serving_dispatches_on_pallas_backend():
     refs = {}
-    svc = BatchedSolveService(m=10, max_batch=8, backend="pallas")
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=8), backend="pallas")
     for rid, size in enumerate((60, 240, 60, 120)):
         dl, d, du, b, _ = make_diag_dominant_system(size, seed=rid)
         svc.submit(SolveRequest(rid, dl, d, du, b))

@@ -1,6 +1,6 @@
 """Correctness tests for the batched multi-SLAE subsystem: functional solve,
-fused chunked solver, batched-grid Pallas kernels, and the serving wrapper —
-all against the per-system NumPy Thomas oracle."""
+the session's chunked ``solve_batched``, batched-grid Pallas kernels, and the
+serving engine — all against the per-system NumPy Thomas oracle."""
 
 import numpy as np
 import pytest
@@ -12,7 +12,10 @@ ensure_x64()
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core.tridiag import (  # noqa: E402
-    BatchedPartitionSolver,
+    AdmissionPolicy,
+    SolveEngine,
+    SolverConfig,
+    TridiagSession,
     fuse_systems,
     make_diag_dominant_system,
     solve_batched,
@@ -29,7 +32,6 @@ from repro.kernels.partition_stage3.ops import (  # noqa: E402
     partition_solve_pallas_batched,
 )
 from repro.serve.solve import (  # noqa: E402
-    BatchedSolveService,
     SolveRequest,
     make_batched_solve_step,
 )
@@ -88,19 +90,23 @@ def test_fuse_systems_decouples_exactly():
 
 
 # ---------------------------------------------------------- chunked solver ----
+def _session(m, num_chunks, backend="reference"):
+    return TridiagSession(SolverConfig(m=m, num_chunks=num_chunks, backend=backend))
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("num_chunks", [1, 2, 3, 7, 32])
 @pytest.mark.parametrize("bsz", [1, 4])
-def test_batched_chunked_solver_matches_reference(bsz, num_chunks):
+def test_batched_chunked_solver_matches_reference(bsz, num_chunks, backend):
     # n/m = 13 blocks per system: chunk counts 2, 3, 7, 32 do not divide the
     # fused block count, exercising the ragged chunk-bounds path.
     n, m = 130, 10
     dl, d, du, b, _ = make_diag_dominant_system(n, seed=num_chunks, batch=(bsz,))
-    solver = BatchedPartitionSolver(m=m, num_chunks=num_chunks)
-    x, timing = solver.solve_timed(dl, d, du, b)
+    session = _session(m, num_chunks, backend)
+    x = session.solve_batched(dl, d, du, b)
     assert x.shape == (bsz, n)
     assert _rel_err(x, _per_system_ref(dl, d, du, b)) < 1e-11
-    assert timing.num_chunks == min(num_chunks, bsz * n // m)
-    assert timing.t_total_ms > 0
+    assert session.plan_for((n,) * bsz).num_chunks == min(num_chunks, bsz * n // m)
 
 
 def test_batched_chunks_span_system_boundaries():
@@ -108,16 +114,17 @@ def test_batched_chunks_span_system_boundaries():
     works because the fused block axis spans the whole batch."""
     bsz, n, m = 8, 30, 10  # 3 blocks/system, 24 fused blocks
     dl, d, du, b, _ = make_diag_dominant_system(n, seed=9, batch=(bsz,))
-    solver = BatchedPartitionSolver(m=m, num_chunks=16)
-    x, timing = solver.solve_timed(dl, d, du, b)
-    assert timing.num_chunks == 16  # > 3 = per-system block count
+    session = _session(m, 16)
+    x = session.solve_batched(dl, d, du, b)
+    assert session.plan_for((n,) * bsz).num_chunks == 16  # > 3 blocks/system
     assert _rel_err(x, _per_system_ref(dl, d, du, b)) < 1e-11
 
 
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("dtype", [np.float64, np.float32])
-def test_batched_chunked_solver_fp32(dtype):
+def test_batched_chunked_solver_fp32(dtype, backend):
     dl, d, du, b, _ = make_diag_dominant_system(200, seed=1, batch=(3,), dtype=dtype)
-    x = BatchedPartitionSolver(m=10, num_chunks=4).solve(dl, d, du, b)
+    x = _session(10, 4, backend).solve_batched(dl, d, du, b)
     assert _rel_err(x, _per_system_ref(dl, d, du, b)) < TOL[dtype]
 
 
@@ -156,7 +163,7 @@ def test_batched_solve_step_builder():
 
 
 def test_solve_service_batches_same_size_requests():
-    svc = BatchedSolveService(m=10, max_batch=4, default_chunks=2)
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=4), default_chunks=2)
     refs = {}
     rid = 0
     for size, count in ((60, 6), (120, 3)):
@@ -186,7 +193,7 @@ def test_solve_service_uses_heuristic_pick():
         sim.dataset(sizes=(10_000, 100_000, 1_000_000, 10_000_000),
                     batches=(1, 8, 64), reps=2)
     )
-    svc = BatchedSolveService(heuristic=h, m=10, max_batch=64)
+    svc = SolveEngine(heuristic=h, m=10)
     assert svc.pick_chunks(10_000, 1) == h.predict_optimum(10_000, 1)
     assert svc.pick_chunks(100_000, 64) == h.predict_optimum(100_000, 64)
     # a big batch of small systems must want more chunks than a single one
@@ -194,7 +201,7 @@ def test_solve_service_uses_heuristic_pick():
 
 
 def test_solve_service_rejects_indivisible_size():
-    svc = BatchedSolveService(m=10)
+    svc = SolveEngine(m=10)
     dl, d, du, b, _ = make_diag_dominant_system(55, seed=0)
     with pytest.raises(ValueError):
         svc.submit(SolveRequest(0, dl, d, du, b))

@@ -20,8 +20,13 @@ ensure_x64()
 
 from repro import compile_cache  # noqa: E402
 from repro.api import SolverConfig, TridiagSession  # noqa: E402
+from repro.core.streams.measure import staged_timing  # noqa: E402
 from repro.core.tridiag import make_diag_dominant_system, thomas_numpy  # noqa: E402
-from repro.core.tridiag.plan import PallasBackend, ReferenceBackend  # noqa: E402
+from repro.core.tridiag.plan import (  # noqa: E402
+    PallasBackend,
+    ReferenceBackend,
+    resolve_backend,
+)
 from repro.kernels.thomas.ops import (  # noqa: E402
     VMEM_BUDGET_BYTES,
     thomas_fits_vmem,
@@ -90,11 +95,11 @@ def test_session_reports_stage2_either_side(n, impl):
 
 def test_staged_path_reports_host_stage2():
     dl, d, du, b, _ = make_diag_dominant_system(400, seed=4)
-    with TridiagSession(SolverConfig()) as s:
-        _, timing = s.solve_timed(dl, d, du, b)
-        assert timing.stage2 == "host"
-        assert s.stats["stage2"] == {"host": 1}
-        assert s.stats["backend"] == {"name": "reference", "interpret": None}
+    x, timing = staged_timing(400, dl, d, du, b, m=10, num_chunks=1, backend="auto")
+    assert timing.stage2 == "host"
+    assert timing.layout == "system-major"
+    assert resolve_backend("auto").name == "reference"
+    assert np.max(np.abs(x - thomas_numpy(dl, d, du, b))) < 1e-11
 
 
 # ----------------------------------------------------------- fp64 refusal --
@@ -121,7 +126,7 @@ def test_fp64_operands_refused_by_first_solve(on_tpu):
         with pytest.raises(ValueError, match=r"dtype=np\.float32"):
             s.solve(dl, d, du, b)
         with pytest.raises(ValueError, match=r"dtype=np\.float32"):
-            s.solve_timed(dl, d, du, b)
+            staged_timing(200, dl, d, du, b, m=10, num_chunks=1, backend="auto")
         assert s.stats["stage2"] == {}
 
 

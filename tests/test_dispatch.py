@@ -1,6 +1,6 @@
-"""Fused single-dispatch execution path: staged-vs-fused parity on every
-planned path (single, batched, ragged, served) × fp64/fp32 × both backends
-from one shared SolverConfig, the fused-executable LRU (hit/miss/eviction
+"""Fused single-dispatch execution path: parity with the staged per-phase
+instrument on every planned path (single, batched, ragged, served) ×
+fp64/fp32 × both backends, the fused-executable LRU (hit/miss/eviction
 stats, capacity, clear), donation semantics, and a two-thread session hammer
 over the new cache."""
 
@@ -17,24 +17,25 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.api import (  # noqa: E402
-    DISPATCH_MODES,
     SolveEngine,
     SolveRequest,
     SolverConfig,
     TridiagSession,
 )
+from repro.core.streams.measure import staged_timing  # noqa: E402
+from repro.core.tridiag.batched import fuse_systems, split_systems  # noqa: E402
 from repro.core.tridiag.layout import (  # noqa: E402
     AUTO_INTERLEAVE_MIN_BATCH,
     resolve_layout,
 )
 from repro.core.tridiag.plan import (  # noqa: E402
     FusedExecutor,
-    PlanExecutor,
     build_plan,
     clear_executable_cache,
     executable_cache_stats,
     set_executable_cache_capacity,
 )
+from repro.core.tridiag.ragged import fuse_ragged, split_ragged  # noqa: E402
 from repro.core.tridiag.reference import (  # noqa: E402
     make_diag_dominant_system,
     thomas_numpy,
@@ -72,21 +73,25 @@ def _fresh_executable_cache():
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("dtype", [np.float64, np.float32])
 def test_fused_matches_staged_on_all_paths(backend, dtype):
-    """One shared config, two dispatch modes: identical-within-tolerance
-    solutions on the single, batched and ragged paths."""
-    base = SolverConfig(m=10, num_chunks=3, backend=backend, dtype=dtype)
-    staged = TridiagSession(base.replace(dispatch="staged"))
-    fused = TridiagSession(base.replace(dispatch="fused"))
+    """The session and the staged instrument on the same plan:
+    identical-within-tolerance solutions on the single, batched and ragged
+    paths."""
+    fused = TridiagSession(
+        SolverConfig(m=10, num_chunks=3, backend=backend, dtype=dtype)
+    )
     tol = TOL[dtype]
+
+    def staged(sizes, *ops):
+        return staged_timing(sizes, *ops, m=10, num_chunks=3, backend=backend)[0]
 
     dl, d, du, b, _ = make_diag_dominant_system(300, seed=0, dtype=dtype)
     ref = thomas_numpy(dl, d, du, b)
-    xs, xf = staged.solve(dl, d, du, b), fused.solve(dl, d, du, b)
+    xs, xf = staged(300, dl, d, du, b), fused.solve(dl, d, du, b)
     assert _rel_err(xs, ref) < tol and _rel_err(xf, ref) < tol
     np.testing.assert_allclose(xf, xs, rtol=tol, atol=tol)
 
     DL, D, DU, B, _ = make_diag_dominant_system(120, seed=1, batch=(3,), dtype=dtype)
-    xbs = staged.solve_batched(DL, D, DU, B)
+    xbs = split_systems(staged((120,) * 3, *fuse_systems(DL, D, DU, B)), 3)
     xbf = fused.solve_batched(DL, D, DU, B)
     for i in range(3):
         ref = thomas_numpy(DL[i], D[i], DU[i], B[i])
@@ -94,8 +99,9 @@ def test_fused_matches_staged_on_all_paths(backend, dtype):
     np.testing.assert_allclose(xbf, xbs, rtol=tol, atol=tol)
 
     systems = _mk_systems((60, 240, 120), dtype=dtype, seed0=2)
+    *ops, sizes = fuse_ragged(systems)
     for xi_s, xi_f, s in zip(
-        staged.solve_many(systems), fused.solve_many(systems), systems
+        split_ragged(staged(sizes, *ops), sizes), fused.solve_many(systems), systems
     ):
         assert _rel_err(xi_f, thomas_numpy(*s)) < tol
         np.testing.assert_allclose(xi_f, xi_s, rtol=tol, atol=tol)
@@ -103,8 +109,8 @@ def test_fused_matches_staged_on_all_paths(backend, dtype):
 
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
 def test_fused_serving_path_matches_oracle(backend):
-    """submit() under the default dispatch="auto" serves each batch as one
-    fused dispatch; every future's solution sits on the fp64 oracle."""
+    """submit() serves each batch as one fused dispatch; every future's
+    solution sits on the fp64 oracle."""
     cfg = SolverConfig(m=10, num_chunks=2, backend=backend, max_wait_ms=5.0)
     assert cfg.dispatch == "auto"
     systems = _mk_systems((60, 120, 60, 240), seed0=7)
@@ -117,33 +123,17 @@ def test_fused_serving_path_matches_oracle(backend):
     assert session.stats["batches"] >= 1
 
 
-def test_engine_dispatch_selection():
-    eng_auto = SolveEngine(m=10)
-    eng_fused = SolveEngine(m=10, dispatch="fused")
-    eng_staged = SolveEngine(m=10, dispatch="staged")
-    assert isinstance(eng_auto._executor, FusedExecutor)
-    assert isinstance(eng_fused._executor, FusedExecutor)
-    assert isinstance(eng_staged._executor, PlanExecutor)
-    with pytest.raises(ValueError, match="dispatch"):
-        SolveEngine(m=10, dispatch="warp")
-
-
-def test_dispatch_validation_and_auto_timed_rule():
-    assert set(DISPATCH_MODES) == {"staged", "fused", "auto"}
-    with pytest.raises(ValueError, match="dispatch='warp'"):
-        SolverConfig(dispatch="warp").validate()
-
-    dl, d, du, b, _ = make_diag_dominant_system(200, seed=3)
-    auto = TridiagSession(SolverConfig(m=10, num_chunks=2))
-    # *_timed keeps the staged path (phase breakdown observable)...
-    _, timing = auto.solve_timed(dl, d, du, b)
-    assert timing.t_stage1_ms > 0.0 and timing.t_stage2_ms > 0.0
-    # ...while an explicit "fused" session reports only the total.
-    fused = TridiagSession(SolverConfig(m=10, num_chunks=2, dispatch="fused"))
-    _, timing = fused.solve_timed(dl, d, du, b)
-    assert timing.phases == (0.0, 0.0, 0.0)
-    assert timing.t_total_ms > 0.0
-    assert timing.num_chunks == 2
+@pytest.mark.parametrize("mode", ["staged", "fused", "bogus"])
+def test_dispatch_validation_and_auto_timed_rule(mode):
+    """``dispatch`` keeps one legal value, "auto"; every other value is
+    refused with a pointer to the per-phase instrument, and the session
+    serves on the fused executor alone."""
+    assert SolverConfig(dispatch="auto").validate().dispatch == "auto"
+    with pytest.raises(ValueError, match=f"dispatch='{mode}'.*staged_timing"):
+        SolverConfig(dispatch=mode).validate()
+    with pytest.raises(ValueError, match="staged_timing"):
+        TridiagSession(SolverConfig(m=10, dispatch=mode))
+    assert isinstance(SolveEngine(m=10)._executor, FusedExecutor)
 
 
 # ---------------------------------------------------------------- donation ---
@@ -235,34 +225,28 @@ def test_executable_cache_eviction_churn_stays_correct():
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("layout", ["system-major", "interleaved"])
 def test_layout_parity_on_all_paths(backend, layout):
-    """Explicit layouts agree with the fp64 oracle (and with each other's
-    tolerance) on the single, batched and ragged paths, staged and fused,
-    both dtypes."""
+    """Explicit layouts agree with the fp64 oracle on the single, batched
+    and ragged paths, both dtypes."""
     for dtype in (np.float64, np.float32):
-        base = SolverConfig(
-            m=10, num_chunks=2, backend=backend, dtype=dtype, layout=layout
+        session = TridiagSession(
+            SolverConfig(m=10, num_chunks=2, backend=backend, dtype=dtype, layout=layout)
         )
         tol = TOL[dtype]
-        staged = TridiagSession(base.replace(dispatch="staged"))
-        fused = TridiagSession(base.replace(dispatch="fused"))
 
         dl, d, du, b, _ = make_diag_dominant_system(200, seed=11, dtype=dtype)
         ref = thomas_numpy(dl, d, du, b)
-        assert _rel_err(staged.solve(dl, d, du, b), ref) < tol
-        assert _rel_err(fused.solve(dl, d, du, b), ref) < tol
+        assert _rel_err(session.solve(dl, d, du, b), ref) < tol
 
         DL, D, DU, B, _ = make_diag_dominant_system(
             120, seed=12, batch=(8,), dtype=dtype
         )
-        for sess in (staged, fused):
-            xb = sess.solve_batched(DL, D, DU, B)
-            for i in range(8):
-                assert _rel_err(xb[i], thomas_numpy(DL[i], D[i], DU[i], B[i])) < tol
+        xb = session.solve_batched(DL, D, DU, B)
+        for i in range(8):
+            assert _rel_err(xb[i], thomas_numpy(DL[i], D[i], DU[i], B[i])) < tol
 
         systems = _mk_systems((60, 240, 120), dtype=dtype, seed0=13)
-        for sess in (staged, fused):
-            for xi, s in zip(sess.solve_many(systems), systems):
-                assert _rel_err(xi, thomas_numpy(*s)) < tol
+        for xi, s in zip(session.solve_many(systems), systems):
+            assert _rel_err(xi, thomas_numpy(*s)) < tol
 
 
 def test_executable_cache_keys_layouts_separately():
@@ -292,7 +276,7 @@ def test_auto_layout_resolution_via_cache_key():
     executable below it."""
     bsz = AUTO_INTERLEAVE_MIN_BATCH
     dl, d, du, b, _ = make_diag_dominant_system(100, seed=15, batch=(bsz,))
-    cfg = SolverConfig(m=10, num_chunks=1, dispatch="fused", backend="reference")
+    cfg = SolverConfig(m=10, num_chunks=1, backend="reference")
     auto = TridiagSession(cfg)
     assert auto.config.layout == "auto"
     auto.solve_batched(dl, d, du, b)
@@ -313,28 +297,25 @@ def test_auto_layout_resolution_via_cache_key():
 def test_resolve_layout_rules_and_validation():
     m, n = 10, 100
     big = (n,) * AUTO_INTERLEAVE_MIN_BATCH
-    # auto: fused + wide flat batch -> interleaved; anything else system-major.
-    assert resolve_layout("auto", big, m, fused=True) == "interleaved"
-    assert resolve_layout("auto", big[:-1], m, fused=True) == "system-major"
-    assert resolve_layout("auto", big, m, fused=False) == "system-major"
-    assert resolve_layout("auto", big, m, fused=True, lead_ndim=1) == "system-major"
+    # auto: wide flat batch -> interleaved; anything else system-major.
+    assert resolve_layout("auto", big, m) == "interleaved"
+    assert resolve_layout("auto", big[:-1], m) == "system-major"
+    assert resolve_layout("auto", big, m, lead_ndim=1) == "system-major"
     # auto: ragged padding past the waste bound stays system-major.
     skewed = (40 * m,) + (m,) * (AUTO_INTERLEAVE_MIN_BATCH - 1)
-    assert resolve_layout("auto", skewed, m, fused=True) == "system-major"
+    assert resolve_layout("auto", skewed, m) == "system-major"
     # explicit layouts pass through; interleaved rejects stacked operands.
-    assert resolve_layout("system-major", big, m, fused=True) == "system-major"
-    assert resolve_layout("interleaved", (n,), m, fused=False) == "interleaved"
+    assert resolve_layout("system-major", big, m) == "system-major"
+    assert resolve_layout("interleaved", (n,), m) == "interleaved"
     with pytest.raises(ValueError, match="interleaved"):
-        resolve_layout("interleaved", big, m, fused=True, lead_ndim=1)
+        resolve_layout("interleaved", big, m, lead_ndim=1)
     with pytest.raises(ValueError, match="layout"):
-        resolve_layout("warp", big, m, fused=True)
+        resolve_layout("warp", big, m)
 
     with pytest.raises(ValueError, match="layout"):
         SolverConfig(layout="warp").validate()
     with pytest.raises(ValueError, match="layout"):
         SolveEngine(m=10, layout="warp")
-    with pytest.raises(ValueError, match="layout"):
-        PlanExecutor("reference", layout="warp")
     with pytest.raises(ValueError, match="layout"):
         FusedExecutor("reference", layout="warp")
 
@@ -343,7 +324,7 @@ def test_two_thread_session_hammer_over_executable_lru():
     """Two sessions solving concurrently (distinct plans, shared tiny LRU):
     the lock-protected cache must neither corrupt results nor deadlock."""
     set_executable_cache_capacity(2)
-    cfg = SolverConfig(m=10, dispatch="fused")
+    cfg = SolverConfig(m=10)
     sizes = (100, 200, 300)
     problems = {
         (n, k): make_diag_dominant_system(n, seed=n + k)[:4]

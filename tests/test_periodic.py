@@ -229,13 +229,6 @@ def test_what_a_periodic_call_refuses():
     assert timing.periodic
 
 
-def test_staged_sessions_still_solve_periodic_systems_fused():
-    ops = random_cyclic(np.random.default_rng(13), 3, 40)
-    with session("pallas", dispatch="staged") as s:
-        x = s.solve_periodic_batched(*ops)
-    assert rel_err(x, dense(*ops)) < TOL
-
-
 def test_interleaved_lanes_shard_over_a_mesh(multi_device_count: int):
     """Each device owns whole systems on the lane-sharded interleaved path,
     so the wrap needs no collective."""

@@ -1,6 +1,6 @@
 """Tests for the plan/execute layer: SolvePlan layout invariants, chunk
-policies, the module-level jit cache, and PlanExecutor correctness — plus the
-guarantee that the chunked/batched solvers stay thin frontends."""
+policies, the module-level jit cache, and the correctness of PlanExecutor,
+the staged per-phase instrument."""
 
 import numpy as np
 import pytest
@@ -10,19 +10,17 @@ from repro.core.tridiag import ensure_x64
 ensure_x64()
 
 from repro.core.tridiag import (  # noqa: E402
-    ChunkedPartitionSolver,
     FixedChunkPolicy,
     HeuristicChunkPolicy,
     PlanExecutor,
-    SolvePlan,
+    SolverConfig,
+    TridiagSession,
     build_plan,
     effective_size,
     jitted_stages,
     make_diag_dominant_system,
     thomas_numpy,
 )
-from repro.core.tridiag import batched as batched_mod  # noqa: E402
-from repro.core.tridiag import chunked as chunked_mod  # noqa: E402
 
 
 def _rel_err(x, ref):
@@ -133,11 +131,12 @@ def test_jitted_stages_cached_per_m():
 
 
 def test_solvers_share_cached_stages():
-    """Constructing many solvers must not create new jitted callables."""
+    """Constructing many executors and sessions must not create new jitted
+    callables."""
     before = jitted_stages(10)
     for k in (1, 2, 4, 8):
-        ChunkedPartitionSolver(m=10, num_chunks=k)
-        batched_mod.BatchedPartitionSolver(m=10, num_chunks=k)
+        PlanExecutor("reference")
+        TridiagSession(SolverConfig(m=10, num_chunks=k, backend="reference"))
     assert jitted_stages(10) == before
 
 
@@ -168,17 +167,3 @@ def test_executor_rejects_mismatched_operands():
     plan = build_plan(200, 10)
     with pytest.raises(ValueError):
         PlanExecutor().execute(plan, dl, d, du, b)
-
-
-# -------------------------------------------------------- thin-frontend-ness --
-def test_frontends_carry_no_chunk_or_halo_logic():
-    """Acceptance: chunked.py / batched.py no longer own chunk-bounds, halo or
-    ghost implementations — the plan layer is the single home for them."""
-    for mod in (chunked_mod, batched_mod):
-        src_names = dir(mod)
-        assert "_stage3_with_ghost" not in src_names
-    assert not hasattr(ChunkedPartitionSolver, "_chunk_bounds")
-    assert not hasattr(batched_mod.BatchedPartitionSolver, "_chunk_bounds")
-    # and the frontends produce plans rather than bounds
-    plan = ChunkedPartitionSolver(m=10, num_chunks=3).plan_for(300)
-    assert isinstance(plan, SolvePlan)

@@ -11,10 +11,10 @@ ensure_x64()
 
 from repro.core.tridiag import (  # noqa: E402
     HeuristicChunkPolicy,
-    RaggedPartitionSolver,
+    SolverConfig,
+    TridiagSession,
     fuse_ragged,
     make_diag_dominant_system,
-    solve_ragged,
     split_ragged,
     thomas_numpy,
 )
@@ -91,12 +91,17 @@ def test_fuse_ragged_promotes_mixed_dtypes():
 
 
 # ------------------------------------------------------------- fused solve ---
+def _session(backend="reference", **cfg):
+    return TridiagSession(SolverConfig(m=10, backend=backend, **cfg))
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("num_chunks", [1, 2, 4, 32])
-def test_ragged_solve_matches_per_system_thomas(num_chunks):
+def test_ragged_solve_matches_per_system_thomas(num_chunks, backend):
     """The acceptance mix {200, 1000, 5000} in one plan, fp64 oracle."""
     sizes = (200, 1000, 5000)
     systems = _mk_systems(sizes, seed0=num_chunks)
-    xs = solve_ragged(systems, m=10, num_chunks=num_chunks)
+    xs = _session(backend, num_chunks=num_chunks).solve_many(systems)
     assert [x.shape[-1] for x in xs] == list(sizes)
     for (dl, d, du, b), x in zip(systems, xs):
         assert _rel_err(x, thomas_numpy(dl, d, du, b)) < TOL[np.float64]
@@ -107,41 +112,42 @@ def test_ragged_chunks_span_system_boundaries():
     because the fused block axis spans the whole heterogeneous batch."""
     sizes = (30, 60, 30, 90, 30)  # 3..9 blocks each, 24 fused blocks
     systems = _mk_systems(sizes, seed0=9)
-    solver = RaggedPartitionSolver(m=10, num_chunks=16)
-    xs, timing = solver.solve_timed(systems)
-    assert timing.num_chunks == 16  # > 9 = the largest per-system block count
+    session = _session(num_chunks=16)
+    xs = session.solve_many(systems)
+    # > 9 = the largest per-system block count
+    assert session.plan_for(sizes).num_chunks == 16
     for (dl, d, du, b), x in zip(systems, xs):
         assert _rel_err(x, thomas_numpy(dl, d, du, b)) < 1e-11
 
 
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("dtype", [np.float64, np.float32])
-def test_ragged_solve_fp32(dtype):
+def test_ragged_solve_fp32(dtype, backend):
     systems = _mk_systems((100, 300, 200), dtype=dtype, seed0=3)
-    xs = solve_ragged(systems, m=10, num_chunks=4)
+    xs = _session(backend, num_chunks=4).solve_many(systems)
     for (dl, d, du, b), x in zip(systems, xs):
         assert _rel_err(x, thomas_numpy(dl, d, du, b)) < TOL[dtype]
 
 
 def test_ragged_single_system_degenerates_to_chunked():
-    from repro.core.tridiag import ChunkedPartitionSolver
-
     (sys0,) = _mk_systems((400,), seed0=5)
-    xs = solve_ragged([sys0], m=10, num_chunks=3)
-    ref = ChunkedPartitionSolver(m=10, num_chunks=3).solve(*sys0)
+    session = _session(num_chunks=3)
+    xs = session.solve_many([sys0])
+    ref = session.solve(*sys0)
     np.testing.assert_allclose(xs[0], ref, rtol=0, atol=0)
 
 
 def test_ragged_rejects_indivisible_size():
     systems = _mk_systems((60, 55))
     with pytest.raises(ValueError):
-        solve_ragged(systems, m=10)
+        _session().solve_many(systems)
 
 
 def test_ragged_solver_rejects_num_chunks_with_policy():
     from repro.core.tridiag import FixedChunkPolicy
 
     with pytest.raises(ValueError):
-        RaggedPartitionSolver(m=10, num_chunks=8, policy=FixedChunkPolicy(2))
+        _session(num_chunks=8, policy=FixedChunkPolicy(2))
 
 
 def test_ragged_campaign_keeps_equal_total_mixes_apart():
@@ -194,8 +200,7 @@ def test_predict_optimum_ragged_equals_effective_size_pick(batched_heuristic):
 def test_ragged_solver_uses_policy_pick(batched_heuristic):
     h = batched_heuristic
     sizes = (200, 1000, 5000)
-    solver = RaggedPartitionSolver(m=10, policy=HeuristicChunkPolicy(h))
-    plan = solver.plan_for(sizes)
+    plan = _session(policy=HeuristicChunkPolicy(h)).plan_for(sizes)
     assert plan.num_chunks == min(
         h.predict_optimum_ragged(sizes), sum(sizes) // 10
     )

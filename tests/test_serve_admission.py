@@ -1,7 +1,8 @@
-"""Tests for the serving admission loop: BatchedSolveService edge cases
-(empty flush, exact max_batch splits, default_chunks fallback) and the
-deadline/mixed-size admission path, including the acceptance comparison
-against the size-segregated PR-1 baseline."""
+"""Tests for the serving admission loop, driven directly on SolveEngine
+through its submit/poll/flush contract: edge cases (empty flush, exact
+max_batch splits, default_chunks fallback) and the deadline/mixed-size
+admission path, including the acceptance comparison against the
+size-segregated PR-1 baseline."""
 
 import numpy as np
 import pytest
@@ -13,7 +14,7 @@ ensure_x64()
 from repro.core.tridiag import make_diag_dominant_system, thomas_numpy  # noqa: E402
 from repro.serve.solve import (  # noqa: E402
     AdmissionPolicy,
-    BatchedSolveService,
+    SolveEngine,
     SolveRequest,
 )
 
@@ -39,7 +40,7 @@ class FakeClock:
 
 # ------------------------------------------------------------- edge cases ----
 def test_flush_empty_service():
-    svc = BatchedSolveService(m=10, max_batch=4)
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=4))
     assert svc.flush() == {}
     assert svc.pending() == 0
     assert svc.stats["batches"] == 0
@@ -48,14 +49,14 @@ def test_flush_empty_service():
 
 def test_queue_split_at_exactly_max_batch():
     refs = {}
-    svc = BatchedSolveService(m=10, max_batch=4)
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=4))
     for rid in range(4):
         _submit(svc, rid, 60, refs)
     out = svc.flush()
     assert svc.stats["batches"] == 1  # exactly one full batch, no remainder
     assert svc.stats["per_batch"][0]["systems"] == 4
 
-    svc2 = BatchedSolveService(m=10, max_batch=4)
+    svc2 = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=4))
     for rid in range(5):
         _submit(svc2, rid, 60, refs)
     out2 = svc2.flush()
@@ -66,7 +67,7 @@ def test_queue_split_at_exactly_max_batch():
 
 
 def test_default_chunks_fallback_without_heuristic():
-    svc = BatchedSolveService(m=10, max_batch=8, default_chunks=3)
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=8), default_chunks=3)
     assert svc.pick_chunks(60, 4) == 3
     assert svc.pick_chunks_ragged((60, 120)) == 3
     refs = {}
@@ -95,7 +96,7 @@ def test_chunk_pricing_identical_across_entry_points():
 
     h = SplitBrainHeuristic()
     sizes = (60, 120, 60)
-    svc = BatchedSolveService(heuristic=h, m=10, max_batch=8)
+    svc = SolveEngine(heuristic=h, m=10, admission=AdmissionPolicy(max_batch=8))
     policy_pick = HeuristicChunkPolicy(h).num_chunks(sizes, 10)
     assert svc.pick_chunks_ragged(sizes) == policy_pick == 4
     # and the same-size special case agrees too
@@ -116,7 +117,9 @@ def test_zero_chunk_heuristic_pick_cannot_kill_a_dispatch():
         def predict_optimum_ragged(self, sizes):
             return 0
 
-    svc = BatchedSolveService(heuristic=ZeroPickHeuristic(), m=10, max_batch=4)
+    svc = SolveEngine(
+        heuristic=ZeroPickHeuristic(), m=10, admission=AdmissionPolicy(max_batch=4)
+    )
     assert svc.pick_chunks_ragged((60,)) == 1
     refs = {}
     _submit(svc, 0, 60, refs)
@@ -126,16 +129,18 @@ def test_zero_chunk_heuristic_pick_cannot_kill_a_dispatch():
 
 
 def test_max_batch_and_admission_conflict_is_rejected():
-    """max_batch lives inside the policy once one is passed; a conflicting
-    ctor arg must not be silently ignored."""
-    with pytest.raises(ValueError):
-        BatchedSolveService(
-            m=10, max_batch=8, admission=AdmissionPolicy(max_wait_ms=5.0)
-        )
+    """max_batch lives inside the admission policy only: there is no second
+    ctor arg to conflict with it, and a bad value is refused, not ignored."""
+    with pytest.raises(TypeError):
+        SolveEngine(m=10, max_batch=8, admission=AdmissionPolicy(max_wait_ms=5.0))
+    with pytest.raises(ValueError, match="max_batch"):
+        AdmissionPolicy(max_batch=0)
+    with pytest.raises(ValueError, match="max_wait_ms"):
+        AdmissionPolicy(max_wait_ms=-1.0)
 
 
 def test_submit_rejects_indivisible_size():
-    svc = BatchedSolveService(m=10)
+    svc = SolveEngine(m=10)
     dl, d, du, b, _ = make_diag_dominant_system(55, seed=0)
     with pytest.raises(ValueError):
         svc.submit(SolveRequest(0, dl, d, du, b))
@@ -147,7 +152,7 @@ def test_submit_rejects_mismatched_diagonals_naming_request(bad):
     sail through submit and explode later inside the fused dispatch with an
     opaque shape error — riding in a batch of innocent neighbours. submit()
     now validates and names the offending request id."""
-    svc = BatchedSolveService(m=10, max_batch=4)
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=4))
     dl, d, du, b, _ = make_diag_dominant_system(60, seed=0)
     parts = {"dl": dl, "du": du, "b": b}
     parts[bad] = parts[bad][:-1]  # one short diagonal
@@ -164,8 +169,8 @@ def test_submit_rejects_mismatched_diagonals_naming_request(bad):
 # -------------------------------------------------------- admission triggers --
 def test_max_batch_admission_dispatches_on_submit():
     clock = FakeClock()
-    svc = BatchedSolveService(
-        m=10, admission=AdmissionPolicy(max_batch=2), clock=clock
+    svc = SolveEngine(
+        m=10, admission=AdmissionPolicy(max_batch=2), eager=True, clock=clock
     )
     refs = {}
     _submit(svc, 0, 60, refs)
@@ -180,9 +185,10 @@ def test_max_batch_admission_dispatches_on_submit():
 
 def test_deadline_admission_dispatches_partial_batch():
     clock = FakeClock()
-    svc = BatchedSolveService(
+    svc = SolveEngine(
         m=10,
         admission=AdmissionPolicy(max_batch=64, max_wait_ms=50.0),
+        eager=True,
         clock=clock,
     )
     refs = {}
@@ -205,8 +211,8 @@ def test_deadline_admission_dispatches_partial_batch():
 def test_mixed_sizes_do_not_wait_for_size_mates():
     """A full mixed-size FIFO prefix dispatches as one ragged batch."""
     clock = FakeClock()
-    svc = BatchedSolveService(
-        m=10, admission=AdmissionPolicy(max_batch=3), clock=clock
+    svc = SolveEngine(
+        m=10, admission=AdmissionPolicy(max_batch=3), eager=True, clock=clock
     )
     refs = {}
     for rid, size in enumerate((60, 240, 120)):
@@ -231,9 +237,10 @@ def test_ragged_admission_beats_size_segregated_baseline():
     workload = [60, 120, 60, 120, 60, 120]  # interleaved size classes
 
     def run(allow_ragged):
-        svc = BatchedSolveService(
+        svc = SolveEngine(
             m=10,
             admission=AdmissionPolicy(max_batch=6, allow_ragged=allow_ragged),
+            eager=True,
         )
         refs = {}
         for rid, size in enumerate(workload):
@@ -261,7 +268,7 @@ def test_ragged_admission_beats_size_segregated_baseline():
 def test_legacy_flush_contract_is_preserved():
     """No admission policy: submit only enqueues (PR-1 behaviour), flush
     drains everything and mixed sizes still fuse instead of serialising."""
-    svc = BatchedSolveService(m=10, max_batch=4)
+    svc = SolveEngine(m=10, admission=AdmissionPolicy(max_batch=4))
     refs = {}
     for rid, size in enumerate((60, 60, 60, 60, 60, 120, 120)):
         _submit(svc, rid, size, refs)

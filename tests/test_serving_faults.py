@@ -454,21 +454,6 @@ def test_pending_counts_inflight_batch():
         session.close()
 
 
-# ------------------------------------------------------------- legacy shim --
-def test_legacy_shim_rides_max_queue():
-    from repro.serve.solve import BatchedSolveService
-
-    with pytest.warns(DeprecationWarning):
-        svc = BatchedSolveService(m=10, max_batch=64, max_queue=2)
-    svc.submit(SolveRequest(0, *_sys(60, 0)))
-    svc.submit(SolveRequest(1, *_sys(60, 1)))
-    with pytest.raises(QueueFullError):
-        svc.submit(SolveRequest(2, *_sys(60, 2)))
-    assert svc.stats["rejected"] == 1
-    out = svc.flush()
-    assert sorted(out) == [0, 1]
-
-
 # ----------------------------------------------- predicted-latency admission --
 def _planted_model(pred_ms):
     """A latency model predicting a constant ``pred_ms`` for every batch."""

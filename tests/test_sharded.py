@@ -257,15 +257,15 @@ class TestShardedParityWide:
         from repro.core.tridiag.layout import resolve_layout
 
         assert (
-            resolve_layout("auto", (160,) * 64, M, fused=True, batch_shards=8)
+            resolve_layout("auto", (160,) * 64, M, batch_shards=8)
             == "system-major"
         )
         assert (
-            resolve_layout("auto", (160,) * 64, M, fused=True, batch_shards=1)
+            resolve_layout("auto", (160,) * 64, M, batch_shards=1)
             == "interleaved"
         )
         assert (
-            resolve_layout("auto", (160,) * 256, M, fused=True, batch_shards=8)
+            resolve_layout("auto", (160,) * 256, M, batch_shards=8)
             == "interleaved"
         )
 
@@ -329,29 +329,14 @@ class TestMeshNoneIdentity:
 
 # ------------------------------------------------------------------- config --
 class TestConfigValidation:
-    def test_mesh_staged_rejected(self):
-        with pytest.raises(ValueError, match="staged"):
-            SolverConfig(mesh="auto", dispatch="staged").validate()
-
     def test_mesh_fused_and_auto_ok(self, multi_device_count):
-        SolverConfig(mesh="auto", dispatch="fused").validate()
+        SolverConfig(mesh="auto").validate()
         SolverConfig(mesh="auto", dispatch="auto").validate()
         SolverConfig(mesh=2, dispatch="auto").validate()
 
     def test_bad_mesh_spec_rejected(self):
         with pytest.raises(ValueError, match="auto"):
             SolverConfig(mesh="everything").validate()
-
-    def test_timed_verbs_fall_back_staged_single_device(self, multi_device_count):
-        # dispatch="auto" + mesh: *_timed keeps the staged single-device path
-        # (documented fallback) and still matches the oracle.
-        n = 800
-        dl, d, du, b, _ = make_diag_dominant_system(n, seed=9)
-        ref = thomas_numpy(dl, d, du, b)
-        with TridiagSession(SolverConfig(mesh="auto", num_chunks=8)) as s:
-            x, timing = s.solve_timed(dl, d, du, b)
-        assert rel_err(x, ref) < 1e-12
-        assert timing.t_stage2_ms >= 0.0  # staged path has a phase breakdown
 
 
 # ------------------------------------------------------------ shard_map proof --

@@ -55,7 +55,6 @@ def obs(size, k, latency_ms, *, t=0.0, batch=1, predicted=None):
         num_chunks=k,
         backend="reference",
         layout="system-major",
-        dispatch="fused",
         latency_ms=latency_ms,
         mean_wait_ms=0.1,
         max_wait_ms=0.2,
@@ -360,7 +359,6 @@ def test_session_records_observations_while_serving():
         assert len(snap) >= 1
         assert all(o.sizes and o.num_chunks >= 1 for o in snap)
         assert all(o.latency_ms > 0 for o in snap)
-        assert {o.dispatch for o in snap} == {"fused"}
         assert session.stats["autotune"]["mode"] == "shadow"
 
 

@@ -13,7 +13,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core.tridiag import (  # noqa: E402
-    ChunkedPartitionSolver,
+    SolverConfig,
+    TridiagSession,
     make_diag_dominant_system,
     partition_solve,
     partition_stage1,
@@ -175,19 +176,27 @@ def test_partition_levels_refuses_m_below_two():
 
 
 # ---------------------------------------------------------------- chunked ----
+def _chunked_session(num_chunks):
+    return TridiagSession(
+        SolverConfig(m=10, num_chunks=num_chunks, backend="reference")
+    )
+
+
 @pytest.mark.parametrize("num_chunks", [1, 2, 3, 8, 32])
 def test_chunked_solver_matches_reference(num_chunks):
     n = 400
     dl, d, du, b, _ = make_diag_dominant_system(n, seed=num_chunks)
-    solver = ChunkedPartitionSolver(m=10, num_chunks=num_chunks)
-    x, timing = solver.solve_timed(dl, d, du, b)
+    session = _chunked_session(num_chunks)
+    x = session.solve(dl, d, du, b)
     assert _rel_err(x, thomas_numpy(dl, d, du, b)) < 1e-11
-    assert timing.num_chunks == min(num_chunks, n // 10)
-    assert timing.t_total_ms > 0
+    assert session.plan_for(n).num_chunks == min(num_chunks, n // 10)
+    assert session.stats["layout"] == {"system-major": 1}
 
 
 def test_chunked_more_chunks_than_blocks():
     n = 30  # 3 blocks, ask for 8 chunks
     dl, d, du, b, _ = make_diag_dominant_system(n, seed=1)
-    x = ChunkedPartitionSolver(m=10, num_chunks=8).solve(dl, d, du, b)
+    session = _chunked_session(8)
+    x = session.solve(dl, d, du, b)
     assert _rel_err(x, thomas_numpy(dl, d, du, b)) < 1e-11
+    assert session.plan_for(n).num_chunks == 3
